@@ -1,5 +1,7 @@
-# Developer entry points. Everything runs on the virtual 8-device CPU
-# mesh (tests/conftest.py pins JAX_PLATFORMS=cpu); no TPU required.
+# Developer entry points. The test targets run on the virtual 8-device CPU
+# mesh (tests/conftest.py pins JAX_PLATFORMS=cpu); no TPU required. The
+# chip is reached with `python chip_smoke.py` through the chip tool (README
+# "Running").
 
 PY ?= python
 PYTEST_FLAGS ?= -q -m 'not slow' -p no:cacheprovider
@@ -11,7 +13,7 @@ DISTRIBUTED = tests/test_clusterproc.py tests/test_spmd.py \
 	tests/test_netfault.py tests/test_join.py \
 	tests/test_golden_cluster.py tests/test_fuzz_cluster.py \
 	tests/test_shardwidth_matrix.py tests/test_tls.py \
-	tests/test_bench_orchestrator.py tests/test_crashmatrix.py
+	tests/test_chip_smoke.py tests/test_crashmatrix.py
 
 .PHONY: test test-core test-distributed test-observability test-parallel \
 	test-flightrec test-devhealth test-explain test-durability \
@@ -151,6 +153,8 @@ lint:
 			bench_suite.py bench_kernels.py; \
 	fi
 
-# The north-star benchmark on the CPU fallback scale: one JSON line.
+# The north-star benchmark's control flow on the host, asked for explicitly
+# (a shrunken, CPU-labelled shape: it checks the harness, it is not a
+# measurement). One JSON line.
 bench-cpu:
 	JAX_PLATFORMS=cpu $(PY) bench.py

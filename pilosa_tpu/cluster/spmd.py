@@ -218,8 +218,8 @@ class SpmdDataPlane:
     def initialize(cls, coordinator_address, num_processes, process_id,
                    cpu_collectives=None):
         """Join the global JAX distributed system. MUST run before any JAX
-        backend initializes in this process (same constraint as platform
-        selection; see cli._honor_jax_platforms_env).
+        backend initializes in this process — cli.cmd_server calls it
+        ahead of utils/device.boot, the first backend use.
 
         cpu_collectives="gloo" opts the CPU backend into real
         cross-process collectives (the 2-process CPU harness and any
@@ -752,11 +752,10 @@ class SpmdDataPlane:
         return result
 
     def _enter_exit_run(self, step):
-        """_run_step_locked bracketed by the step-lifecycle events the
-        wedge classifier reads (bench._classify_wedge): a node whose
-        flightrec shows announce-without-enter never reached the
-        collective (control-plane loss); enter-without-exit means the
-        collective itself hung. Caller holds self._lock.
+        """_run_step_locked bracketed by the step-lifecycle flightrec
+        events: a node whose recorder shows announce-without-enter never
+        reached the collective (control-plane loss); enter-without-exit
+        means the collective itself hung. Caller holds self._lock.
 
         Mesh observatory: runs the step under a _StepClock (t0 = the
         step's announcement-receipt stamp, so announce_recv covers

@@ -136,7 +136,10 @@ class Holder:
         for idx in list(self.indexes.values()):
             for field in list(idx.fields.values()):
                 for view in list(field.views.values()):
-                    yield from view.fragments.values()
+                    # a copy, like the levels above: imports create
+                    # fragments while the oplog's rotation checkpoint
+                    # sweeps them from its own thread
+                    yield from list(view.fragments.values())
 
     def flush_caches(self):
         """Persist every fragment's TopN cache (reference: holder cache

@@ -1,9 +1,10 @@
 """Whole-plan fusion: compile an entire PQL query into ONE jitted
 device program, cached by workload fingerprint.
 
-BENCH r03 measured 66.1ms p50 on the 1B-column Intersect+Count with
-64.9ms of it dispatch RTT. Count batching (PR 9) amortizes that RTT
-across *concurrent* queries; nothing removed it per query, so an
+Every top-level call of a query pays one dispatch round trip (its cost
+on the chip: not measured on this round's code). Count batching (PR 9)
+amortizes that RTT across *concurrent* queries; nothing removed it per
+query, so an
 interactive client running one query at a time still pays the full
 round trip per top-level call. This module removes the per-call
 multiplier: an eligible multi-call query traces into one jitted

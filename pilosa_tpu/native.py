@@ -1,7 +1,7 @@
 """ctypes bindings for the native host-side kernels (native/pilosa_native.cpp).
 
-Loads `native/libpilosa_native.so`, building it once with `make` if absent
-(and a compiler is available). Every entry point has a pure-Python/numpy
+Loads `native/libpilosa_native.so`, building it with `make` when absent or
+stale (and a compiler is available). Every entry point has a pure-Python/numpy
 fallback so the package works without a toolchain; `PILOSA_TPU_NATIVE=0`
 forces the fallbacks.
 
@@ -13,6 +13,7 @@ container optimization (roaring.go:2334).
 """
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -39,15 +40,16 @@ def _load():
         lib = None
         if os.environ.get("PILOSA_TPU_NATIVE", "1") != "0":
             try:
-                # Build to a process-private name then atomically publish:
-                # concurrent processes (multi-node-on-one-host, xdist) must
-                # never CDLL a half-written .so. make no-ops when current.
-                tmp = f"{_SO_PATH}.{os.getpid()}"
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, f"SO_OUT={tmp}"],
-                    check=True, capture_output=True, timeout=120)
-                if os.path.exists(tmp):
-                    os.replace(tmp, _SO_PATH)
+                # make no-ops when the .so is current and publishes a new
+                # one with an atomic rename (native/Makefile); the lock
+                # keeps concurrent processes (multi-node-on-one-host)
+                # from compiling into the same file at once.
+                with open(os.path.join(_NATIVE_DIR, ".build.lock"),
+                          "w") as lock:
+                    fcntl.flock(lock, fcntl.LOCK_EX)
+                    subprocess.run(
+                        ["make", "-C", _NATIVE_DIR],
+                        check=True, capture_output=True, timeout=120)
                 lib = ctypes.CDLL(_SO_PATH)
                 _declare(lib)
             except Exception as e:

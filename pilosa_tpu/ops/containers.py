@@ -1,8 +1,9 @@
 """Device-resident compressed plane containers.
 
 Dense [S, W] uint32 plane stacks (ops/bitplane.py) make every Count scan
-S * W * 4 bytes of HBM — BENCH r03 measured the serving path at 89.6% of
-HBM peak, so bytes-moved is the wall (ROADMAP item 2). The reference
+S * W * 4 bytes of HBM, so for a bandwidth-bound popcount bytes moved is
+the wall (device share of HBM peak: not measured on this round's code).
+The reference
 never pays this: roaring picks array/bitmap/run representation per 64K
 block by density (reference: roaring/roaring.go container types;
 PAPER.md §2.1). This module is the device analogue — per-fragment

@@ -4,18 +4,19 @@ The jnp kernels in `bitplane.py` already let XLA fuse AND+popcount+reduce;
 and the north-star scan — Count(Intersect(a, b)) over every shard of a
 1B-column index (reference: intersectionCount* kernels
 roaring/roaring.go:3121-3480 driven by executor.mapReduce
-executor.go:2455) — is pure AND+popcount+reduce, so that fused XLA path is
-already bandwidth-optimal. Measured on a TPU v5 lite chip (fresh inputs,
-960 shards x 128 KiB planes): jnp 3.57 ms vs pallas 3.39 ms — parity
-within noise. These kernels therefore exist as an *alternative backend* —
-explicit HBM->VMEM streaming with a lane-resident accumulator — selectable
-with `PILOSA_TPU_PALLAS=1`, not the default ("don't hand-schedule what the
-compiler already fuses"). They also serve as the template for future fused
-ops XLA can't express in one pass (e.g. BSI multi-plane compare+count).
+executor.go:2455) — is pure AND+popcount+reduce, a shape XLA fuses on its
+own. Pallas-vs-jnp device time: not measured on this round's code. These
+kernels therefore exist as an *alternative backend* — explicit HBM->VMEM
+streaming with a lane-resident accumulator — selectable with
+`PILOSA_TPU_PALLAS=1`, not the default (keep-or-delete is ROADMAP D4).
 
-Dispatch contract: `available()` says whether pallas can run here; callers
-(`QueryKernels`) consult `enabled()`. On non-TPU backends the kernels run
-via the Pallas interpreter (used by the differential tests).
+Dispatch contract: callers consult `enabled()` — the opt-in env var on a
+TPU backend, nothing else. There is no probe: a kernel the Mosaic compiler
+refuses RAISES at its call site instead of silently becoming the jnp path.
+`chip_smoke.py`'s kernels phase compiles every public function here with
+interpret=False on the chip at the serving shapes and compares it bit for
+bit with jnp. On the (explicitly requested) CPU backend the kernels run
+through the Pallas interpreter, which is what the differential tests use.
 """
 
 import functools
@@ -28,7 +29,6 @@ import numpy as np
 from ..shardwidth import WORDS_PER_ROW
 
 __all__ = [
-    "available",
     "enabled",
     "count_intersect_stack",
     "count_expr_stack",
@@ -40,34 +40,23 @@ __all__ = [
 ]
 
 # Rows of the [S, W] stack processed per grid step. 16 sublanes x 32768
-# words = 2 MiB/input block in VMEM — two inputs + scratch + double
-# buffering fit in ~16 MiB VMEM. (32 rows fails to compile on v5 lite.)
+# words = 2 MiB/input block in VMEM. (32 rows fails to compile on v5 lite.)
 _BLOCK_ROWS = 16
 
 
 def _interpret():
+    """Interpreter off a TPU. utils/device.boot makes the backend explicit
+    (a TPU, or JAX_PLATFORMS=cpu on purpose), so this never papers over a
+    chip that failed to initialise."""
     return jax.default_backend() != "tpu"
 
 
-@functools.lru_cache(maxsize=1)
-def available():
-    """True when pallas is importable and a trivial kernel runs."""
-    try:
-        out = count_intersect_stack(
-            np.full((1, WORDS_PER_ROW), 0xFFFFFFFF, dtype=np.uint32),
-            np.full((1, WORDS_PER_ROW), 0xFFFFFFFF, dtype=np.uint32),
-        )
-        return int(out) == WORDS_PER_ROW * 32
-    except Exception:
-        return False
-
-
 def enabled():
-    """Use pallas for the serving hot path? Opt-in AND real TPU only: XLA's
-    fused jnp path is at parity on TPU (see module docstring) and on other
-    backends the kernels would run through the (very slow) interpreter."""
+    """Use pallas for the serving hot path? Opt-in AND a TPU backend (on
+    the CPU backend the kernels would run through the very slow
+    interpreter). No capability probe: a compile error propagates."""
     return (os.environ.get("PILOSA_TPU_PALLAS", "0") == "1"
-            and jax.default_backend() == "tpu" and available())
+            and jax.default_backend() == "tpu")
 
 
 def _pad_rows(x, block):
@@ -120,6 +109,12 @@ def _count_expr_call(ops, n_rows, interpret):
     arity = len(ops) + 1
     n_blocks = n_rows // _BLOCK_ROWS
     spec = pl.BlockSpec((_BLOCK_ROWS, WORDS_PER_ROW), lambda i: (i, 0))
+    # Every operand block is double-buffered and the fold + popcount
+    # temporaries take about three blocks more (a v5e asked for 17.79 MiB
+    # at three operands, chip_smoke PR 21). The default scoped limit of
+    # 16 MiB only holds two operands, so ask for what the arity needs.
+    block_bytes = _BLOCK_ROWS * WORDS_PER_ROW * 4
+    vmem_limit = (2 * arity + 4) * block_bytes
 
     call = pl.pallas_call(
         _count_expr_kernel(ops, n_blocks),
@@ -128,6 +123,7 @@ def _count_expr_call(ops, n_rows, interpret):
         out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, 128), jnp.int32),
         scratch_shapes=[pltpu.VMEM((8, 128), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )
 

@@ -49,16 +49,6 @@ def apply_op_chain(acc, planes, ops):
     return acc
 
 
-def _shard_map():
-    """shard_map across jax versions: top-level export on recent jax,
-    jax.experimental on 0.4.x."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def build_global_mesh(axis="shards"):
     """1-D mesh over the GLOBAL device list, process-major: each
     process's addressable block is contiguous along the shard axis —
@@ -199,9 +189,8 @@ class ShardedQueryEngine:
         """Distributed Intersect+Count: local popcount per device slice,
         psum across the shard axis over ICI."""
         jax, jnp = _jax()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        shard_map = _shard_map()
 
         hi_lo, combine = _hi_lo()
         key = ("count_intersect",)
@@ -228,9 +217,8 @@ class ShardedQueryEngine:
         One jit per (ops, arity): elementwise chain on the local slice, one
         psum across ICI."""
         jax, jnp = _jax()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        shard_map = _shard_map()
 
         hi_lo, combine = _hi_lo()
         key = ("expr", ops, len(planes))
@@ -258,9 +246,8 @@ class ShardedQueryEngine:
         one jitted program (reference analog: per-node TopN + heap merge,
         executor.go:930)."""
         jax, jnp = _jax()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        shard_map = _shard_map()
 
         hi_lo, combine = _hi_lo()
         key = ("topn",)
@@ -296,9 +283,8 @@ class ShardedQueryEngine:
         broadcast intermediate stays one B-stack wide), then the partials
         psum over ICI. Returns the host int64 [R1, R2] matrix."""
         jax, jnp = _jax()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        shard_map = _shard_map()
 
         hi_lo, combine = _hi_lo()
         has_filt = filt is not None
@@ -331,9 +317,8 @@ class ShardedQueryEngine:
         """Distributed BSI Sum: per-plane popcounts psum'd over shards.
         planes [D, S, W]; sign/exists/filt [S, W]."""
         jax, jnp = _jax()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        shard_map = _shard_map()
 
         hi_lo, combine = _hi_lo()
         key = ("sum",)

@@ -189,8 +189,8 @@ def result_to_json(result):
 class QueryCoalescer:
     """Folds concurrent batchable queries into fused vmapped dispatches
     (exec/stacked.launch_query_batch) so the per-dispatch RTT is paid
-    once per batch instead of once per query — BENCH_r03 measured
-    64.9ms of a 66.1ms p50 sitting in dispatch round-trip.
+    once per batch instead of once per query (what that round trip
+    costs on the chip: not measured on this round's code).
 
     Lifecycle: HTTP handler threads submit() parsed single-call queries
     and block on a per-query event; one lazy-started daemon drain thread
@@ -974,8 +974,8 @@ class API:
             raise GatewayTimeoutError(
                 "request deadline expired before execution")
         # Device-link fail-fast: with the link DOWN a query would wedge
-        # behind the dispatch lock until the watchdog fires (75s+ in the
-        # r04/r05 postmortems); reject in microseconds instead. DEGRADED
+        # behind the dispatch lock until the watchdog fires; reject in
+        # microseconds instead. DEGRADED
         # still serves — hysteresis keeps one flaky probe from shedding
         # load. Applies to remote fan-out legs too: the coordinator gets
         # a fast 503 it can surface rather than a wedged peer socket.
@@ -1972,7 +1972,13 @@ class API:
     # -- info/status --------------------------------------------------------
 
     def info(self):
-        return {"shardWidth": SHARD_WIDTH, "version": __version__}
+        """shardWidth/version (reference: GET /info) plus what this node
+        computes on — platform, deviceKind, deviceCount as JAX reports
+        them — so a client can tell what answered it."""
+        from ..utils import device
+
+        return {"shardWidth": SHARD_WIDTH, "version": __version__,
+                **device.facts()}
 
     def status(self, include_remote_observability=False):
         state = "NORMAL"
@@ -2077,7 +2083,7 @@ class API:
             out["plans"] = {k: plans.get(k) for k in
                             ("retained", "misestimates_flagged")}
             # device-link roll-up: the coordinator's /status answers
-            # "which node's tunnel is dead" without a per-node ssh
+            # "which node's device link is dead" without a per-node ssh
             dev = client.debug_device(limit=0)
             out["device_link"] = {k: dev.get(k) for k in
                                   ("state", "state_since",

@@ -47,13 +47,9 @@ class Diagnostics:
         n_fields = sum(len(i.fields) for i in indexes)
         n_shards = sum(len(i.available_shards()) for i in indexes)
         cluster = self.api.cluster
-        try:
-            import jax
+        from ..utils import device
 
-            backend = jax.default_backend()
-            n_devices = jax.device_count()
-        except Exception:
-            backend, n_devices = "none", 0
+        dev = device.facts()
         return {
             "version": __version__,
             "os": platform.system(),
@@ -63,8 +59,8 @@ class Diagnostics:
             "numShards": n_shards,
             "numNodes": len(cluster.nodes) if cluster else 1,
             "replicaN": cluster.replica_n if cluster else 1,
-            "backend": backend,
-            "numDevices": n_devices,
+            "backend": dev["platform"],
+            "numDevices": dev["deviceCount"],
             "uptimeSeconds": int(time.time() - self._t0),
         }
 

@@ -505,7 +505,7 @@ class PilosaHTTPServer:
 
     def _get_healthz(self, req):
         """Liveness: the process is up and serving HTTP. Deliberately
-        ignores the device link — a dead tunnel needs draining
+        ignores the device link — a dead link needs draining
         (/readyz), not a restart loop."""
         return {"status": "ok"}
 

@@ -406,18 +406,12 @@ class RuntimeMonitor:
         --spmd mode that must wait for jax.distributed.initialize; see
         cluster/spmd.py) — and tolerates backends that don't implement
         memory_stats (CPU returns None/raises)."""
-        import sys
+        from . import device
 
-        jax = sys.modules.get("jax")
-        if jax is None:
+        if not device.backends_are_initialized():
             return
-        try:
-            from jax._src import xla_bridge
+        import jax
 
-            if not xla_bridge.backends_are_initialized():
-                return
-        except Exception:
-            return  # can't prove a live backend; don't risk initializing one
         try:
             for d in jax.local_devices():
                 mem = d.memory_stats()

@@ -430,30 +430,6 @@ def test_slow_query_line_batch_attribution(srv):
 # -------------------------------------------------- observability plumbing
 
 
-def test_bare_flightrec_debug_server_serves_dispatch(env):
-    """The bench child's bare debug server (no PilosaHTTPServer) now
-    serves /debug/dispatch, so missed-deadline kill records can carry
-    the dispatch-phase table."""
-    import urllib.request
-
-    from pilosa_tpu.utils import flightrec
-
-    holder, api, ex = env
-    ex.execute("i", "Count(Row(f=1))")  # populate the global aggregate
-    srv = flightrec.start_debug_server()
-    try:
-        port = srv.server_address[1]
-        with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/debug/dispatch",
-                timeout=5) as resp:
-            snap = json.loads(resp.read().decode())
-        assert "phases" in snap and snap["phases"]
-        fam = next(iter(snap["phases"].values()))
-        assert "sync" in fam or "dispatch_ack" in fam
-    finally:
-        srv.shutdown()
-
-
 def test_plan_annotates_batched_strategy(env):
     """With a coalesce window configured, EXPLAIN marks stack-coverable
     Count/bitmap nodes `batched` and names the padding buckets."""

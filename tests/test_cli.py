@@ -8,12 +8,7 @@ the introspection commands that only print.
 import io
 from contextlib import redirect_stdout
 
-try:
-    import tomllib  # 3.11+
-except ImportError:  # same fallback chain as cli.py's --config loader
-    import pytest
-
-    tomllib = pytest.importorskip("tomli")
+import tomllib
 
 from pilosa_tpu.cli import main
 

@@ -301,6 +301,22 @@ def test_holder_reopen_preserves_schema(tmp_path):
     h2.close()
 
 
+def test_fragment_sweep_survives_concurrent_fragment_creation(holder):
+    """The oplog's rotation checkpoint sweeps every fragment from its own
+    thread while imports create new ones (seen on the chip at 954 shards:
+    "dictionary changed size during iteration")."""
+    field = holder.create_index("i").create_field("f")
+    view = field.create_view_if_not_exists("standard")
+    for shard in range(4):
+        view.create_fragment_if_not_exists(shard)
+    seen = 0
+    for frag in holder._all_fragments():
+        view.create_fragment_if_not_exists(100 + seen)  # grows the dict
+        seen += 1
+    assert seen >= 4
+    assert holder.sync_fragments() == len(view.fragments)
+
+
 def test_existence_field(holder):
     idx = holder.create_index("i")
     assert idx.existence_field() is not None

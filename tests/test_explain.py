@@ -457,10 +457,7 @@ def test_cli_flags_fold_into_config():
 
     from pilosa_tpu.cli import main
 
-    try:
-        import tomllib
-    except ImportError:
-        tomllib = pytest.importorskip("tomli")
+    import tomllib
 
     buf = io.StringIO()
     with redirect_stdout(buf):

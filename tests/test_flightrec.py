@@ -445,25 +445,6 @@ def test_http_5xx_records_event(harness):
     assert events[-1]["tags"]["status"] >= 500
 
 
-def test_start_debug_server_serves_ring():
-    flightrec.record("bench.child_start", pid=1)
-    srv = flightrec.start_debug_server()
-    try:
-        port = srv.server_address[1]
-        with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/debug/flightrecorder",
-                timeout=5) as resp:
-            snap = json.loads(resp.read().decode())
-        assert any(e["kind"] == "bench.child_start"
-                   for e in snap["events"])
-        # anything else 404s
-        with pytest.raises(urllib.error.HTTPError):
-            urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/nope", timeout=5)
-    finally:
-        srv.shutdown()
-
-
 # -------------------------------------------------------- stats satellite
 
 def test_runtime_monitor_sample_age_gauge():

@@ -475,10 +475,7 @@ def test_cli_config_merges_fusion_flags(tmp_path):
 
     from pilosa_tpu.cli import main  # noqa: PLC0415
 
-    try:
-        import tomllib  # noqa: PLC0415
-    except ImportError:
-        tomllib = pytest.importorskip("tomli")
+    import tomllib  # noqa: PLC0415
 
     p = tmp_path / "c.toml"
     p.write_text('fusion = "shadow"\nfusion-cache-size = 16\n')

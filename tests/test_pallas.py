@@ -103,6 +103,29 @@ def test_enabled_requires_tpu_backend(monkeypatch):
         assert pk.enabled() is False
 
 
+def test_opt_in_kernel_failure_propagates(rng, monkeypatch):
+    """PILOSA_TPU_PALLAS=1 on a TPU backend is the whole gate — there is
+    no capability probe, so a kernel the compiler refuses RAISES at its
+    call site instead of silently becoming the jnp path."""
+    from pilosa_tpu.parallel.sharded import QueryKernels
+
+    assert not hasattr(pk, "available")
+    monkeypatch.setenv("PILOSA_TPU_PALLAS", "1")
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pk, "_count_expr_call", refuse)
+    monkeypatch.setattr(pk, "_topn_call", refuse)
+    assert pk.enabled() is True  # no trial kernel ran to decide this
+    planes = [_stack(rng, 2) for _ in range(2)]
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        QueryKernels.count_expr(planes, "&")
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        bp.topn_counts(_stack(rng, 3), _stack(rng, 1)[0], 2)
+
+
 def test_empty_stack_both_backends(monkeypatch):
     """Empty stacks: count is 0 and topn is all-zero on BOTH backends (the
     dispatcher guards before either backend sees the degenerate shape)."""

@@ -3,13 +3,11 @@
 The 2-process gloo differential lives in tests/test_spmd_mesh.py (slow);
 everything here is the fast half of the contract: serve-mode plumbing,
 the mesh stack cache's keying/generation/shadow semantics, the batched
-collective program vs serial counts, the step-lifecycle wedge
-classifier, and the /debug/spmd surface on a no-spmd node.
+collective program vs serial counts, and the /debug/spmd surface on a
+no-spmd node.
 """
 
-import importlib.util
 import os
-import sys
 import threading
 from collections import OrderedDict
 
@@ -285,47 +283,6 @@ def test_fusion_mesh_program_key_and_touch():
                if e["fingerprint"] == "fp1"]
     assert entries and entries[0]["mesh"] == [2, 1]
     assert entries[0]["hits"] == 2
-
-
-# -- wedge classifier ---------------------------------------------------------
-
-
-def _bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_spmd_wedge", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_classify_wedge_spmd_lifecycle():
-    bench = _bench()
-    up = {"state": "UP"}
-    announce = {"kind": "spmd.step_announce", "tags": {"seq": 4}}
-    enter = {"kind": "spmd.step_enter", "tags": {"seq": 4}}
-    exit_ = {"kind": "spmd.step_exit", "tags": {"seq": 4, "ok": True}}
-    # announced but never entered: a PEER is stuck / the stream gapped
-    assert bench._classify_wedge(
-        "main", {"events": [announce]}, up) == "spmd_never_entered"
-    # entered but never exited: the collective program itself hung
-    assert bench._classify_wedge(
-        "main", {"events": [announce, enter]}, up) \
-        == "spmd_collective_hung"
-    # a peer that entered without seeing the announcement still counts
-    assert bench._classify_wedge(
-        "main", {"events": [enter]}, up) == "spmd_collective_hung"
-    # full lifecycle is healthy -> falls through to unclassified
-    assert bench._classify_wedge(
-        "main", {"events": [announce, enter, exit_]}, up) \
-        == "unclassified"
-    # an open dispatch outranks the spmd signature (it is the inner hang)
-    assert bench._classify_wedge(
-        "main", {"events": [announce, enter,
-                            {"kind": "dispatch.start", "tags": {}}]},
-        up) == "dispatch_wedge"
 
 
 # -- /debug/spmd on a no-spmd node -------------------------------------------
