@@ -412,6 +412,12 @@ def cmd_server(args):
                      if getattr(api, "oplog", None) is not None
                      else {"enabled": False}))
         _incident.register_collector("admission", api.admission_stats)
+        # which phase the dispatches wedged in: the evaluator's own table
+        # (GET /debug/dispatch)
+        _local_ex = getattr(api.executor, "local", api.executor)
+        if hasattr(_local_ex, "dispatch_phase_stats"):
+            _incident.register_collector(
+                "dispatch", _local_ex.dispatch_phase_stats)
 
     # Metrics exemplars: timing histograms keep one recent trace id per
     # bucket, exposed in OpenMetrics exemplar syntax on /metrics and in

@@ -9,7 +9,9 @@ import os
 import queue
 import shutil
 import threading
+import time
 
+from ..utils import tracing
 from .field import FieldOptions
 from .index import Index, IndexOptions, validate_name
 
@@ -43,7 +45,8 @@ class SnapshotQueue:
                 continue
             try:
                 if frag.is_open and frag.op_n > 0:
-                    frag.snapshot()
+                    with tracing.annotate("holder.snapshot"):
+                        frag.snapshot()
             except Exception:
                 logging.getLogger("pilosa_tpu").exception(
                     "snapshot failed for %r", frag)
@@ -80,6 +83,12 @@ class Holder:
         self.cache_flush_interval = cache_flush_interval
         self._flush_stop = None
         self._flush_thread = None
+        # flush_caches runs and their seconds (GET /debug/vars `holder`);
+        # _flush_started is set while one runs
+        self.cache_flushes = 0
+        self._flush_seconds = 0.0
+        self._flush_started = None
+        self._flush_stats_lock = threading.Lock()
         self._lock = threading.RLock()
         self.opened = False
 
@@ -144,8 +153,27 @@ class Holder:
     def flush_caches(self):
         """Persist every fragment's TopN cache (reference: holder cache
         flush ticker holder.go:506-549)."""
-        for frag in self._all_fragments():
-            frag.flush_cache()
+        self._flush_started = t0 = time.monotonic()
+        try:
+            with tracing.annotate("holder.flush_caches"):
+                for frag in self._all_fragments():
+                    frag.flush_cache()
+        finally:
+            with self._flush_stats_lock:
+                self._flush_seconds += time.monotonic() - t0
+                self._flush_started = None
+                self.cache_flushes += 1
+
+    def flush_stats(self):
+        """{cache_flushes, cache_flush_seconds}; the seconds include what
+        a flush still running has taken so far, so that after - before of
+        two reads is the seconds of flushing between them, whatever the
+        flush's place in that window."""
+        with self._flush_stats_lock:
+            started = self._flush_started
+            running = 0.0 if started is None else time.monotonic() - started
+            return {"cache_flushes": self.cache_flushes,
+                    "cache_flush_seconds": self._flush_seconds + running}
 
     def recalculate_caches(self):
         """(reference: Holder.RecalculateCaches holder.go:553)"""

@@ -245,10 +245,13 @@ class Executor:
 
         # Key translation happens only on the coordinating node; remote
         # shards always receive integer IDs (reference: executor.go:2610).
+        from ..utils import tracing
+
         if not opt.remote:
             from .translate import translate_calls, translate_results
 
-            translate_calls(idx, query.calls)
+            with tracing.start_span("exec.translate", stage="calls"):
+                translate_calls(idx, query.calls)
 
         explain = getattr(opt, "explain", None)
         if explain == "plan":
@@ -265,13 +268,12 @@ class Executor:
             return []
 
         from ..utils import profile as profile_mod
-        from ..utils import tracing
         from ..utils import workload as workload_mod
         from ..utils.stats import global_stats
 
         import time as _time
 
-        # Per-query stacked-counter deltas: the before/after cache_stats
+        # Per-query stacked-counter deltas: the before/after counters()
         # diff attributes dispatches, cache traffic, and upload bytes to
         # THIS query — for the profile when one is active, and for the
         # always-on workload fingerprint table on every non-remote query
@@ -283,8 +285,13 @@ class Executor:
         prof = profile_mod.current()
         wctx = None if opt.remote else workload_mod.begin_query(
             idx.name, query)
-        wl_before = self._stacked.counters() if wctx is not None else None
-        before = self._stacked.cache_stats() if prof is not None else None
+        before = self._stacked.counters() \
+            if wctx is not None or prof is not None else None
+        if prof is not None:
+            # _call_shards leaves the shard count of the query's calls
+            # here: the profile's shards_touched without a second walk
+            # of every field's fragments
+            self._explain_tls.shards = None
 
         # a previous query's fused-batch stamp must not leak into this
         # query's batch= attribution; same for the whole-plan fused=
@@ -365,20 +372,18 @@ class Executor:
                     span.set_tag("calls", len(query.calls))
 
             if prof is not None:
-                after = self._stacked.cache_stats()
+                after = self._stacked.counters()
+                n_shards = getattr(self._explain_tls, "shards", None)
                 prof.set_tag("shards_touched",
-                             len(self._call_shards(idx, shards)))
-                for key, tag in (("dispatches", "dispatches"),
-                                 ("pairwise_dispatches",
-                                  "pairwise_dispatches"),
-                                 ("pairwise_syncs", "pairwise_syncs"),
-                                 ("hits", "cache_hits"),
-                                 ("misses", "cache_misses")):
-                    prof.add(tag, after[key] - before[key])
+                             len(self._call_shards(idx, shards))
+                             if n_shards is None else n_shards)
+                for i, tag in ((0, "dispatches"), (1, "cache_hits"),
+                               (2, "cache_misses"),
+                               (4, "pairwise_dispatches"),
+                               (5, "pairwise_syncs")):
+                    prof.add(tag, after[i] - before[i])
                 prof.add("bytes_materialized",
-                         (after["planes_uploaded"]
-                          - before["planes_uploaded"])
-                         * WORDS_PER_ROW * 4)
+                         (after[3] - before[3]) * WORDS_PER_ROW * 4)
         finally:
             if deadline is not None:
                 set_thread_deadline(None)
@@ -388,11 +393,11 @@ class Executor:
                 wl_after = self._stacked.counters()
                 workload_mod.end_query(
                     wctx, _time.perf_counter() - t_query, deltas={
-                        "dispatches": wl_after[0] - wl_before[0],
-                        "cache_hits": wl_after[1] - wl_before[1],
-                        "cache_misses": wl_after[2] - wl_before[2],
+                        "dispatches": wl_after[0] - before[0],
+                        "cache_hits": wl_after[1] - before[1],
+                        "cache_misses": wl_after[2] - before[2],
                         "bytes_materialized":
-                            (wl_after[3] - wl_before[3])
+                            (wl_after[3] - before[3])
                             * WORDS_PER_ROW * 4,
                     })
 
@@ -415,7 +420,8 @@ class Executor:
                     if wctx is not None else None)
 
         if not opt.remote:
-            results = translate_results(idx, query.calls, results)
+            with tracing.start_span("exec.translate", stage="results"):
+                results = translate_results(idx, query.calls, results)
         return results
 
     def explain_analyze_call(self, idx, call, shards, opt):
@@ -696,17 +702,25 @@ class Executor:
             "SetRowAttrs": self._exec_set_row_attrs,
             "SetColumnAttrs": self._exec_set_column_attrs,
         }.get(call.name)
-        if handler is not None:
-            return handler(idx, call, shards, opt)
-        # default: bitmap call
-        return self._exec_bitmap_call(idx, call, shards, opt)
+        # exec.plan: validation, shard selection, signature, gating — up
+        # to the first stack lookup or dispatch, which ends it
+        # (tracing.end_current in exec/stacked.py); a call that reaches
+        # neither is all plan
+        from ..utils import tracing
+
+        with tracing.start_span("exec.plan", op=call.name):
+            if handler is not None:
+                return handler(idx, call, shards, opt)
+            # default: bitmap call
+            return self._exec_bitmap_call(idx, call, shards, opt)
 
     # ------------------------------------------------------- shard selection
 
     def _call_shards(self, idx, shards):
-        if shards is not None:
-            return list(shards)
-        return idx.available_shards()
+        out = list(shards) if shards is not None \
+            else idx.available_shards()
+        self._explain_tls.shards = len(out)
+        return out
 
     # --------------------------------------------------- batched execution
 
@@ -961,7 +975,7 @@ class Executor:
             self._bump_fallback_heat(idx, child)
 
     def _exec_bitmap_call(self, idx, call, shards, opt):
-        import jax
+        from .stacked import fetch as stacked_fetch
 
         self.validate_bitmap_call(idx, call)
         self._bump_fallback_heat(idx, call)
@@ -977,7 +991,7 @@ class Executor:
                   if plane is not None]
         row = Row()
         if planes:
-            hosts = jax.device_get([p for _, p in planes])
+            hosts = stacked_fetch([p for _, p in planes])
             for (shard, _), host in zip(planes, hosts):
                 if host.any():
                     row.segments[shard] = host
@@ -1261,10 +1275,10 @@ class Executor:
             return 0
         # Host int sum: per-shard counts fit int32 (<= 2^20) but the total
         # can exceed 2^31 past 2048 shards.
-        import jax
+        from .stacked import fetch as stacked_fetch
 
         return int(np.sum(np.asarray(
-            jax.device_get(jnp.stack(counts)), dtype=np.int64)))
+            stacked_fetch(jnp.stack(counts)), dtype=np.int64)))
 
     def _sum_filter_planes(self, idx, call, shard):
         """Returns (has_filter, plane). has_filter with plane None means the
@@ -2037,7 +2051,9 @@ class Executor:
             out = {}
             if pending:
                 groups, dev_counts = zip(*pending)
-                host = np.asarray(jnp.stack(list(dev_counts)))  # one sync
+                from .stacked import fetch as stacked_fetch
+
+                host = stacked_fetch(jnp.stack(list(dev_counts)))  # one sync
                 for group, c in zip(groups, host):
                     if int(c) > 0:
                         out[group] = out.get(group, 0) + int(c)

@@ -57,6 +57,7 @@ import numpy as np
 
 from ..utils import faultpoints
 from ..utils import flightrec as _flightrec
+from ..utils import tracing as _tracing
 from ..utils.stats import global_stats
 
 __all__ = [
@@ -308,7 +309,8 @@ class IngestEngine:
                                       rows=self._rows, bytes=self._bytes)
                 continue
             try:
-                self.flush()
+                with _tracing.annotate("ingest.merge"):
+                    self.flush()
             except Exception as exc:  # noqa: BLE001 — keep merging
                 global_stats.count("ingest_merge_errors", 1)
                 _flightrec.record("ingest.merge_error", error=str(exc))

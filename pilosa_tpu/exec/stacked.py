@@ -94,20 +94,31 @@ class GroupCommit:
         exception if its batch failed."""
         import time as _time
 
-        entry = [payload, None, None, threading.Event()]
+        _tracing.end_current("exec.plan")
+        # [payload, result, error, done, size of the batch it rode]
+        entry = [payload, None, None, threading.Event(), 0]
         with self._lock:
             self._queue.append(entry)
             leader = len(self._queue) == 1
-        if not leader:
-            entry[3].wait()
-            if entry[2] is not None:
-                raise entry[2]
-            return entry[1]
-        if self._window_s > 0.0:
-            _time.sleep(self._window_s)
-        with self._lock:
-            batch = self._queue
-            self._queue = []
+        # dispatch.queue: a follower's whole wait for its leader's batch,
+        # a leader's window sleep and drain
+        with _tracing.start_span("dispatch.queue") as span:
+            if not leader:
+                entry[3].wait()
+                if span is not None:
+                    span.set_tag("role", "follower")
+                    span.set_tag("batch", entry[4])
+                if entry[2] is not None:
+                    raise entry[2]
+                return entry[1]
+            if self._window_s > 0.0:
+                _time.sleep(self._window_s)
+            with self._lock:
+                batch = self._queue
+                self._queue = []
+            if span is not None:
+                span.set_tag("role", "leader")
+                span.set_tag("batch", len(batch))
         try:
             t0 = _time.perf_counter()
             results = process([e[0] for e in batch])
@@ -129,6 +140,7 @@ class GroupCommit:
         finally:
             for e in batch:
                 if e is not entry:
+                    e[4] = len(batch)
                     e[3].set()
         return entry[1]
 
@@ -232,16 +244,51 @@ class _PhaseClock:
 def _device_get_batch(payloads):
     """GroupCommit `process` for plain result fetches: payloads are
     tuples of device values; ONE device_get resolves them all."""
-    import jax
-
     flat = [a for arrays in payloads for a in arrays]
-    vals = jax.device_get(flat)
+    vals = fetch(flat)
     out = []
     i = 0
     for arrays in payloads:
         out.append(vals[i:i + len(arrays)])
         i += len(arrays)
     return out
+
+
+
+def _named_jit(name, fn):
+    """`jax.jit(fn)` under a name of its own, the body inside a
+    `jax.named_scope` of the same name: the profiler's trace then says
+    `PjitFunction(<name>)` and `jit_<name>` where every program of this
+    module used to be `fn`, and the program's operations carry the scope."""
+    import jax
+
+    def program(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program)
+
+
+def _looked_up(span, outcome, stack, planes_uploaded=0):
+    """Tag a `stack.lookup` span with how the stack was come by (`hit`,
+    `stale`: served as it is under pending ingest deltas, `patch`,
+    `build`) and the planes that went to the device for it."""
+    if span is not None:
+        span.set_tag("outcome", outcome)
+        span.set_tag("planes_uploaded", planes_uploaded)
+    return stack
+
+
+def fetch(arrays):
+    """Every result fetch of the serving path: the wait for the device
+    and the device->host copy of `arrays`, as the `dispatch.fetch` stage
+    (outside the dispatch lock and outside `stacked.kernel`)."""
+    import jax
+
+    with _tracing.start_span("dispatch.fetch", arrays=len(arrays)):
+        return jax.device_get(arrays)
+
 
 from ..core.fragment import BSI_EXISTS_BIT, BSI_OFFSET_BIT, BSI_SIGN_BIT
 from ..core.index import EXISTENCE_FIELD_NAME
@@ -286,28 +333,6 @@ def batch_bucket(n):
         if n <= b:
             return b
     return BATCH_BUCKETS[-1]
-
-
-#: process-wide dispatch-phase aggregate, folded by _note_phases in
-#: lockstep with each evaluator's own table: incident bundles
-#: (utils/incident.py) read it without a handle on any evaluator, so a
-#: postmortem still carries which phase the dispatches wedged in.
-_GLOBAL_PHASES = {}
-_GLOBAL_PHASES_LOCK = threading.Lock()
-
-
-def global_dispatch_phases():
-    """{kernel: {phase: {count, seconds}}} across every evaluator in the
-    process (the incident bundle's dispatch collector)."""
-    with _GLOBAL_PHASES_LOCK:
-        return {k: {p: dict(v) for p, v in fam.items()}
-                for k, fam in _GLOBAL_PHASES.items()}
-
-
-def reset_global_dispatch_phases():
-    """Pristine module aggregate (tests)."""
-    with _GLOBAL_PHASES_LOCK:
-        _GLOBAL_PHASES.clear()
 
 
 #: thread-local batch attribution: the batch paths stamp how many
@@ -873,6 +898,12 @@ class StackedEvaluator:
         a cold build analyzes the host stack's measured density and
         picks dense / block-sparse / run-length per the configured
         --container-repr mode (ops/containers.choose)."""
+        _tracing.end_current("exec.plan")
+        with _tracing.start_span("stack.lookup", pool="leaf",
+                                 field=field_name) as span:
+            return self._leaf_stack(idx, field_name, row_id, shards, span)
+
+    def _leaf_stack(self, idx, field_name, row_id, shards, span):
         key = ("leaf", idx.name, field_name, row_id, shards)
         field = idx.field(field_name)
         view = field.view(VIEW_STANDARD) if field is not None else None
@@ -880,14 +911,14 @@ class StackedEvaluator:
             return None
         hit = self._cache_get_fast(key, (view.uid, view.mutations))
         if hit is not None:
-            return hit
+            return _looked_up(span, "hit", hit)
         stamp = (view.uid, view.mutations)
         gens = self._fragment_gens(idx, field_name, shards, view=view)
         if gens is None:
             return None
         hit = self._cache_get(key, gens, stamp)
         if hit is not None:
-            return hit
+            return _looked_up(span, "hit", hit)
         # Incremental maintenance: when k << S shards drifted (a write
         # bumps only its fragment's generation), gather + upload ONLY
         # those planes and scatter them into the cached device stack —
@@ -904,7 +935,7 @@ class StackedEvaluator:
         if stale is not None:
             if self._serve_stale(key, idx.name, field_name, VIEW_STANDARD,
                                  shards, stale, gens):
-                return stale[1]
+                return _looked_up(span, "stale", stale[1])
             changed = self._changed_shards(stale[0], gens, shards)
             if changed is not None:
                 import jax.numpy as jnp
@@ -926,7 +957,7 @@ class StackedEvaluator:
                 self._note_patch("read")
                 cont = _containers.dense_container(stack)
                 self._cache_put(key, gens, cont, cont.nbytes, stamp)
-                return cont
+                return _looked_up(span, "patch", cont, len(changed))
         host = self._host_rows(view, [row_id], shards)
         cont = _containers.build(
             host[0],
@@ -934,7 +965,7 @@ class StackedEvaluator:
             place_replicated=self._place_replicated,
             fragment=(idx.name, field_name, VIEW_STANDARD, row_id))
         self._cache_put(key, gens, cont, cont.nbytes, stamp)
-        return cont
+        return _looked_up(span, "build", cont, len(shards))
 
     def _host_rows(self, view, row_ids, shards, pad=True):
         """Host [R, S_padded, W] uint32 gather of rows over shards
@@ -1024,6 +1055,14 @@ class StackedEvaluator:
         builds a transient stack (freed after use) — callers pass it when
         the full candidate set exceeds the rows pool, so oversized scans
         don't churn out every reusable chunk."""
+        _tracing.end_current("exec.plan")
+        with _tracing.start_span("stack.lookup", pool="rows",
+                                 field=field_name) as span:
+            return self._rows_stack(idx, field_name, row_chunk, shards,
+                                    view_name, cache, span)
+
+    def _rows_stack(self, idx, field_name, row_chunk, shards, view_name,
+                    cache, span):
         key = ("rows", idx.name, field_name, view_name, row_chunk, shards)
         field = idx.field(field_name)
         view = field.view(view_name) if field is not None else None
@@ -1032,7 +1071,7 @@ class StackedEvaluator:
         if cache:
             hit = self._cache_get_fast(key, (view.uid, view.mutations))
             if hit is not None:
-                return hit
+                return _looked_up(span, "hit", hit)
         stamp = (view.uid, view.mutations)
         gens = self._fragment_gens(idx, field_name, shards, view_name,
                                    view=view)
@@ -1040,13 +1079,13 @@ class StackedEvaluator:
             return None
         hit = self._cache_get(key, gens, stamp if cache else None)
         if hit is not None:
-            return hit
+            return _looked_up(span, "hit", hit)
         if cache:
             stale = self._stale_entry(key, gens)
             if stale is not None:
                 if self._serve_stale(key, idx.name, field_name, view_name,
                                      shards, stale, gens):
-                    return stale[1]
+                    return _looked_up(span, "stale", stale[1])
                 changed = self._changed_shards(stale[0], gens, shards,
                                                rows=len(row_chunk))
                 if changed is not None:
@@ -1061,17 +1100,25 @@ class StackedEvaluator:
                     self._note_patch("read")
                     self._cache_put(key, gens, stack, stack.size * 4,
                                     stamp)
-                    return stack
+                    return _looked_up(span, "patch", stack,
+                                      len(row_chunk) * len(changed))
         host = self._host_rows(view, list(row_chunk), shards)
         stack = self._place(host, shard_axis=1)
         if cache:
             self._cache_put(key, gens, stack, stack.size * 4, stamp)
-        return stack
+        return _looked_up(span, "build", stack,
+                          len(row_chunk) * len(shards))
 
     def bsi_stack(self, idx, field_name, shards):
         """Cached (planes [D,S,W], sign [S,W], exists [S,W]) device stacks
         of a BSI field's bit-plane rows (reference layout fragment.go:91-93).
         None when the field/view vanished."""
+        _tracing.end_current("exec.plan")
+        with _tracing.start_span("stack.lookup", pool="bsi",
+                                 field=field_name) as span:
+            return self._bsi_stack(idx, field_name, shards, span)
+
+    def _bsi_stack(self, idx, field_name, shards, span):
         field = idx.field(field_name)
         if field is None:
             return None
@@ -1083,7 +1130,7 @@ class StackedEvaluator:
             return None
         hit = self._cache_get_fast(key, (view.uid, view.mutations))
         if hit is not None:
-            return hit
+            return _looked_up(span, "hit", hit)
         stamp = (view.uid, view.mutations)
         gens = self._fragment_gens(idx, field_name, shards, view_name,
                                    view=view)
@@ -1091,14 +1138,14 @@ class StackedEvaluator:
             return None
         hit = self._cache_get(key, gens, stamp)
         if hit is not None:
-            return hit
+            return _looked_up(span, "hit", hit)
         rows = [BSI_EXISTS_BIT, BSI_SIGN_BIT] + [
             BSI_OFFSET_BIT + i for i in range(depth)]
         stale = self._stale_entry(key, gens)
         if stale is not None:
             if self._serve_stale(key, idx.name, field_name, view_name,
                                  shards, stale, gens):
-                return stale[1]
+                return _looked_up(span, "stale", stale[1])
             changed = self._changed_shards(stale[0], gens, shards,
                                            rows=len(rows))
             if changed is not None:
@@ -1117,12 +1164,13 @@ class StackedEvaluator:
                 )
                 self._note_patch("read")
                 self._cache_put(key, gens, arrays, stale[2], stamp)
-                return arrays
+                return _looked_up(span, "patch", arrays,
+                                  len(rows) * len(changed))
         host = self._host_rows(view, rows, shards)
         arr = self._place(host, shard_axis=1)
         arrays = (arr[2:], arr[1], arr[0])  # planes, sign, exists
         self._cache_put(key, gens, arrays, arr.size * 4, stamp)
-        return arrays
+        return _looked_up(span, "build", arrays, len(rows) * len(shards))
 
     def bsi_condition_stack(self, idx, key, shards):
         """[S, W] mask of a BSI condition leaf evaluated over the cached
@@ -1231,7 +1279,9 @@ class StackedEvaluator:
         waited on the lock vs how long its kernel held it, emits a
         `stacked.kernel` child span (op=kind), and accumulates the
         profile's lock-wait/kernel-wall totals — the two numbers that
-        split "slow query" into contention vs compute.
+        split "slow query" into contention vs compute. The wait for the
+        lock is a span of its own, `dispatch.lock_wait`, beside
+        `stacked.kernel`, which opens once the lock is held.
 
         Yields a _PhaseClock: sites mark "dispatch_ack" after the
         program call returns and "sync" after the launch barrier, so a
@@ -1241,6 +1291,7 @@ class StackedEvaluator:
         the clock detect a first call (its key absent from the arg-spec
         cache) and relabel dispatch_ack as compile."""
         _check_thread_deadline()
+        _tracing.end_current("exec.plan")
         prof = _profile.current()
         _flightrec.record("dispatch.start", kernel=kind)
         token = _flightrec.watch_begin("dispatch." + kind)
@@ -1250,47 +1301,50 @@ class StackedEvaluator:
             compiling = key is not None and key not in self._fn_specs
         t0 = time.perf_counter()
         try:
-            with self._dispatch_lock:
+            with _tracing.start_span("dispatch.lock_wait", op=kind):
+                self._dispatch_lock.acquire()
+            try:
                 t1 = time.perf_counter()
                 ph = _PhaseClock(t1, compiling)
-                if prof is None:
+                with _tracing.start_span("stacked.kernel",
+                                         op=kind) as span:
                     yield ph
-                else:
-                    with _tracing.start_span("stacked.kernel",
-                                             op=kind) as span:
-                        if span is not None:
-                            span.set_tag("lock_wait_seconds",
-                                         round(t1 - t0, 6))
-                        yield ph
-                        if span is not None:
-                            for phase, dt in ph.phases:
-                                span.set_tag(f"phase_{phase}_seconds",
-                                             round(dt, 6))
-                t2 = time.perf_counter()
+                    if span is not None:
+                        for phase, dt in ph.phases:
+                            span.set_tag(f"phase_{phase}_seconds",
+                                         round(dt, 6))
+            finally:
+                self._dispatch_lock.release()
+            t2 = time.perf_counter()
         finally:
             _flightrec.watch_end(token)
-        wait, wall = t1 - t0, t2 - t1
-        # fold the residual (span bookkeeping, unmarked tails) into the
-        # last phase so the phases sum exactly to the dispatch wall; a
-        # site that never marked attributes its whole wall in one piece
-        if ph.phases:
-            ph.phases[-1][1] += t2 - ph._t
-        else:
-            ph.phases.append(["compile" if compiling else "dispatch_ack",
-                              wall])
-        phases = [("lock_wait", wait)] + [tuple(p) for p in ph.phases]
-        self._note_kernel(kind, wall, nbytes_in, nbytes_out)
-        self._note_phases(kind, phases)
-        _flightrec.record("dispatch.end", kernel=kind,
-                          lock_wait_seconds=round(wait, 6),
-                          kernel_wall_seconds=round(wall, 6))
-        if prof is not None:
-            prof.add("dispatch_lock_wait_seconds", wait)
-            prof.add("kernel_wall_seconds", wall)
-            prof.add("locked_dispatches", 1)
-            for phase, dt in phases:
-                if phase != "lock_wait":  # already counted above
-                    prof.add(f"phase_{phase}_seconds", dt)
+        # dispatch.account: the always-on bookkeeping after a launch
+        # (kernel and phase tables, their histograms, the flight
+        # recorder, the profile's tags), off the lock
+        with _tracing.start_span("dispatch.account", op=kind):
+            wait, wall = t1 - t0, t2 - t1
+            # fold the residual (span bookkeeping, unmarked tails) into
+            # the last phase so the phases sum exactly to the dispatch
+            # wall; a site that never marked attributes its whole wall in
+            # one piece
+            if ph.phases:
+                ph.phases[-1][1] += t2 - ph._t
+            else:
+                ph.phases.append(
+                    ["compile" if compiling else "dispatch_ack", wall])
+            phases = [("lock_wait", wait)] + [tuple(p) for p in ph.phases]
+            self._note_kernel(kind, wall, nbytes_in, nbytes_out)
+            self._note_phases(kind, phases)
+            _flightrec.record("dispatch.end", kernel=kind,
+                              lock_wait_seconds=round(wait, 6),
+                              kernel_wall_seconds=round(wall, 6))
+            if prof is not None:
+                prof.add("dispatch_lock_wait_seconds", wait)
+                prof.add("kernel_wall_seconds", wall)
+                prof.add("locked_dispatches", 1)
+                for phase, dt in phases:
+                    if phase != "lock_wait":  # already counted above
+                        prof.add(f"phase_{phase}_seconds", dt)
 
     def _note_kernel(self, kind, wall, nbytes_in, nbytes_out):
         """Per-kernel-family attribution (see /debug/kernels)."""
@@ -1323,13 +1377,6 @@ class StackedEvaluator:
                     p = fam[phase] = {"count": 0, "seconds": 0.0}
                 p["count"] += 1
                 p["seconds"] += dt
-        # mirror into the process-wide aggregate incident bundles read
-        with _GLOBAL_PHASES_LOCK:
-            gfam = _GLOBAL_PHASES.setdefault(kind, {})
-            for phase, dt in phases:
-                gp = gfam.setdefault(phase, {"count": 0, "seconds": 0.0})
-                gp["count"] += 1
-                gp["seconds"] += dt
         for phase, dt in phases:
             global_stats.timing("dispatch_phase_seconds", dt,
                                 {"kernel": kind, "phase": phase})
@@ -1405,17 +1452,12 @@ class StackedEvaluator:
         all-dense signatures trace to EXACTLY the legacy tree-eval +
         popcount program (to_dense is the identity), which is the
         forced-dense bit-identity guarantee."""
-        import jax
-
         csig = _containers.norm_csig(csig)
 
         def build():
-            @jax.jit
-            def fn(*flat):
-                return _containers.count_program(
-                    sig, csig, flat, self._tree_eval)
-
-            return fn
+            return _named_jit(
+                "count_tree", lambda *flat: _containers.count_program(
+                    sig, csig, flat, self._tree_eval))
 
         return self._get_fn(("count", sig, csig), build)
 
@@ -1425,14 +1467,12 @@ class StackedEvaluator:
         are [batch] (hi, lo) vectors. This is bench.py's batched-serving
         trick productionized (VERDICT r3 item 5): one dispatch + one fetch
         amortize the per-query round trip across every concurrent query."""
-        import jax
         import jax.numpy as jnp
 
         csig = _containers.norm_csig(csig)
         af = _containers.flat_arity(csig)
 
         def build():
-            @jax.jit
             def fn(*all_flat):
                 his, los = [], []
                 for q in range(batch):
@@ -1443,7 +1483,7 @@ class StackedEvaluator:
                     los.append(lo)
                 return jnp.stack(his), jnp.stack(los)
 
-            return fn
+            return _named_jit("count_batch", fn)
 
         return self._get_fn(("countB", sig, csig, batch), build)
 
@@ -1457,7 +1497,6 @@ class StackedEvaluator:
         and overlay-carrying containers side by side. Outputs are
         [n_calls] (hi, lo) vectors — the same 16-bit overflow-split
         contract as every count program."""
-        import jax
         import jax.numpy as jnp
 
         plans = tuple((sig, _containers.norm_csig(csig))
@@ -1465,7 +1504,6 @@ class StackedEvaluator:
         key = ("fused", plans)
 
         def build():
-            @jax.jit
             def fn(*all_flat):
                 his, los = [], []
                 i = 0
@@ -1478,7 +1516,7 @@ class StackedEvaluator:
                     los.append(lo)
                 return jnp.stack(his), jnp.stack(los)
 
-            return fn
+            return _named_jit("fused_count", fn)
 
         return self._get_fn(key, build), key
 
@@ -1539,8 +1577,6 @@ class StackedEvaluator:
         (sig, stacks) pairs; returns (count, fused-batch size) pairs in
         order — the size is how many REAL queries shared the payload's
         dispatch (padding excluded)."""
-        import jax
-
         groups = {}
         for pos, (sig, stacks) in enumerate(payloads):
             csig = tuple(c.csig for c in stacks)
@@ -1575,7 +1611,7 @@ class StackedEvaluator:
                     ph.mark("sync")
                 outs.append((chunk, his, los))
         flat = [a for _, h, l in outs for a in (h, l)]
-        vals = jax.device_get(flat)  # ONE transfer for everything
+        vals = fetch(flat)  # ONE transfer for everything
         results = [None] * len(payloads)
         i = 0
         for chunk, _, _ in outs:
@@ -1591,17 +1627,12 @@ class StackedEvaluator:
         Compressed leaves decompress in-program (exact by construction)
         so the output is always the legacy dense plane; `csig` accepts a
         legacy arity int for raw dense args (time_union fold)."""
-        import jax
-
         csig = _containers.norm_csig(csig)
 
         def build():
-            @jax.jit
-            def fn(*flat):
-                return _containers.plane_program(
-                    sig, csig, flat, self._tree_eval)
-
-            return fn
+            return _named_jit(
+                "plane_tree", lambda *flat: _containers.plane_program(
+                    sig, csig, flat, self._tree_eval))
 
         return self._get_fn(("plane", sig, csig), build)
 
@@ -1631,7 +1662,6 @@ class StackedEvaluator:
             vprog = jax.vmap(lambda *flat: _containers.count_program(
                 sig, csig, flat, self._tree_eval))
 
-            @jax.jit
             def fn(*flat):
                 # flat is query-major: flat[q*af + j] = query q's j-th
                 # component, so flat[j::af] gathers slot j across the
@@ -1639,7 +1669,7 @@ class StackedEvaluator:
                 slots = [jnp.stack(flat[j::af]) for j in range(af)]
                 return vprog(*slots)
 
-            return fn
+            return _named_jit("count_vmap", fn)
 
         return self._get_fn(("countV", sig, csig, bucket), build)
 
@@ -1655,12 +1685,11 @@ class StackedEvaluator:
             vprog = jax.vmap(lambda *flat: _containers.plane_program(
                 sig, csig, flat, self._tree_eval))
 
-            @jax.jit
             def fn(*flat):
                 slots = [jnp.stack(flat[j::af]) for j in range(af)]
                 return vprog(*slots)
 
-            return fn
+            return _named_jit("plane_vmap", fn)
 
         return self._get_fn(("planeV", sig, csig, bucket), build)
 
@@ -1750,15 +1779,13 @@ class StackedEvaluator:
         The dispatch index identifies which fused launch served the
         item, so the caller can attribute each dispatch exactly once
         across the members that rode it."""
-        import jax
-
         flat = []
         for kind, _, _, out in launched:
             if kind == "count":
                 flat.extend(out)  # (hi, lo)
             else:
                 flat.append(out)
-        vals = jax.device_get(flat)
+        vals = fetch(flat)
         results = {}
         i = 0
         for di, (kind, chunk, bucket, _) in enumerate(launched):
@@ -1793,8 +1820,8 @@ class StackedEvaluator:
                 return bitplane.hi_lo(per_shard, axis=-1)
 
             if has_filt:
-                return jax.jit(lambda rows, filt: counts(rows, filt))
-            return jax.jit(lambda rows: counts(rows, None))
+                return _named_jit("row_counts", counts)
+            return _named_jit("row_counts", lambda rows: counts(rows, None))
 
         return self._get_fn(("row_counts", has_filt), build)
 
@@ -1821,9 +1848,9 @@ class StackedEvaluator:
                         *bitplane.hi_lo(cc))
 
             if has_filt:
-                return jax.jit(kernel)
-            return jax.jit(
-                lambda planes, sign, exists: kernel(
+                return _named_jit("bsi_sum", kernel)
+            return _named_jit(
+                "bsi_sum", lambda planes, sign, exists: kernel(
                     planes, sign, exists, None))
 
         return self._get_fn(("sum", has_filt), build)
@@ -1869,9 +1896,9 @@ class StackedEvaluator:
                 return (empty, use_neg, bits, *bitplane.hi_lo(per_shard))
 
             if has_filt:
-                return jax.jit(kernel)
-            return jax.jit(
-                lambda planes, sign, exists: kernel(
+                return _named_jit("bsi_minmax", kernel)
+            return _named_jit(
+                "bsi_minmax", lambda planes, sign, exists: kernel(
                     planes, sign, exists, None))
 
         return self._get_fn(("minmax", has_filt, is_max), build)
@@ -2142,14 +2169,16 @@ class StackedEvaluator:
         return mag, combine_hi_lo(c_hi, c_lo)
 
     def counters(self):
-        """(dispatches, hits, misses, planes_uploaded) — the per-query
-        delta source for the always-on workload table. A bare tuple read
-        instead of the full cache_stats() dict: this runs twice per
-        query, and the workload_overhead bench gates the sum at <2% of
-        query wall."""
+        """(dispatches, hits, misses, planes_uploaded,
+        pairwise_dispatches, pairwise_syncs) — the per-query delta source
+        for the always-on workload table and for a query's profile. A
+        bare tuple read instead of the full cache_stats() dict: this runs
+        twice per query, and the workload_overhead bench gates the sum at
+        <2% of query wall."""
         with self._lock:
             return (self.dispatches, self.hits, self.misses,
-                    self.planes_uploaded)
+                    self.planes_uploaded, self.pairwise_dispatches,
+                    self.pairwise_syncs)
 
     def cache_stats(self):
         """Snapshot for /debug/vars: hit rate and byte pressure reveal

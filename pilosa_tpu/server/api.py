@@ -933,9 +933,12 @@ class API:
             return
 
         def run():
+            from ..utils import tracing
+
             try:
-                self.holder.sync_fragments()
-                self.oplog.checkpoint()
+                with tracing.annotate("oplog.rotate_checkpoint"):
+                    self.holder.sync_fragments()
+                    self.oplog.checkpoint()
             except Exception as e:  # noqa: BLE001 — retried at next rotate
                 self.logger.printf(
                     "oplog checkpoint after rotation failed: %s", e)
@@ -1086,7 +1089,11 @@ class API:
                     # and the stacked kernel dispatches — joins its trace
                     stack.enter_context(tracing.with_span(prof.root))
                 with tracing.start_span("api.Query", index=index_name):
-                    query = parse(pql) if isinstance(pql, str) else pql
+                    if isinstance(pql, str):
+                        with tracing.start_span("pql.parse"):
+                            query = parse(pql)
+                    else:
+                        query = pql
                     results = self.executor.execute(
                         index_name, query, shards=shards, options=options)
         except (ApiError,):

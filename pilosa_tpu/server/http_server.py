@@ -696,6 +696,7 @@ class PilosaHTTPServer:
         http/handler.go:281), plus the stacked-evaluator cache gauges."""
         import json as _json
 
+        from ..utils import tracing
         from ..utils.stats import registry_of
 
         out = _json.loads(registry_of(self.stats).expvar_json())
@@ -703,6 +704,11 @@ class PilosaHTTPServer:
         local = getattr(ex, "local", ex)  # ClusterExecutor wraps Executor
         if hasattr(local, "stacked_stats"):
             out["stacked"] = local.stacked_stats()
+        # finished live spans by name (wall, self, thread CPU); all CPU of
+        # the process, runtime threads and edge included; cache flushes
+        out["spans"] = tracing.span_stats()
+        out["process"] = {"cpu_seconds": time.process_time()}
+        out["holder"] = self.api.holder.flush_stats()
         if self.api.spmd is not None:
             out["spmd"] = self.api.spmd.stats()
         from ..utils import workpool
@@ -1090,8 +1096,28 @@ class PilosaHTTPServer:
             def log_message(self, fmt, *args):
                 pass
 
+            # A profiled request also marks its stay at the edge in the
+            # profiler's trace (the profile's root opens only in
+            # api.query): `http.parse` the request line and headers,
+            # `http.request` routing, the call, encoding and the write.
+            # What is left between two requests is the wait for the client.
+
+            def parse_request(self):
+                if b"profile=true" in self.raw_requestline:
+                    from ..utils import tracing
+
+                    with tracing.annotate("http.parse"):
+                        return super().parse_request()
+                return super().parse_request()
+
             def _dispatch(self):
-                server.dispatch(self)
+                if "profile=true" in self.path:
+                    from ..utils import tracing
+
+                    with tracing.annotate("http.request"):
+                        server.dispatch(self)
+                else:
+                    server.dispatch(self)
 
             do_GET = do_POST = do_DELETE = do_OPTIONS = _dispatch
 

@@ -44,7 +44,7 @@ import threading
 import time
 import zlib
 
-from ..utils import faultpoints, flightrec
+from ..utils import faultpoints, flightrec, tracing
 from ..utils.stats import global_stats
 
 #: record header: payload length, crc32(payload), lsn
@@ -137,8 +137,10 @@ def _syncer_loop():
         with _dirty_lock:
             batch = list(_dirty)
             _dirty.clear()
-        for f in batch:
-            fsync_file(f)
+        if batch:
+            with tracing.annotate("oplog.fsync_interval"):
+                for f in batch:
+                    fsync_file(f)
 
 
 # -- the oplog ---------------------------------------------------------------

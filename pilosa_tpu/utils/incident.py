@@ -305,10 +305,6 @@ def _default_collectors():
         from . import devhealth
         return devhealth.snapshot(limit=64)
 
-    def dispatch():
-        from ..exec.stacked import global_dispatch_phases
-        return {"phases": global_dispatch_phases()}
-
     def workload_():
         from . import workload
         return workload.table().snapshot(top=20)
@@ -345,7 +341,7 @@ def _default_collectors():
         from ..cluster import spmd as spmd_mod
         return spmd_mod.observatory_snapshot()
 
-    return {"device": device, "dispatch": dispatch,
+    return {"device": device,
             "workload": workload_, "heat": heat, "slo": slo,
             "fusion": fusion, "queries": queries,
             "open_ops": open_ops, "traces": traces, "spmd": spmd}

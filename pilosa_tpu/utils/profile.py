@@ -24,6 +24,7 @@ GET /debug/queries) and are stashed per-thread for the HTTP handler to
 attach to the response (`take_last()`).
 """
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -36,6 +37,14 @@ MAX_PROFILE_SPANS = 512
 
 #: finished profiles retained for GET /debug/queries
 MAX_RECENT = 128
+
+#: one profile in this many has its spans read the thread-CPU clock, and
+#: counts as many times in the per-name CPU sums (tracing.count_span):
+#: 30 reads a Count at 6-13 µs each cost the chip's host more than the
+#: rest of the profile, and its clock ticks every 10 ms, so only sums over
+#: thousands of queries say anything — which one query in eight gives too
+CPU_SAMPLE = 8
+_turn = itertools.count()
 
 _active = {}  # trace_id -> QueryProfile (only while the query runs)
 _recent = deque(maxlen=MAX_RECENT)
@@ -61,7 +70,8 @@ class QueryProfile:
         # nop tracer) so every start_span below it allocates a real child
         self.root = tracing.Span(
             "query", tracing.new_trace_id(), tracing.new_trace_id(),
-            None, {"index": index})
+            None, {"index": index},
+            cpu_weight=0 if next(_turn) % CPU_SAMPLE else CPU_SAMPLE)
 
     # -- collection (called from arbitrary query threads) --------------------
 
@@ -112,10 +122,11 @@ class QueryProfile:
         self.root.finish()
         self.duration = self.root.duration
         _active.pop(self.root.trace_id, None)
-        # the root span bypasses start_span, so index it here — this is
-        # what lets GET /debug/traces/{trace_id} resolve a profiled query
-        # (e.g. from a metrics exemplar) after it finished
+        # the root span bypasses start_span, so index and count it here —
+        # this is what lets GET /debug/traces/{trace_id} resolve a
+        # profiled query (e.g. from a metrics exemplar) after it finished
         tracing.index_span(self.root)
+        tracing.count_span(self.root)
         if self.slow_threshold is not None \
                 and self.duration > self.slow_threshold:
             self.slow = True
@@ -135,14 +146,8 @@ class QueryProfile:
             spans = list(self._spans)
             tags = dict(self._tags)
             dropped = self._dropped
-        nodes = {}
-        for s in spans:
-            nodes[s.span_id] = dict(
-                name=s.name, start=s.start, duration=s.duration,
-                tags=dict(s.tags), children=[])
-        root = dict(name=self.root.name, start=self.root.start,
-                    duration=self.root.duration, tags=dict(self.root.tags),
-                    children=[])
+        nodes = {s.span_id: _node(s) for s in spans}
+        root = _node(self.root)
         for s in spans:
             parent = nodes.get(s.parent_id)
             (parent["children"] if parent is not None
@@ -160,6 +165,22 @@ class QueryProfile:
         if dropped:
             out["spansDropped"] = dropped
         return out
+
+
+def _node(span):
+    """One span of a profile's tree: wall `duration`, thread `cpu`, and
+    `self` / `selfCpu` (its own less its same-thread children's), to a
+    tenth of a microsecond: seventeen digits a number only make the reply
+    longer to encode, send and parse."""
+    return dict(name=span.name, start=span.start,
+                duration=_tenth_us(span.duration), cpu=_tenth_us(span.cpu),
+                self=_tenth_us(span.self_time),
+                selfCpu=_tenth_us(span.self_cpu),
+                tags=dict(span.tags), children=[])
+
+
+def _tenth_us(seconds):
+    return None if seconds is None else round(seconds, 7)
 
 
 def begin(index, query, slow_threshold=None):

@@ -6,16 +6,31 @@ shape: a process-global `Tracer` defaulting to nop, spans started on every
 executor/API hot path, and trace context propagated across nodes via HTTP
 headers (reference: http/handler.go extractTracing / http/client.go inject).
 
-Backends: `NopTracer` (default, zero overhead), `InMemoryTracer` (tests +
-/debug inspection), and — when opentelemetry happens to be importable —
-`OTelTracer` adapting to an OTel tracer. No hard OTel dependency.
+Backends: `NopTracer` (default) and `InMemoryTracer` (tests, `--tracing
+memory`, /debug inspection). With the nop tracer installed and nothing
+live on the calling thread (no profiled query, no continued remote trace)
+`start_span` hands back one shared no-op object: no Span exists, no clock
+is read.
+
+A live span records, besides its wall duration, the CPU its thread burned
+(`time.thread_time()`), and at finish its `self` time and `self` CPU: its
+own less what its same-thread children covered (children report into the
+parent as they finish). At 32 callers a stage's wall is mostly waiting for
+the interpreter lock; the CPU is what the stage costs the host. Finished
+spans are summed by name into `span_stats()` (GET /debug/vars `spans`).
+
+A live span also holds a `jax.profiler.TraceAnnotation` of its name, so
+under a profiler session (`jax.profiler.start_trace`) every stage is a
+host event on the thread that ran it, on the clock of the device's
+operations. JAX is never imported from here: a process that has not
+imported it cannot be under its profiler.
 """
 
-import contextlib
-import random
+import sys
 import threading
 import time
 from collections import OrderedDict
+from random import getrandbits
 
 TRACE_HEADER = "X-Pilosa-Trace-Id"
 PARENT_HEADER = "X-Pilosa-Span-Id"
@@ -23,51 +38,159 @@ PARENT_HEADER = "X-Pilosa-Span-Id"
 _local = threading.local()
 
 
+_trace_annotation = None
+
+
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation`, or None in a process that has not
+    imported JAX (looked up once it has)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            _trace_annotation = profiler.TraceAnnotation
+    return _trace_annotation
+
+
+def annotate(name):
+    """Context manager marking `name` in the profiler's trace and nothing
+    else: for the loops that run beside requests (cache flush, oplog
+    sync, ingest merge, snapshot queue), a few times a second at most.
+    Under half a microsecond with no profiler session."""
+    cls = _annotation_class()
+    return _NOOP if cls is None else cls(name)
+
+
 class Span:
-    """One timed operation. Finished spans carry duration + tags.
+    """One timed operation. Finished spans carry duration, CPU and tags.
 
     `start` is wall-clock (for display and cross-node alignment);
     `duration` is measured on the monotonic clock so NTP steps and
     operator clock changes cannot corrupt it — durations feed both the
     profile tree and the skew estimator, which assumes they are real
-    elapsed time."""
+    elapsed time. `cpu` is the thread-CPU clock's difference on the
+    thread that ran the span; `self_time` / `self_cpu` are the span's own
+    less its same-thread children's.
+
+    The CPU clock is a system call, and on the chip's host a slow and a
+    coarse one (6 to 13 µs a read, advancing in 10 ms ticks: one span's
+    `cpu` there reads 0 or 0.01, and only sums over many spans mean
+    anything). So a span may go unclocked (`cpu_weight` 0: `cpu` and
+    `self_cpu` stay None), and a clocked one counts `cpu_weight` times in
+    the per-name sums: utils/profile.py clocks one query in
+    CPU_SAMPLE and weighs it by as much, which leaves the sums unbiased.
+    A span inherits its parent's weight.
+
+    As a context manager a span is the thread's active span from
+    `__enter__` to `__exit__`, which finishes and publishes it."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "tags",
-                 "start", "duration", "_t0")
+                 "start", "duration", "cpu", "self_time", "self_cpu",
+                 "cpu_weight", "_t0", "_c0", "_kids", "_kids_cpu",
+                 "_parent", "_tid", "_prev", "_ann")
 
-    def __init__(self, name, trace_id, span_id, parent_id, tags):
+    def __init__(self, name, trace_id, span_id, parent_id, tags,
+                 parent=None, cpu_weight=1):
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.tags = dict(tags)
+        self.tags = tags
+        self.duration = self.cpu = self.self_time = self.self_cpu = None
+        self._kids = self._kids_cpu = 0.0
+        self._parent = parent
+        self.cpu_weight = cpu_weight if parent is None \
+            else parent.cpu_weight
+        self._tid = threading.get_ident()
+        self._prev = None
+        cls = _trace_annotation or _annotation_class()
+        if cls is None:
+            self._ann = None
+        else:
+            self._ann = cls(name)
+            self._ann.__enter__()
         self.start = time.time()
-        self.duration = None
+        self._c0 = time.thread_time() if self.cpu_weight else None
         self._t0 = time.perf_counter()
 
     @classmethod
     def from_dict(cls, d):
         """Rebuild a (finished) span from its to_dict shape — used when the
         coordinator merges spans fetched from remote nodes."""
-        span = cls(d.get("name", ""), d.get("traceID"), d.get("spanID"),
-                   d.get("parentID"), d.get("tags") or {})
+        span = cls.__new__(cls)
+        span.name = d.get("name", "")
+        span.trace_id = d.get("traceID")
+        span.span_id = d.get("spanID")
+        span.parent_id = d.get("parentID")
+        span.tags = dict(d.get("tags") or {})
         span.start = d.get("start")
         span.duration = d.get("duration")
+        span.cpu = d.get("cpu")
+        span.self_time = d.get("self")
+        span.self_cpu = d.get("selfCpu")
+        span._parent = span._ann = None
         return span
 
     def set_tag(self, key, value):
         self.tags[key] = value
 
     def finish(self):
+        """Stop the clocks (on the thread that started the span) and
+        report into the parent. Idempotent."""
+        if self.duration is not None:
+            return
+        duration = time.perf_counter() - self._t0
+        if self._c0 is not None:
+            cpu = self.cpu = max(0.0, time.thread_time() - self._c0)
+            self.self_cpu = max(0.0, cpu - self._kids_cpu)
+        else:
+            cpu = 0.0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.duration = duration
+        self.self_time = max(0.0, duration - self._kids)
+        parent = self._parent
+        if parent is not None and parent._tid == self._tid \
+                and parent.duration is None:
+            parent._kids += duration
+            parent._kids_cpu += cpu
+
+    def __enter__(self):
+        self._prev = getattr(_local, "span", None)
+        _local.span = self
+        return self
+
+    def __exit__(self, *exc):
         if self.duration is None:
-            self.duration = time.perf_counter() - self._t0
+            _local.span = self._prev
+            self.finish()
+            _publish(self)
+        return False
 
     def to_dict(self):
         """JSON shape for /debug/traces and query profiles."""
         return {"name": self.name, "traceID": self.trace_id,
                 "spanID": self.span_id, "parentID": self.parent_id,
                 "tags": dict(self.tags), "start": self.start,
-                "duration": self.duration}
+                "duration": self.duration, "cpu": self.cpu,
+                "self": self.self_time, "selfCpu": self.self_cpu}
+
+
+class _NoSpan:
+    """What `start_span` returns when nothing is live: one shared object,
+    `with ... as span` binds None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NOOP = _NoSpan()
 
 
 class NopTracer:
@@ -168,6 +291,7 @@ class TraceIndex:
 
 
 _global_tracer = NopTracer()
+_nop_tracer = True
 
 # Secondary finished-span consumer (utils/profile.py registers its
 # per-query router here). Separate from the tracer so query profiling
@@ -178,11 +302,18 @@ _span_sink = None
 # always present (zero-cost when no spans are created — see class doc).
 _trace_index = TraceIndex()
 
+# Finished live spans summed by name (GET /debug/vars `spans`): what an
+# operator reads after sampling a few ?profile=true queries, and how the
+# benchmark reads the CPU a stage costs.
+_span_stats = {}
+_span_stats_lock = threading.Lock()
+
 
 def set_tracer(tracer):
     """Install the process-global tracer (reference: tracing.go SetGlobal)."""
-    global _global_tracer
+    global _global_tracer, _nop_tracer
     _global_tracer = tracer if tracer is not None else NopTracer()
+    _nop_tracer = isinstance(_global_tracer, NopTracer)
 
 
 def get_tracer():
@@ -219,8 +350,43 @@ def get_trace(trace_id):
     return _trace_index.get(trace_id)
 
 
+def count_span(span):
+    """Add one finished span to the per-name sums (its CPU `cpu_weight`
+    times: it stands for that many spans that went unclocked)."""
+    with _span_stats_lock:
+        row = _span_stats.get(span.name)
+        if row is None:
+            row = _span_stats[span.name] = [0, 0.0, 0.0, 0.0, 0.0]
+        row[0] += 1
+        row[1] += span.duration
+        row[2] += span.self_time
+        if span.cpu is not None:
+            row[3] += span.cpu * span.cpu_weight
+            row[4] += span.self_cpu * span.cpu_weight
+
+
+def span_stats():
+    """{span name: {count, seconds, self_seconds, cpu_seconds,
+    self_cpu_seconds}} over every live span finished in this process."""
+    with _span_stats_lock:
+        rows = {name: list(row) for name, row in _span_stats.items()}
+    return {name: dict(zip(("count", "seconds", "self_seconds",
+                            "cpu_seconds", "self_cpu_seconds"), row))
+            for name, row in sorted(rows.items())}
+
+
+def _publish(span):
+    """A finished span goes to the tracer, to its query's profile, into
+    the trace index and into the per-name sums."""
+    _global_tracer.on_finish(span)
+    if _span_sink is not None:
+        _span_sink(span)
+    index_span(span)
+    count_span(span)
+
+
 def _new_id():
-    return "%016x" % random.getrandbits(64)
+    return "%016x" % getrandbits(64)
 
 
 def new_trace_id():
@@ -231,44 +397,53 @@ def current_span():
     return getattr(_local, "span", None)
 
 
-@contextlib.contextmanager
-def with_span(span):
+class with_span:
     """Adopt `span` as the active context on THIS thread (for worker
     threads continuing a request's trace; does not finish the span)."""
-    prev = current_span()
-    _local.span = span
-    try:
-        yield span
-    finally:
-        _local.span = prev
+
+    __slots__ = ("span", "_prev")
+
+    def __init__(self, span):
+        self.span = span
+
+    def __enter__(self):
+        self._prev = getattr(_local, "span", None)
+        _local.span = self.span
+        return self.span
+
+    def __exit__(self, *exc):
+        _local.span = self._prev
+        return False
 
 
-@contextlib.contextmanager
 def start_span(name, **tags):
     """Start a child of the current thread's active span (or a new trace).
+    Use as `with start_span(...) as span:`.
 
-    Nop-fast: when the global tracer is the NopTracer and there is no
-    incoming context, this allocates no Span at all.
+    With the nop tracer installed and no active span on this thread this
+    returns the one shared no-op object, whose `as` target is None: no
+    Span is allocated and no clock read.
     """
-    tracer = _global_tracer
-    parent = current_span()
-    if isinstance(tracer, NopTracer) and parent is None:
-        yield None
-        return
-    trace_id = parent.trace_id if parent else _new_id()
-    span = Span(name, trace_id, _new_id(),
-                parent.span_id if parent else None, tags)
-    prev = parent
-    _local.span = span
-    try:
-        yield span
-    finally:
-        _local.span = prev
-        span.finish()
-        tracer.on_finish(span)
-        if _span_sink is not None:
-            _span_sink(span)
-        index_span(span)
+    parent = getattr(_local, "span", None)
+    if parent is None:
+        if _nop_tracer:
+            return _NOOP
+        return Span(name, _new_id(), _new_id(), None, tags)
+    return Span(name, parent.trace_id, _new_id(), parent.span_id, tags,
+                parent)
+
+
+def end_current(name):
+    """Finish the thread's active span now if it is called `name`: for a
+    stage that ends where the next one begins and not where a block does
+    (`exec.plan` runs from execute_call to the first stack lookup; a
+    nested call's plan ends with it). The `with` that opened it then
+    exits as a no-op."""
+    span = getattr(_local, "span", None)
+    while span is not None and span.name == name \
+            and span._tid == threading.get_ident():
+        span.__exit__(None, None, None)
+        span = getattr(_local, "span", None)
 
 
 # -- cross-node propagation (reference: handler extractTracing / client
@@ -298,29 +473,14 @@ def _header_get(headers, name):
     return None
 
 
-@contextlib.contextmanager
 def span_from_headers(name, headers, **tags):
     """Continue a remote trace from incoming HTTP headers (case-insensitive
-    lookup — see _header_get)."""
+    lookup — see _header_get); without them, `start_span`."""
     trace_id = _header_get(headers, TRACE_HEADER)
-    parent_id = _header_get(headers, PARENT_HEADER)
     if trace_id is None:
-        with start_span(name, **tags) as span:
-            yield span
-        return
-    tracer = _global_tracer
-    span = Span(name, trace_id, _new_id(), parent_id, tags)
-    prev = current_span()
-    _local.span = span
-    try:
-        yield span
-    finally:
-        _local.span = prev
-        span.finish()
-        tracer.on_finish(span)
-        if _span_sink is not None:
-            _span_sink(span)
-        index_span(span)
+        return start_span(name, **tags)
+    return Span(name, trace_id, _new_id(),
+                _header_get(headers, PARENT_HEADER), tags)
 
 
 # -- cross-node assembly (Dapper, Sigelman et al. 2010 §5) ------------------

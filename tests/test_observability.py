@@ -158,8 +158,13 @@ def test_profile_span_tree_matches_dispatch_counters(tmp_path):
         assert tags["dispatch_lock_wait_seconds"] >= 0
         assert tags["bytes_materialized"] >= 0
         assert tags["cache_hits"] >= 0 and tags["cache_misses"] >= 0
-        for s in kernels:
-            assert s["tags"]["lock_wait_seconds"] >= 0
+        # the wait for the dispatch lock is a span of its own beside
+        # each kernel span (it was a tag on it)
+        waits = [s for s in _walk(root) if s["name"] == "dispatch.lock_wait"]
+        assert len(waits) == len(kernels)
+        assert all(s["duration"] >= 0 for s in waits)
+        assert tags["dispatch_lock_wait_seconds"] >= \
+            sum(s["duration"] for s in waits)
 
         # per-op latency histograms landed in the registry behind /metrics
         text = h.client._request("GET", "/metrics").decode()
@@ -174,6 +179,7 @@ def test_profile_off_by_default(tmp_path):
     h = ServerHarness(data_dir=str(tmp_path))
     try:
         profile_mod.clear_recent()
+        profile_mod.take_last()  # whatever an earlier test left here
         h.client.create_index("np")
         h.client.create_field("np", "f")
         h.client.query("np", "Set(1, f=10)")
@@ -487,3 +493,221 @@ def test_cluster_fanout_node_spans_and_profile():
     finally:
         tracing.set_tracer(None)
         c.close()
+
+
+# ------------------------------------------- stage spans and their counters
+
+
+STAGES = {  # span -> parent, for a stacked Count (ISSUE 26 section 2)
+    "api.Query": "query",
+    "pql.parse": "api.Query",
+    "exec.translate": "api.Query",
+    "executor.Execute": "api.Query",
+    "executor.executeCount": "executor.Execute",
+    "exec.plan": "executor.executeCount",
+    "stack.lookup": "executor.executeCount",
+    "dispatch.queue": "executor.executeCount",
+    "dispatch.lock_wait": "executor.executeCount",
+    "stacked.kernel": "executor.executeCount",
+    "dispatch.account": "executor.executeCount",
+    "dispatch.fetch": "executor.executeCount",
+}
+
+
+def _seed_count(h, index="sc", n_shards=4):
+    h.api.create_index(index)
+    for field in ("f", "g"):
+        h.api.create_field(index, field)
+        h.api.import_bits(
+            index, field, [1] * n_shards,
+            [s * SHARD_WIDTH + 5 for s in range(n_shards)])
+
+
+def _parents(node, parent=None, out=None):
+    out = {} if out is None else out
+    out.setdefault(node["name"], set()).add(parent)
+    for child in node["children"]:
+        _parents(child, node["name"], out)
+    return out
+
+
+def test_profiled_count_yields_every_stage_span(tmp_path, monkeypatch):
+    """A profiled Count through API.query: every stage span, each under
+    the span that was current where the work happened; children's
+    durations sum to no more than the parent's; self >= 0; cpu within the
+    wall (every profile clocked here, not one in CPU_SAMPLE)."""
+    from pilosa_tpu.exec.executor import ExecOptions
+
+    monkeypatch.setattr(profile_mod, "CPU_SAMPLE", 1)
+    h = ServerHarness(data_dir=str(tmp_path))
+    try:
+        _seed_count(h)
+        pql = "Count(Intersect(Row(f=1), Row(g=1)))"
+        assert h.api.query("sc", pql) == [4]
+        profile_mod.take_last()
+        assert h.api.query("sc", pql,
+                           options=ExecOptions(profile=True)) == [4]
+        root = profile_mod.take_last()["spans"]
+    finally:
+        h.close()
+    assert root["name"] == "query"
+    parents = _parents(root)
+    for name, parent in STAGES.items():
+        assert parents.get(name) == {parent}, (name, parents.get(name))
+    lookups = [s for s in _walk(root) if s["name"] == "stack.lookup"]
+    assert [s["tags"]["outcome"] for s in lookups] == ["hit", "hit"]
+    queue, = [s for s in _walk(root) if s["name"] == "dispatch.queue"]
+    assert queue["tags"] == {"role": "leader", "batch": 1}
+    kernel, = [s for s in _walk(root) if s["name"] == "stacked.kernel"]
+    assert "lock_wait_seconds" not in kernel["tags"]
+    for node in _walk(root):
+        assert node["self"] >= 0 and node["selfCpu"] >= 0
+        assert 0 <= node["cpu"] <= node["duration"] + 1e-3
+        # (the tree's numbers are rounded to a tenth of a microsecond)
+        assert sum(c["duration"] for c in node["children"]) \
+            <= node["duration"] + 1e-6
+        assert node["self"] == pytest.approx(
+            node["duration"] - sum(c["duration"] for c in node["children"]),
+            abs=1e-6)
+
+
+def test_one_profile_in_cpu_sample_is_clocked_and_weighed(monkeypatch):
+    """The thread-CPU clock is read for one profile in CPU_SAMPLE; its
+    spans count that many times in the CPU sums, the others not at all,
+    so the sums stay an unbiased estimate and every span is counted."""
+    import itertools
+
+    monkeypatch.setattr(profile_mod, "CPU_SAMPLE", 4)
+    monkeypatch.setattr(profile_mod, "_turn", itertools.count())
+    zero = dict.fromkeys(("count", "cpu_seconds", "self_cpu_seconds"), 0)
+    before = tracing.span_stats().get("sampled", zero)
+    spans = []
+    for _ in range(8):
+        prof = profile_mod.begin("i", "q")
+        with tracing.with_span(prof.root):
+            with tracing.start_span("sampled") as span:
+                sum(range(20000))
+        tree = prof.finish()["spans"]
+        spans.append(span)
+        assert tree["children"][0]["cpu"] == (
+            None if span.cpu is None else round(span.cpu, 7))
+    clocked = [s for s in spans if s.cpu is not None]
+    assert [s.cpu is not None for s in spans] == [True, False, False, False] * 2
+    assert all(s.cpu_weight == 4 and s.self_cpu == s.cpu for s in clocked)
+    assert all(s.self_cpu is None and s.duration > 0 for s in spans
+               if s.cpu is None)
+    after = tracing.span_stats()["sampled"]
+    assert after["count"] - before["count"] == 8
+    assert after["cpu_seconds"] - before["cpu_seconds"] == pytest.approx(
+        4 * sum(s.cpu for s in clocked))
+
+
+def test_debug_vars_spans_process_and_holder(tmp_path):
+    """/debug/vars: `spans` gains one tree a profiled query and nothing
+    for an unprofiled one; `process.cpu_seconds` never falls;
+    `holder.cache_flushes` counts flush_caches runs."""
+    h = ServerHarness(data_dir=str(tmp_path))
+    try:
+        _seed_count(h)
+        pql = "Count(Intersect(Row(f=1), Row(g=1)))"
+        h.client.query("sc", pql)
+
+        def read():
+            return h.client._request("GET", "/debug/vars")
+
+        v0 = read()
+        for _ in range(3):
+            h.client.query("sc", pql)
+        v1 = read()
+        assert v1["spans"] == v0["spans"]  # nothing live: nothing counted
+        n = 5
+        for _ in range(n):
+            h.client.query("sc", pql, profile=True)
+        v2 = read()
+        for name in list(STAGES) + ["query"]:
+            row = v2["spans"][name]
+            assert set(row) == {"count", "seconds", "self_seconds",
+                                "cpu_seconds", "self_cpu_seconds"}
+            per_query = 2 if name in ("stack.lookup", "exec.translate") else 1
+            assert row["count"] - v1["spans"].get(
+                name, {"count": 0})["count"] == n * per_query, name
+        cpu = [v["process"]["cpu_seconds"] for v in (v0, v1, v2)]
+        assert cpu == sorted(cpu) and cpu[0] > 0
+        assert v2["holder"] == {"cache_flushes": 0,
+                                "cache_flush_seconds": 0.0}
+        h.holder.flush_caches()
+        v3 = read()
+        assert v3["holder"]["cache_flushes"] == 1
+        assert v3["holder"]["cache_flush_seconds"] >= 0
+    finally:
+        h.close()
+
+
+def test_a_flush_held_open_is_counted_at_read(tmp_path):
+    """cache_flush_seconds includes what a running flush has taken so far,
+    so after - before is the flushing inside a window wherever it falls."""
+    from pilosa_tpu.core import Holder
+
+    holder = Holder(str(tmp_path), cache_flush_interval=0).open()
+    try:
+        idx = holder.create_index("fl")
+        idx.create_field("f").set_bit(1, 3)
+        frag = next(iter(holder._all_fragments()))
+        entered, release = threading.Event(), threading.Event()
+
+        def held_flush():
+            entered.set()
+            release.wait(30)
+
+        frag.flush_cache = held_flush
+        t = threading.Thread(target=holder.flush_caches)
+        t.start()
+        assert entered.wait(30)
+        first = holder.flush_stats()
+        time.sleep(0.01)
+        second = holder.flush_stats()
+        assert first["cache_flushes"] == second["cache_flushes"] == 0
+        assert second["cache_flush_seconds"] > first["cache_flush_seconds"] > 0
+        release.set()
+        t.join(30)
+        done = holder.flush_stats()
+        assert done["cache_flushes"] == 1
+        assert done["cache_flush_seconds"] >= second["cache_flush_seconds"]
+        assert holder.flush_stats() == done  # nothing running: it stands
+    finally:
+        release.set()
+        holder.close()
+
+
+def test_stage_spans_reach_the_profilers_trace(tmp_path):
+    """Under jax.profiler.start_trace a profiled query leaves host events
+    named after its spans in the .xplane.pb, and the count program is
+    called by its own name, not `fn`."""
+    import glob
+
+    import jax
+
+    from pilosa_tpu.exec.executor import ExecOptions
+
+    h = ServerHarness(data_dir=str(tmp_path / "data"))
+    try:
+        _seed_count(h)
+        pql = "Count(Intersect(Row(f=1), Row(g=1)))"
+        h.api.query("sc", pql)
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            h.api.query("sc", pql, options=ExecOptions(profile=True))
+            h.holder.flush_caches()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        h.close()
+    path, = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    names = {e.name for plane in jax.profiler.ProfileData.from_file(
+        path).planes for line in plane.lines for e in line.events}
+    for want in ("query", "executor.Execute", "dispatch.fetch",
+                 "stack.lookup", "holder.flush_caches",
+                 "PjitFunction(count_tree)"):
+        assert want in names, want
+    assert "PjitFunction(fn)" not in names

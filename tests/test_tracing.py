@@ -175,3 +175,111 @@ def test_slow_query_log(tmp_path):
     finally:
         holder.close()
     assert any("SLOW QUERY" in line and "Count" in line for line in log.lines)
+
+
+# -- a span's own time and CPU, and the nothing-live path --------------------
+
+
+def test_nothing_live_is_one_shared_noop():
+    """With the nop tracer and no active span every start_span (and a
+    span_from_headers without headers) hands back the same object: no
+    Span is allocated and the per-name sums do not move."""
+    tracing.set_tracer(None)
+    before = tracing.span_stats()
+    made = []
+    real_init = tracing.Span.__init__
+
+    def counting_init(self, *a, **k):
+        made.append(a[0])
+        real_init(self, *a, **k)
+
+    tracing.Span.__init__ = counting_init
+    try:
+        first = tracing.start_span("x", index="i")
+        assert tracing.start_span("y") is first
+        assert tracing.span_from_headers("h", {}, method="GET") is first
+        with first as span:
+            assert span is None
+        tracing.end_current("exec.plan")  # nothing to end: a no-op
+    finally:
+        tracing.Span.__init__ = real_init
+    assert made == []
+    assert tracing.span_stats() == before
+
+
+def test_span_self_time_and_cpu(tracer):
+    """Children report into the parent as they finish: their durations sum
+    to no more than the parent's, self >= 0, 0 <= cpu <= duration + 1 ms."""
+    with tracing.start_span("parent"):
+        with tracing.start_span("a"):
+            sum(range(20000))
+        with tracing.start_span("b"):
+            with tracing.start_span("c"):
+                sum(range(20000))
+    by = {s.name: s for s in tracer.spans}
+    assert set(by) == {"parent", "a", "b", "c"}
+    for s in by.values():
+        assert s.self_time >= 0 and s.self_cpu >= 0
+        assert 0 <= s.cpu <= s.duration + 1e-3
+        assert s.self_time <= s.duration and s.self_cpu <= s.cpu
+    assert by["a"].duration + by["b"].duration <= by["parent"].duration
+    assert by["c"].duration <= by["b"].duration
+    assert by["parent"].self_time == pytest.approx(
+        by["parent"].duration - by["a"].duration - by["b"].duration)
+    assert by["a"].self_time == by["a"].duration  # a leaf is all its own
+    d = by["b"].to_dict()
+    assert d["self"] == by["b"].self_time and d["selfCpu"] == by["b"].self_cpu
+    assert d["cpu"] == by["b"].cpu
+    again = tracing.Span.from_dict(d)
+    assert (again.cpu, again.self_time, again.self_cpu) == (
+        d["cpu"], d["self"], d["selfCpu"])
+
+
+def test_child_on_another_thread_is_not_taken_off_self(tracer):
+    """A worker thread's span runs beside its parent: it keeps the parent
+    id, but the parent's own time is not reduced by it."""
+    import threading
+
+    with tracing.start_span("parent") as parent:
+        def work():
+            with tracing.with_span(parent):
+                with tracing.start_span("worker"):
+                    sum(range(20000))
+
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    worker, = tracer.find("worker")
+    assert worker.parent_id == parent.span_id
+    assert parent.self_time == parent.duration
+
+
+def test_end_current_ends_a_stage_early(tracer):
+    """exec.plan runs to the first stack lookup, not to the end of its
+    block: end_current finishes it there, the `with` exits as a no-op and
+    the next stage is its sibling."""
+    with tracing.start_span("call") as call:
+        with tracing.start_span("exec.plan"):
+            with tracing.start_span("exec.plan"):  # a nested call's plan
+                tracing.end_current("stack.lookup")  # another name: no-op
+                assert tracing.current_span().name == "exec.plan"
+                tracing.end_current("exec.plan")
+                assert tracing.current_span() is call
+                with tracing.start_span("stack.lookup") as lookup:
+                    assert lookup.parent_id == call.span_id
+        assert tracing.current_span() is call
+    assert len(tracer.find("exec.plan")) == 2  # each published once
+    assert [s.name for s in tracer.spans].count("stack.lookup") == 1
+
+
+def test_span_stats_sum_by_name(tracer):
+    before = tracing.span_stats().get("counted", {"count": 0})["count"]
+    for _ in range(3):
+        with tracing.start_span("counted"):
+            pass
+    row = tracing.span_stats()["counted"]
+    assert row["count"] == before + 3
+    assert set(row) == {"count", "seconds", "self_seconds", "cpu_seconds",
+                        "self_cpu_seconds"}
+    assert row["seconds"] >= row["self_seconds"] >= 0
+    assert row["cpu_seconds"] >= row["self_cpu_seconds"] >= 0
