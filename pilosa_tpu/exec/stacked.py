@@ -45,104 +45,114 @@ from . import adaptive as _adaptive
 from . import ingest as _ingest
 
 
+class _Waiter:
+    """One submitted payload in a GroupCommit: what its caller waits on.
+    `batch` is set (to the batch it must lead) when the previous leader
+    hands leadership to it; `size` is the size of the batch it rode."""
+
+    __slots__ = ("payload", "result", "error", "done", "batch", "size")
+
+    def __init__(self, payload):
+        self.payload = payload
+        self.result = self.error = self.batch = None
+        self.done = threading.Event()
+        self.size = 0
+
+
 class GroupCommit:
-    """Group-commit batching for the serving path (cross-query batching,
-    VERDICT r4 item 5 productionizing bench.py's batching trick).
+    """Group-commit batching for the serving path: ONE leader at a time.
 
     Per-query device work is already async — XLA queues each fused
-    program without blocking — but resolving a result costs one full
-    dispatch round trip, which can dwarf a sub-millisecond device pass.
-    Serving threads therefore amortize: the first thread to arrive becomes the
-    LEADER and drains everything queued, processing the WHOLE batch with
-    one `process` call (one device_get — or one fused multi-query program
-    + one device_get); threads that arrive while the leader works queue
-    up for the next leader. Leadership transfers by the emptiness rule:
-    whoever appends to an EMPTY queue leads. Zero added latency for a
-    lone query (its leader drains immediately); under concurrency, batch
-    size grows to the natural arrival rate — classic group commit.
+    program without blocking — but a launch and the fetch of its result
+    cost host time that can dwarf a sub-millisecond device pass, and
+    every thread that pays them pays under one interpreter lock. Serving
+    threads therefore share them: a caller that finds no batch in flight
+    LEADS at once, a batch of one; callers that arrive while a batch is
+    in flight queue, and when that batch is done the head of the queue
+    leads everything queued with one `process` call (one lock hold, one
+    fetch). No timer and no sleep: a lone query never waits, and under
+    concurrency a batch is whatever arrived during the previous one —
+    the slower a cycle, the larger the next batch.
 
-    A leader failure (compile error, device OOM, device loss) propagates
-    to EVERY waiter in its batch — events always fire, so no HTTP thread
-    can hang on a dead leader."""
-
-    #: a transport whose FASTEST batch is slower than this is
-    #: RTT-dominated; batching windows only engage then
-    RTT_DOMINATED_S = 0.02
-    #: leader pause before draining on RTT-dominated transports — lets
-    #: concurrent queries pile into the batch; small vs the round trip it
-    #: amortizes, and NEVER applied on fast local transports
-    WINDOW_S = 0.005
+    Whether a caller leads or queues, and to whom leadership passes, is
+    decided under the one `_lock`, so no waiter is ever left without a
+    leader. A leader failure (compile error, device OOM, device loss)
+    propagates to EVERY waiter of its batch and leadership still passes
+    on — events always fire, so no HTTP thread can hang on a dead
+    leader."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._queue = []
-        self._window_s = 0.0  # adaptive: engages once batches measure slow
-        # Minimum observed batch latency ~ the transport's round-trip
-        # floor: a large batch is slow everywhere, but only a transport
-        # whose FASTEST batch is still slow is RTT-dominated. Keying the
-        # window on the min keeps it off local devices even under bursts.
-        self._min_elapsed_s = float("inf")
-        # observability: batches/batched expose the achieved batching
-        # factor (batched/batches ≈ queries per round trip)
+        self._queue = []          # arrived while a batch is in flight
+        self._in_flight = False   # a leader is between taking and passing
+        # observability: batched / batches = queries per `process` call
         self.batches = 0
         self.batched = 0
 
     def submit(self, payload, process):
-        """Enqueue `payload`; the batch leader calls
-        `process([payloads...]) -> [results...]` once for everything it
-        drained. Returns this payload's result; re-raises the leader's
+        """Hand `payload` to the batch leader, who calls
+        `process([payloads...]) -> [results...]` once for its whole
+        batch. Returns this payload's result; re-raises the leader's
         exception if its batch failed."""
-        import time as _time
-
         _tracing.end_current("exec.plan")
-        # [payload, result, error, done, size of the batch it rode]
-        entry = [payload, None, None, threading.Event(), 0]
+        me = _Waiter(payload)
         with self._lock:
-            self._queue.append(entry)
-            leader = len(self._queue) == 1
-        # dispatch.queue: a follower's whole wait for its leader's batch,
-        # a leader's window sleep and drain
+            if self._in_flight:
+                self._queue.append(me)
+                batch = None
+            else:
+                self._in_flight = True
+                batch = [me]
+        # dispatch.queue: the wait for the batch in flight — a follower's
+        # lasts until its own batch is done, a leader's until it leads
         with _tracing.start_span("dispatch.queue") as span:
-            if not leader:
-                entry[3].wait()
-                if span is not None:
-                    span.set_tag("role", "follower")
-                    span.set_tag("batch", entry[4])
-                if entry[2] is not None:
-                    raise entry[2]
-                return entry[1]
-            if self._window_s > 0.0:
-                _time.sleep(self._window_s)
-            with self._lock:
-                batch = self._queue
-                self._queue = []
+            if batch is None:
+                me.done.wait()
+                # taken off the waiter: a waiter that kept the batch it
+                # is part of would be a reference cycle, and its payload
+                # (device stacks) would outlive the call until a
+                # collection
+                batch, me.batch = me.batch, None
+            follower = batch is None
             if span is not None:
-                span.set_tag("role", "leader")
-                span.set_tag("batch", len(batch))
+                span.set_tag("role", "follower" if follower else "leader")
+                span.set_tag("batch", me.size if follower else len(batch))
+            if follower:
+                if me.error is not None:
+                    raise me.error
+                return me.result
         try:
-            t0 = _time.perf_counter()
-            results = process([e[0] for e in batch])
-            elapsed = _time.perf_counter() - t0
-            # adapt: on an RTT-dominated transport a small leader pause
-            # turns the round trip into a shared cost; on a local device
-            # it would only add latency, so keep it off there
-            self._min_elapsed_s = min(self._min_elapsed_s, elapsed)
-            self._window_s = self.WINDOW_S \
-                if self._min_elapsed_s > self.RTT_DOMINATED_S else 0.0
-            self.batches += 1
-            self.batched += len(batch)
-            for e, r in zip(batch, results):
-                e[1] = r
+            results = process([w.payload for w in batch])
+            for w, r in zip(batch, results):
+                w.result = r
         except BaseException as exc:
-            for e in batch:
-                e[2] = exc
+            for w in batch:
+                w.error = exc
             raise
         finally:
-            for e in batch:
-                if e is not entry:
-                    e[4] = len(batch)
-                    e[3].set()
-        return entry[1]
+            # leadership passes on once the batch is fetched, not after
+            # its launch: on the chip both answered the same rate, and
+            # this way makes the fewest launches and fetches
+            self._pass_on(len(batch))
+            for w in batch:
+                if w is not me:
+                    w.size = len(batch)
+                    w.done.set()
+        return me.result
+
+    def _pass_on(self, done):
+        """End the batch in flight (of `done` payloads): the head of the
+        queue leads everything queued, or nothing is in flight any
+        more."""
+        with self._lock:
+            self.batches += 1
+            self.batched += done
+            batch, self._queue = self._queue, []
+            if not batch:
+                self._in_flight = False
+                return
+        batch[0].batch = batch
+        batch[0].done.set()
 
 
 #: serializes every multi-device launch in this process (see
@@ -327,6 +337,15 @@ _OPS = {"Intersect": "&", "Union": "|", "Difference": "-", "Xor": "^"}
 BATCH_BUCKETS = (1, 4, 16, 64)
 
 
+def _pow2_chunks(n, cap):
+    """`n` as the powers of two that add up to it, none over `cap`,
+    largest first: 37 under 32 is 32 + 4 + 1."""
+    while n:
+        size = min(cap, 1 << (n.bit_length() - 1))
+        yield size
+        n -= size
+
+
 def batch_bucket(n):
     """Smallest padding bucket holding `n` queries."""
     for b in BATCH_BUCKETS:
@@ -488,6 +507,12 @@ class StackedEvaluator:
         # fuse into ONE program per signature bucket + ONE fetch).
         self._fetch_commit = GroupCommit()
         self._count_commit = GroupCommit()
+        # device launches made for count batches, and chunks that went out
+        # as solo programs because their bucket was not built yet (its
+        # build thread is in _count_builds until the program is in _fns)
+        self.count_launches = 0
+        self.count_batch_fallbacks = 0
+        self._count_builds = {}
         self._lock = threading.Lock()
         # Multi-device dispatches must not interleave: stacks are
         # mesh-sharded, so every serving program is a GSPMD launch across
@@ -1265,8 +1290,13 @@ class StackedEvaluator:
             1, CHUNK_BYTES // (self._padded_len(shards) * WORDS_PER_ROW * 4))
 
     @contextlib.contextmanager
-    def _locked_dispatch(self, kind, nbytes_in=0, nbytes_out=0, fn=None):
-        """Hold the process-wide dispatch lock around one device launch.
+    def _locked_dispatch(self, kind, nbytes_in=0, nbytes_out=0, fn=None,
+                         check_deadline=True):
+        """Hold the process-wide dispatch lock around one device launch
+        (a count batch's leader: around all of its batch's launches, and
+        with `check_deadline` off — every caller of the batch checked
+        its own deadline before it queued, and the leader's must not
+        fail its followers).
 
         Always on (cheap — a few dict/deque ops vs ms-scale kernels;
         the flightrec + devhealth bench legs hold the total under 2% of
@@ -1290,7 +1320,8 @@ class StackedEvaluator:
         actuals). `fn` — when it is a _wrap_spec_capture kernel — lets
         the clock detect a first call (its key absent from the arg-spec
         cache) and relabel dispatch_ack as compile."""
-        _check_thread_deadline()
+        if check_deadline:
+            _check_thread_deadline()
         _tracing.end_current("exec.plan")
         prof = _profile.current()
         _flightrec.record("dispatch.start", kernel=kind)
@@ -1392,17 +1423,24 @@ class StackedEvaluator:
 
     # -- compiled kernels ----------------------------------------------------
 
-    def _get_fn(self, key, build):
+    def _cached_fn(self, key):
         with self._lock:
             fn = self._fns.get(key)
             if fn is not None:
                 self._fns.move_to_end(key)
-                return fn
-        fn = self._wrap_spec_capture(key, build())
+            return fn
+
+    def _cache_fn(self, key, fn):
         with self._lock:
             self._fns[key] = fn
             while len(self._fns) > MAX_FNS:
                 self._fns.popitem(last=False)
+
+    def _get_fn(self, key, build):
+        fn = self._cached_fn(key)
+        if fn is None:
+            fn = self._wrap_spec_capture(key, build())
+            self._cache_fn(key, fn)
         return fn
 
     def _wrap_spec_capture(self, key, fn):
@@ -1466,26 +1504,25 @@ class StackedEvaluator:
         program: args are batch*flat_arity container components, outputs
         are [batch] (hi, lo) vectors. This is bench.py's batched-serving
         trick productionized (VERDICT r3 item 5): one dispatch + one fetch
-        amortize the per-query round trip across every concurrent query."""
+        amortize the per-query round trip across every concurrent query.
+        Returns the jitted program itself; what serves is its compilation
+        for one group's shapes (_compile_count_bucket)."""
         import jax.numpy as jnp
 
         csig = _containers.norm_csig(csig)
         af = _containers.flat_arity(csig)
 
-        def build():
-            def fn(*all_flat):
-                his, los = [], []
-                for q in range(batch):
-                    flat = all_flat[q * af:(q + 1) * af]
-                    hi, lo = _containers.count_program(
-                        sig, csig, flat, self._tree_eval)
-                    his.append(hi)
-                    los.append(lo)
-                return jnp.stack(his), jnp.stack(los)
+        def fn(*all_flat):
+            his, los = [], []
+            for q in range(batch):
+                flat = all_flat[q * af:(q + 1) * af]
+                hi, lo = _containers.count_program(
+                    sig, csig, flat, self._tree_eval)
+                his.append(hi)
+                los.append(lo)
+            return jnp.stack(his), jnp.stack(los)
 
-            return _named_jit("count_batch", fn)
-
-        return self._get_fn(("countB", sig, csig, batch), build)
+        return _named_jit("count_batch", fn)
 
     def fused_count_fn(self, plans):
         """A whole query's Count trees fused into ONE program (exec/
@@ -1549,24 +1586,32 @@ class StackedEvaluator:
         counts = [combine_hi_lo(h, l) for h, l in zip(his_h, los_h)]
         return counts, key, compiled
 
-    #: count-batcher buckets: batch sizes are rounded up to a power of two
-    #: (padding repeats the first query) so at most log2(MAX) programs
-    #: compile per signature; 32 bounds device time per dispatch (32
-    #: passes over the leaf stacks) near the round trip it amortizes
+    #: largest fused count program: 32 bounds device time per launch (32
+    #: passes over the leaf stacks) near the round trip it amortizes. A
+    #: group is sent as the power-of-two chunks that add up to it
+    #: (5 = 4 + 1), so at most log2(MAX) + 1 programs compile per
+    #: signature and no launch reads a plane twice to pad itself
     MAX_COUNT_BATCH = 32
 
     def _batched_count(self, sig, stacks):
-        """Group-commit count execution: the batch leader drains every
-        queued count query, groups them by signature, runs one fused
-        program per group (power-of-two bucket, padded by repeating the
-        first query), fetches ALL results in one transfer, and
-        distributes. Solo queries pay nothing extra; leader failures
+        """Group-commit count execution: the batch leader takes every
+        count query that queued during the previous batch, groups them
+        by program (signature and stack shapes), launches each group as
+        its power-of-two chunks under ONE hold of the dispatch lock,
+        fetches ALL results in one transfer, and distributes. A lone
+        query leads at once and pays nothing extra; a follower makes no
+        launch, takes no lock and fetches nothing; leader failures
         propagate to every waiter (GroupCommit contract).
+
+        The caller looked its stacks up itself, before it queues here: a
+        read that follows an acknowledged write is answered from stacks
+        looked up after the ack, whoever leads its batch.
 
         The per-payload return is (count, fused-batch size); the size is
         stamped into the waiter's thread-local here so SLOW QUERY lines
         and strategy notes can attribute `batch=` without threading it
         through every caller."""
+        _check_thread_deadline()
         count, size = self._count_commit.submit(
             (sig, tuple(stacks)), self._process_count_batch)
         note_batch_size(size)
@@ -1575,52 +1620,100 @@ class StackedEvaluator:
     def _process_count_batch(self, payloads):
         """GroupCommit `process` for count queries: payloads are
         (sig, stacks) pairs; returns (count, fused-batch size) pairs in
-        order — the size is how many REAL queries shared the payload's
-        dispatch (padding excluded)."""
+        order — the size is how many queries shared the payload's
+        launch. Every query reads its own leaves (flat arguments, one
+        count_program a query, as _count_batch_fn builds them)."""
         groups = {}
+        nbytes_in = 0
         for pos, (sig, stacks) in enumerate(payloads):
-            csig = tuple(c.csig for c in stacks)
-            groups.setdefault((sig, csig), []).append(pos)
-        outs = []
-        for (sig_g, csig_g), positions in groups.items():
-            for i in range(0, len(positions), self.MAX_COUNT_BATCH):
-                chunk = positions[i:i + self.MAX_COUNT_BATCH]
-                size = 1 << (len(chunk) - 1).bit_length()
-                if size == 1:
-                    # solo query: reuse the plain count program (shared
-                    # with warm pre-batching traffic) instead of
-                    # compiling an identical batch-1 variant
-                    fn = self._count_fn(sig_g, csig_g)
+            flat = _containers.flatten(stacks)
+            # a group is one program: the dense csig carries no shape,
+            # and a compiled bucket takes one shape and sharding only
+            key = (sig, tuple(c.csig for c in stacks),
+                   tuple((a.shape, a.dtype, a.sharding) for a in flat))
+            groups.setdefault(key, []).append((pos, flat))
+            nbytes_in += sum(c.nbytes for c in stacks)
+        launches = []  # (program, flat arguments, positions answered)
+        unbuilt = []
+        for key, members in groups.items():
+            solo = self._count_fn(key[0], key[1])
+            at = 0
+            for size in _pow2_chunks(len(members), self.MAX_COUNT_BATCH):
+                chunk = members[at:at + size]
+                at += size
+                fn = solo if size == 1 else self._cached_fn(
+                    ("countB", key, size))
+                if fn is None:
+                    # never compile with followers waiting: send the
+                    # chunk as the program that exists, build afterwards
+                    unbuilt.append((key, size))
+                    launches.extend(
+                        (solo, flat, (pos,)) for pos, flat in chunk)
                 else:
-                    fn = self._count_batch_fn(sig_g, csig_g, size)
-                args = []
-                nbytes_in = 0
-                for pos in chunk:
-                    args.extend(_containers.flatten(payloads[pos][1]))
-                    nbytes_in += sum(c.nbytes for c in payloads[pos][1])
-                for _ in range(size - len(chunk)):
-                    args.extend(  # pad: repeat q0
-                        _containers.flatten(payloads[chunk[0]][1]))
-                    nbytes_in += sum(
-                        c.nbytes for c in payloads[chunk[0]][1])
-                with self._locked_dispatch(
-                        "count", nbytes_in=nbytes_in, fn=fn) as ph:
-                    his, los = fn(*args)
-                    ph.mark("dispatch_ack")
-                    _launch_barrier((his, los))
-                    ph.mark("sync")
-                outs.append((chunk, his, los))
-        flat = [a for _, h, l in outs for a in (h, l)]
-        vals = fetch(flat)  # ONE transfer for everything
+                    launches.append(
+                        (fn, [a for _, flat in chunk for a in flat],
+                         [pos for pos, _ in chunk]))
+        first = next((fn for fn, _, _ in launches
+                      if fn._spec_key not in self._fn_specs), None)
+        with self._locked_dispatch("count", nbytes_in=nbytes_in, fn=first,
+                                   check_deadline=False) as ph:
+            outs = [fn(*args) for fn, args, _ in launches]
+            ph.mark("dispatch_ack")
+            _launch_barrier(outs)
+            ph.mark("sync")
+            span = _tracing.current_span()
+            if span is not None:
+                span.set_tag("queries", len(payloads))
+                span.set_tag("launches", len(launches))
+        with self._lock:
+            self.count_launches += len(launches)
+            self.count_batch_fallbacks += len(unbuilt)
+        vals = fetch([a for out in outs for a in out])  # ONE transfer
         results = [None] * len(payloads)
-        i = 0
-        for chunk, _, _ in outs:
-            # atleast_1d: the solo path returns 0-d scalars
-            his, los = np.atleast_1d(vals[i]), np.atleast_1d(vals[i + 1])
-            i += 2
-            for q, pos in enumerate(chunk):
-                results[pos] = (combine_hi_lo(his[q], los[q]), len(chunk))
+        for i, (_, _, positions) in enumerate(launches):
+            # atleast_1d: the solo program returns 0-d scalars
+            his = np.atleast_1d(vals[2 * i])
+            los = np.atleast_1d(vals[2 * i + 1])
+            for q, pos in enumerate(positions):
+                results[pos] = (combine_hi_lo(his[q], los[q]),
+                                len(positions))
+        for key, size in unbuilt:
+            self._build_count_bucket(key, size)
         return results
+
+    def _build_count_bucket(self, key, size):
+        """Compile the `size`-query program of group `key` on a thread
+        of its own: off the dispatch lock, with nobody waiting on it.
+        One build a bucket; a failed build goes to the flight recorder
+        and the bucket's chunks keep going out as solos."""
+        fkey = ("countB", key, size)
+        with self._lock:
+            if fkey in self._count_builds or fkey in self._fns:
+                return
+            thread = self._count_builds[fkey] = threading.Thread(
+                target=self._compile_count_bucket, args=(fkey,),
+                name="count-bucket-build", daemon=True)
+        thread.start()
+
+    def _compile_count_bucket(self, fkey):
+        import jax
+
+        _, (sig, csig, args), size = fkey
+        specs = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                      for shape, dtype, sharding in args) * size
+        try:
+            jitted = self._count_batch_fn(sig, csig, size)
+            compiled = jitted.lower(*specs).compile()
+        except Exception as exc:  # noqa: BLE001 — the solos keep serving
+            _flightrec.record("count_bucket.build_failed", size=size,
+                              sig=repr(sig), error=repr(exc))
+            return
+        fn = self._wrap_spec_capture(fkey, compiled)
+        fn._jit_fn = jitted
+        self._fn_specs[fkey] = specs
+        self._cache_fn(fkey, fn)
+        with self._lock:
+            del self._count_builds[fkey]
 
     def _plane_fn(self, sig, csig):
         """Tree -> combined [S, W] plane stack (filter materialization).
@@ -2199,6 +2292,8 @@ class StackedEvaluator:
                 "group_fetched_queries": self._fetch_commit.batched,
                 "count_batches": self._count_commit.batches,
                 "count_batched_queries": self._count_commit.batched,
+                "count_launches": self.count_launches,
+                "count_batch_fallbacks": self.count_batch_fallbacks,
                 "batch_dispatches": self.batch_dispatches,
                 "batched_queries": self.batched_queries,
                 "fused_dispatches": self.fused_dispatches,

@@ -1,0 +1,446 @@
+"""Group commit on the serving path (ISSUE 27): one leader at a time in
+`GroupCommit`, and `_process_count_batch` — one hold of the dispatch lock,
+one fetch and no padded read a batch, never a compile with followers
+waiting. Nothing below is timed: threads meet on events, and `queued`
+polls the commit's own queue."""
+
+import json
+import sys
+import threading
+import time
+import urllib.request
+
+import numpy as np
+import pytest
+
+from pilosa_tpu.exec import stacked
+from pilosa_tpu.exec.stacked import GroupCommit, StackedEvaluator
+from pilosa_tpu.ops import containers
+from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_ROW
+
+WAIT = 30
+
+
+def start(fn, *args):
+    thread = threading.Thread(target=fn, args=args)
+    thread.start()
+    return thread
+
+
+def joined(threads):
+    for t in threads:
+        t.join(WAIT)
+    return not any(t.is_alive() for t in threads)
+
+
+def queued(commit, n):
+    """Spin until `n` callers stand in the queue (they queue under the
+    commit's lock, so what is seen there has arrived)."""
+    while len(commit._queue) < n:
+        time.sleep(0.001)
+
+
+class Gate:
+    """A `process` that records its batches and can be held inside."""
+
+    def __init__(self, hold=False):
+        self.batches, self.inside, self.most_inside = [], 0, 0
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        if not hold:
+            self.release.set()
+        self._lock = threading.Lock()
+
+    def __call__(self, payloads):
+        with self._lock:
+            self.batches.append(list(payloads))
+            self.inside += 1
+            self.most_inside = max(self.most_inside, self.inside)
+        self.entered.set()
+        assert self.release.wait(WAIT)
+        with self._lock:
+            self.inside -= 1
+        return [("done", p) for p in payloads]
+
+
+# ---------------------------------------------------------------- the class
+
+
+def test_a_lone_submit_leads_at_once():
+    """No batch in flight: the caller leads a batch of one on its own
+    thread, with nothing to wait for (no event of its own is ever set)."""
+    commit, gate = GroupCommit(), Gate()
+    called_on = []
+
+    def process(payloads):
+        called_on.append(threading.get_ident())
+        return gate(payloads)
+
+    assert commit.submit("a", process) == ("done", "a")
+    assert commit.submit("b", process) == ("done", "b")
+    assert gate.batches == [["a"], ["b"]]
+    assert called_on == [threading.get_ident()] * 2
+    assert (commit.batches, commit.batched) == (2, 2)
+    assert not commit._in_flight and not commit._queue
+
+
+def test_arrivals_during_a_batch_form_exactly_one_next_batch():
+    commit, gate = GroupCommit(), Gate(hold=True)
+    results = {}
+
+    def submit(p):
+        results[p] = commit.submit(p, gate)
+
+    first = start(submit, 0)
+    assert gate.entered.wait(WAIT)
+    late = []
+    for p in range(1, 6):
+        late.append(start(submit, p))
+        queued(commit, p)
+    assert gate.batches == [[0]]  # nobody led while the first was in flight
+    gate.release.set()
+    assert joined([first, *late])
+    assert gate.batches == [[0], [1, 2, 3, 4, 5]]
+    assert results == {p: ("done", p) for p in range(6)}
+    assert gate.most_inside == 1
+    assert (commit.batches, commit.batched) == (2, 6)
+    assert not commit._in_flight and not commit._queue
+
+
+def test_a_batchs_payloads_die_with_the_batch():
+    """No waiter, batch or result keeps a payload after its caller has
+    its answer: a payload holds device stacks (119 MiB each at a billion
+    columns), and a reference cycle would keep them until a collection."""
+    import gc
+    import weakref
+
+    class Payload:
+        pass
+
+    commit, gate = GroupCommit(), Gate(hold=True)
+    gate.batches = type("Forgetful", (), {"append": lambda self, b: None})()
+    alive = []
+
+    def submit():
+        payload = Payload()
+        alive.append(weakref.ref(payload))
+        commit.submit(payload, lambda ps: [None for _ in gate(ps)])
+
+    gc.disable()
+    try:
+        first = start(submit)
+        assert gate.entered.wait(WAIT)
+        late = []
+        for n in range(1, 4):
+            late.append(start(submit))
+            queued(commit, n)
+        gate.release.set()
+        assert joined([first, *late])
+        assert [ref() for ref in alive] == [None] * 4
+    finally:
+        gc.enable()
+
+
+def test_a_leaders_failure_reaches_its_batch_and_the_next_batch_runs():
+    commit = GroupCommit()
+    entered, release = threading.Event(), threading.Event()
+    seen = []
+
+    def process(payloads):
+        seen.append(list(payloads))
+        if len(seen) == 1:
+            entered.set()
+            assert release.wait(WAIT)
+            return [p for p in payloads]
+        if len(seen) == 2:
+            # the failing batch: hold until the third batch has queued
+            queued(commit, 1)
+            raise RuntimeError("device lost")
+        return [p * 10 for p in payloads]
+
+    outcomes = {}
+
+    def submit(p):
+        try:
+            outcomes[p] = commit.submit(p, process)
+        except RuntimeError as exc:
+            outcomes[p] = exc
+
+    first = start(submit, 1)
+    assert entered.wait(WAIT)
+    doomed = []
+    for n, p in enumerate((2, 3, 4), 1):
+        doomed.append(start(submit, p))
+        queued(commit, n)
+    release.set()          # batch 2 = [2, 3, 4] starts and waits for a 5
+    assert joined([first])
+    after = start(submit, 5)
+    assert joined([*doomed, after])
+    assert seen == [[1], [2, 3, 4], [5]]
+    assert outcomes[1] == 1 and outcomes[5] == 50
+    assert all(isinstance(outcomes[p], RuntimeError) for p in (2, 3, 4))
+    assert len({id(outcomes[p]) for p in (2, 3, 4)}) == 1
+    assert not commit._in_flight and not commit._queue
+    assert commit.submit(6, process) == 60   # and a lone caller still leads
+
+
+def test_64_threads_lose_no_wakeup_and_get_their_own_results():
+    commit = GroupCommit()
+    inside = [0, 0]
+    guard = threading.Lock()
+
+    def process(payloads):
+        with guard:
+            inside[0] += 1
+            inside[1] = max(inside[1], inside[0])
+        out = [(p[0], p[1], p[0] * 1000 + p[1]) for p in payloads]
+        with guard:
+            inside[0] -= 1
+        return out
+
+    wrong = []
+
+    def client(t):
+        for n in range(200):
+            if commit.submit((t, n), process) != (t, n, t * 1000 + n):
+                wrong.append((t, n))
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [start(client, t) for t in range(64)]
+        assert joined(threads)
+    finally:
+        sys.setswitchinterval(before)
+    assert not wrong
+    assert inside[1] == 1            # never two `process` calls at once
+    assert commit.batched == 64 * 200
+    assert commit.batches <= commit.batched
+    assert not commit._in_flight and not commit._queue
+
+
+# ---------------------------------------------------------------- the batch
+
+OPS = {"&": np.bitwise_and, "|": np.bitwise_or, "^": np.bitwise_xor,
+       "-": lambda a, b: a & ~b}
+
+
+def sig_of(op):
+    return (op, (("leaf", 0), ("leaf", 1)))
+
+
+class Planes:
+    """Dense leaf stacks of two shard counts on the evaluator's devices,
+    their numpy originals beside them."""
+
+    def __init__(self, ev, seed=5):
+        import jax
+
+        rng = np.random.default_rng(seed)
+        self.host, self.dev = {}, {}
+        sharding = ev._stack_sharding()
+        for shards in (8, 16):
+            for leaf in range(3):
+                plane = rng.integers(0, 2**32, (shards, WORDS_PER_ROW),
+                                     dtype=np.uint32)
+                self.host[shards, leaf] = plane
+                self.dev[shards, leaf] = containers.dense_container(
+                    jax.device_put(plane, sharding) if sharding is not None
+                    else jax.numpy.asarray(plane))
+
+    def payload(self, op, shards, a, b):
+        return (sig_of(op), (self.dev[shards, a], self.dev[shards, b]))
+
+    def answer(self, op, shards, a, b):
+        words = OPS[op](self.host[shards, a], self.host[shards, b])
+        return int(np.unpackbits(words.view(np.uint8)).sum())
+
+
+class CountingLock:
+    def __init__(self):
+        self._lock, self.holds = threading.Lock(), 0
+
+    def acquire(self):
+        self._lock.acquire()
+        self.holds += 1
+
+    def release(self):
+        self._lock.release()
+
+
+def mixed_batch(planes, n, programs=8):
+    """`n` queries over three leaves and `programs` of the eight programs
+    that four operators and two shard sets make."""
+    picks = [("&|^-"[i % programs % 4], (8, 16)[i % programs // 4],
+              i % 3, (i + 1) % 3) for i in range(n)]
+    return ([planes.payload(*p) for p in picks],
+            [planes.answer(*p) for p in picks])
+
+
+def expected_chunks(payloads):
+    groups = {}
+    for sig, stacks in payloads:
+        key = (sig, stacks[0].shape)
+        groups[key] = groups.get(key, 0) + 1
+    return [list(stacked._pow2_chunks(n, StackedEvaluator.MAX_COUNT_BATCH))
+            for n in groups.values()]
+
+
+def finish_builds(ev):
+    for thread in list(ev._count_builds.values()):
+        thread.join(120)
+        assert not thread.is_alive()
+
+
+BATCHES = [(1, 8), (3, 8), (5, 8), (17, 8), (33, 8), (5, 1), (33, 1)]
+
+
+@pytest.fixture(scope="module")
+def warm():
+    """An evaluator that has seen every batch below once, so that every
+    bucket they decompose into is built."""
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    for n, programs in BATCHES:
+        ev._process_count_batch(mixed_batch(planes, n, programs)[0])
+        finish_builds(ev)
+    assert not ev._count_builds
+    return ev, planes
+
+
+def test_pow2_chunks_add_up_and_pad_nothing():
+    for n in range(1, 100):
+        chunks = list(stacked._pow2_chunks(n, 32))
+        assert sum(chunks) == n
+        assert all(c & (c - 1) == 0 and c <= 32 for c in chunks)
+        assert chunks == sorted(chunks, reverse=True)
+        assert len([c for c in chunks if c < 32]) <= 5
+    assert list(stacked._pow2_chunks(37, 32)) == [32, 4, 1]
+
+
+@pytest.mark.parametrize("n,programs", BATCHES)
+def test_a_batch_gives_the_solo_answers_in_the_fewest_launches(
+        warm, n, programs):
+    ev, planes = warm
+    payloads, answers = mixed_batch(planes, n, programs)
+    solo = [ev._process_count_batch([p])[0][0] for p in payloads]
+    assert solo == answers
+    ev._dispatch_lock = lock = CountingLock()
+    before = dict(ev.cache_stats(), **ev._kernels["count"])
+    try:
+        got = ev._process_count_batch(payloads)
+    finally:
+        ev._dispatch_lock = stacked._DISPATCH_LOCK
+    after = dict(ev.cache_stats(), **ev._kernels["count"])
+    chunks = expected_chunks(payloads)
+    assert [count for count, _ in got] == answers
+    # the size a query reports is the size of the launch it rode
+    assert sorted(size for _, size in got) == sorted(
+        c for group in chunks for c in group for _ in range(c))
+    assert lock.holds == 1
+    assert after["count"] - before["count"] == 1
+    assert after["count_launches"] - before["count_launches"] == sum(
+        len(group) for group in chunks)
+    assert after["count_batch_fallbacks"] == before["count_batch_fallbacks"]
+    # no padded read: the bytes sent in are the answered queries' own
+    assert after["bytes_in"] - before["bytes_in"] == sum(
+        c.nbytes for _, stacks in payloads for c in stacks)
+
+
+def test_an_unbuilt_bucket_goes_out_as_solos_and_is_built_afterwards():
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    payloads, answers = mixed_batch(planes, 4)
+    batch = [payloads[0]] * 5 + [payloads[1]]
+    want = [answers[0]] * 5 + [answers[1]]
+    ev._dispatch_lock = lock = CountingLock()
+    try:
+        got = ev._process_count_batch(batch)
+    finally:
+        ev._dispatch_lock = stacked._DISPATCH_LOCK
+    assert [count for count, _ in got] == want
+    assert {size for _, size in got} == {1}
+    stats = ev.cache_stats()
+    assert stats["count_batch_fallbacks"] == 1   # the chunk of 4
+    assert stats["count_launches"] == 6          # 4 solos + 1 + 1
+    assert lock.holds == 1
+    finish_builds(ev)
+    assert not ev._count_builds
+    built = [key for key in ev._fns if key[0] == "countB"]
+    assert [key[2] for key in built] == [4]
+    # the same batch now rides the bucket: 4 + 1, and the other group's 1
+    got = ev._process_count_batch(batch)
+    assert [count for count, _ in got] == want
+    assert sorted(size for _, size in got) == [1, 1, 4, 4, 4, 4]
+    stats = ev.cache_stats()
+    assert stats["count_batch_fallbacks"] == 1
+    assert stats["count_launches"] == 6 + 3
+    # /debug/kernels prices the bucket from the shapes it was built for
+    assert any("countB" in entry["key"]
+               for entry in ev.kernels_snapshot()["compiled"])
+
+
+def test_a_leaders_lapsed_deadline_does_not_fail_its_batch(warm):
+    """Each caller checks its own deadline before it queues; the leader's
+    has no say over the batch it leads."""
+    ev, planes = warm
+    payloads, answers = mixed_batch(planes, 2)
+    stacked.set_thread_deadline(0.0)  # long past
+    try:
+        with pytest.raises(stacked.DeadlineExceededError):
+            ev._batched_count(*payloads[0])
+        got = ev._process_count_batch(payloads)
+    finally:
+        stacked.set_thread_deadline(None)
+    assert [count for count, _ in got] == answers
+
+
+# ------------------------------------------------------------ over the wire
+
+
+def test_32_http_clients_share_batches(tmp_path):
+    from tests.harness import ServerHarness
+
+    h = ServerHarness(data_dir=str(tmp_path))
+    try:
+        h.client.create_index("i")
+        for field in ("f", "g"):
+            h.client.create_field("i", field)
+            cols = [s * SHARD_WIDTH + c for s in range(4)
+                    for c in range(0, 40, 1 + len(field))]
+            h.client.import_bits("i", field, [1] * len(cols), cols)
+        queries = [f"Count({op}(Row(f=1), Row(g=1)))"
+                   for op in ("Intersect", "Union", "Difference", "Xor")]
+        url = f"{h.address}/index/i/query"
+
+        def ask(pql):
+            req = urllib.request.Request(url, data=pql.encode(),
+                                         method="POST")
+            with urllib.request.urlopen(req, timeout=WAIT) as r:
+                return json.loads(r.read())["results"][0]
+
+        want = {q: ask(q) for q in queries}
+        assert len(set(want.values())) > 1
+        before = h.api.executor._stacked.cache_stats()
+        wrong = []
+
+        def client(t):
+            for n in range(25):
+                q = queries[(t + n) % 4]
+                if ask(q) != want[q]:
+                    wrong.append(q)
+
+        threads = [start(client, t) for t in range(32)]
+        assert joined(threads)
+        assert not wrong
+        with urllib.request.urlopen(f"{h.address}/debug/vars",
+                                    timeout=WAIT) as r:
+            after = json.loads(r.read())["stacked"]
+        queries_n = after["count_batched_queries"] \
+            - before["count_batched_queries"]
+        assert queries_n == 32 * 25
+        assert after["count_batches"] - before["count_batches"] < queries_n
+        assert after["count_launches"] >= after["count_batches"]
+        assert "count_batch_fallbacks" in after
+    finally:
+        h.close()

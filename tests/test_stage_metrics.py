@@ -33,19 +33,15 @@ def tracer():
 # ------------------------------------------------------------ dispatch.queue
 
 
-def test_group_commit_follower_gets_a_queue_span(tracer, monkeypatch):
-    """A follower's whole wait is `dispatch.queue` role=follower; its
-    leader's is the window it held open. The leader is held in its window
-    until the follower has joined, then in `process` by an event."""
+def test_group_commit_follower_gets_a_queue_span(tracer):
+    """A follower's whole wait is `dispatch.queue` role=follower, tagged
+    with the batch it rode; the caller that leads that batch waited in
+    the same queue, role=leader. The first leader is held in `process` by
+    an event until both have queued behind it."""
     from pilosa_tpu.exec.stacked import GroupCommit
 
     commit = GroupCommit()
-    window = commit._window_s = 12345.0
-    joined, processing, release = (threading.Event() for _ in range(3))
-    real_sleep = time.sleep
-    monkeypatch.setattr(
-        time, "sleep",
-        lambda s: joined.wait(30) if s == window else real_sleep(s))
+    processing, release = threading.Event(), threading.Event()
 
     def process(payloads):
         processing.set()
@@ -58,26 +54,27 @@ def test_group_commit_follower_gets_a_queue_span(tracer, monkeypatch):
         with tracing.start_span(name):
             results[name] = commit.submit(payload, process)
 
-    leader = threading.Thread(target=submit, args=("leader", 1))
-    leader.start()
-    while not commit._queue:
-        real_sleep(0.001)
-    follower = threading.Thread(target=submit, args=("follower", 2))
-    follower.start()
-    while len(commit._queue) < 2:
-        real_sleep(0.001)
-    joined.set()
+    threads = [threading.Thread(target=submit, args=("first", 1))]
+    threads[0].start()
     assert processing.wait(30)
-    assert not tracer.find("follower")  # still waiting on its leader
+    for n, name in enumerate(("second", "third"), 1):
+        threads.append(threading.Thread(target=submit, args=(name, n + 1)))
+        threads[-1].start()
+        while len(commit._queue) < n:
+            time.sleep(0.001)
+    assert not tracer.find("second")  # still waiting for the batch in flight
     release.set()
-    leader.join(30)
-    follower.join(30)
-    assert results == {"leader": 2, "follower": 4}
+    for t in threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert results == {"first": 2, "second": 4, "third": 6}
     by_parent = {s.parent_id: s for s in tracer.find("dispatch.queue")}
-    lead = by_parent[tracer.find("leader")[0].span_id]
-    follow = by_parent[tracer.find("follower")[0].span_id]
-    assert lead.tags == {"role": "leader", "batch": 2}
-    assert follow.tags == {"role": "follower", "batch": 2}
+    tags = {name: by_parent[tracer.find(name)[0].span_id].tags
+            for name in results}
+    assert tags == {"first": {"role": "leader", "batch": 1},
+                    "second": {"role": "leader", "batch": 2},
+                    "third": {"role": "follower", "batch": 2}}
+    assert (commit.batches, commit.batched) == (2, 3)
 
 
 # -------------------------------------------------------------- stack.lookup
@@ -131,7 +128,8 @@ def test_lookup_after_a_write_says_patch(tmp_path):
 SIG = ("&", (("leaf", 0), ("leaf", 1)))
 PROGRAMS = {  # PERF.md section 3: builder -> the name its program carries
     "count_tree": lambda ev: ev._count_fn(SIG, 2),
-    "count_batch": lambda ev: ev._count_batch_fn(SIG, 2, 2),
+    "count_batch": lambda ev: types.SimpleNamespace(
+        _jit_fn=ev._count_batch_fn(SIG, 2, 2)),
     "fused_count": lambda ev: ev.fused_count_fn(((SIG, 2),))[0],
     "plane_tree": lambda ev: ev._plane_fn(SIG, 2),
     "count_vmap": lambda ev: ev._vmap_count_fn(SIG, 2, 4),
