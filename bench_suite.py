@@ -1704,7 +1704,10 @@ def bench_adaptive():
     for j in range(n_cold):
         fill(f"cold{j}", [0])
 
-    prev_budget = stacked_mod.MAX_STACK_BYTES
+    # the budgets in force are `stacked_mod.budgets()` (shares of the
+    # device's memory on a chip, the constants on the host CPU): this leg
+    # replaces the function, which holds on either
+    prev_budgets = stacked_mod.budgets
     # one probe build sizes the budget: room for the 4-row hot working
     # set plus 2 streaming entries — the cold burst (8/round) must not
     # fit alongside it, or LRU would never be forced to choose
@@ -1724,7 +1727,7 @@ def bench_adaptive():
             # policy, so every query must stay on the stacked path
             adaptive.observe_fallback("Count", 1000.0, 1)
         ex = Executor_cls(holder)
-        stacked_mod.MAX_STACK_BYTES = budget
+        stacked_mod.budgets = lambda: (budget, prev_budgets()[1])
         st = ex._stacked
         hot_ms = None
         for r in range(rounds):
@@ -1734,7 +1737,7 @@ def bench_adaptive():
             hot_ms = (time.perf_counter() - t0) / 4 * 1000
             for j in range(8):
                 ex.execute("adp", f"Count(Row(cold{(r * 8 + j) % n_cold}=0))")
-        stacked_mod.MAX_STACK_BYTES = prev_budget
+        stacked_mod.budgets = prev_budgets
         return st.hits, hot_ms
 
     lru_hits, _ = run_policy("off")
@@ -1793,7 +1796,7 @@ def bench_adaptive():
 
     adaptive.reset()
     workload.reset()
-    stacked_mod.MAX_STACK_BYTES = prev_budget
+    stacked_mod.budgets = prev_budgets
     _close(holder)
     _emit("adaptive_cache_hit_ratio", hit_ratio, 1.0, {
         "platform": platform, "n_shards": n_shards,

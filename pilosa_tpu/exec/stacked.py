@@ -36,6 +36,7 @@ from collections import OrderedDict
 
 import numpy as np
 
+from ..utils import device as _device
 from ..utils import flightrec as _flightrec
 from ..utils import profile as _profile
 from ..utils import tracing as _tracing
@@ -290,6 +291,14 @@ def _looked_up(span, outcome, stack, planes_uploaded=0):
     return stack
 
 
+def _placed(span, nbytes, repr_kind):
+    """Tag a `stack.place` span (container choice and upload, or a
+    patch's scatter) with what went to the device."""
+    if span is not None:
+        span.set_tag("bytes", int(nbytes))
+        span.set_tag("repr", repr_kind)
+
+
 def fetch(arrays):
     """Every result fetch of the serving path: the wait for the device
     and the device->host copy of `arrays`, as the `dispatch.fetch` stage
@@ -305,15 +314,43 @@ from ..core.index import EXISTENCE_FIELD_NAME
 from ..core.view import VIEW_STANDARD
 from ..shardwidth import WORDS_PER_ROW
 
-# Device-byte budget for cached stacks; excess evicts least-recently-used.
+# Device-byte budgets for cached stacks; excess evicts least-recently-used.
 # (Entry size scales with shard count — ~120 MB per 954-shard stack — so a
-# count bound alone could pin several GB of HBM.)
-MAX_STACK_BYTES = 512 * 1024 * 1024
-# Separate budget for TopN/GroupBy row-chunk stacks ([rows, shards, words]
-# keyed by the exact candidate tuple): they are large and churn with any
+# count bound alone could pin several GB of HBM.) Two pools: leaf/BSI
+# stacks, and TopN/GroupBy row-chunk stacks ([rows, shards, words] keyed by
+# the exact candidate tuple), which are large and churn with any
 # candidate-set change, so they must not be able to evict the long-lived
 # leaf/BSI stacks the Count/Sum serving paths depend on.
+#
+# The budgets in force are `budgets()`: shares of the memory the device
+# reports (`utils/device.memory_bytes`) — a quarter of one device for the
+# leaf/BSI pool, an eighth for the rows pool, times the local devices a
+# stack is sharded over (each holds 1/n of every stack). The other five
+# eighths are for what is no cached stack: the stacks that writes replace
+# while batches in flight still hold the old ones (4.3–5.9 GB at 32
+# clients with 5 % imports, PERF.md section 5), a build's upload in
+# flight, the programs' temporaries. Over-budget costs a rebuild; out of
+# memory costs the query. The two constants stand where the backend
+# reports no memory (the host CPU).
+MAX_STACK_BYTES = 512 * 1024 * 1024
 MAX_ROWS_STACK_BYTES = 256 * 1024 * 1024
+STACK_MEMORY_DIVISOR = 4            # of one device's bytes_limit
+ROWS_STACK_MEMORY_DIVISOR = 8
+
+
+def budgets():
+    """(leaf/BSI pool, rows pool) byte budgets in force: every reader of a
+    budget calls this. Observed, never configured; initialises no
+    backend (before one exists the constants are in force, and nothing is
+    cached yet to hold to them)."""
+    per_device = _device.memory_bytes()
+    if per_device is None:
+        return MAX_STACK_BYTES, MAX_ROWS_STACK_BYTES
+    n = _device.facts()["localDeviceCount"]
+    return (per_device // STACK_MEMORY_DIVISOR * n,
+            per_device // ROWS_STACK_MEMORY_DIVISOR * n)
+
+
 # Compiled tree programs are tiny but unbounded shapes would accumulate.
 MAX_FNS = 128
 # Below this many shards the per-shard path's dispatch count is too small
@@ -564,6 +601,13 @@ class StackedEvaluator:
         # assert planes_uploaded stays O(changed shards) under writes.
         self.patches = 0
         self.planes_uploaded = 0
+        # Cold builds (a lookup's outcome `build`: every plane gathered
+        # from the fragments and uploaded) and where their seconds go:
+        # the whole build, and of it the host gather (`_host_rows`); the
+        # rest is container choice and upload. A patch counts above.
+        self.builds = 0
+        self.build_seconds = 0.0
+        self.build_gather_seconds = 0.0
         # Streaming-ingest observability: reads served from a stale
         # stack whose drift is fully covered by pending ingest deltas
         # (the merge folds them off the read path; exec/ingest.py).
@@ -701,11 +745,12 @@ class StackedEvaluator:
         return tuple(gens)
 
     def _pool(self, key):
-        """Row-chunk stacks live in their own LRU pool (see
-        MAX_ROWS_STACK_BYTES)."""
+        """(pool, its budget): row-chunk stacks live in their own LRU
+        pool (see `budgets`)."""
+        stack_budget, rows_budget = budgets()
         if key[0] == "rows":
-            return self._rows_stacks, MAX_ROWS_STACK_BYTES
-        return self._stacks, MAX_STACK_BYTES
+            return self._rows_stacks, rows_budget
+        return self._stacks, stack_budget
 
     @staticmethod
     def _heat_key(key):
@@ -969,33 +1014,35 @@ class StackedEvaluator:
                     view, [row_id], [shards[j] for j in changed],
                     pad=False)
                 ent = stale[1]
-                if isinstance(ent, _containers.Container) \
-                        and ent.kind != "dense":
-                    old = _containers.container_to_dense(ent)
-                elif isinstance(ent, _containers.Container):
-                    old = ent.arrays[0]
-                else:
-                    old = ent
-                stack = self._place(
-                    old.at[np.asarray(changed)].set(
-                        jnp.asarray(block[0])), shard_axis=0)
+                with _tracing.start_span("stack.place") as place:
+                    if isinstance(ent, _containers.Container) \
+                            and ent.kind != "dense":
+                        old = _containers.container_to_dense(ent)
+                    elif isinstance(ent, _containers.Container):
+                        old = ent.arrays[0]
+                    else:
+                        old = ent
+                    stack = self._place(
+                        old.at[np.asarray(changed)].set(
+                            jnp.asarray(block[0])), shard_axis=0)
+                    cont = _containers.dense_container(stack)
+                    _placed(place, cont.nbytes, "dense")
                 self._note_patch("read")
-                cont = _containers.dense_container(stack)
                 self._cache_put(key, gens, cont, cont.nbytes, stamp)
                 return _looked_up(span, "patch", cont, len(changed))
-        host = self._host_rows(view, [row_id], shards)
-        cont = _containers.build(
-            host[0],
-            place_sharded=lambda a: self._place(a, shard_axis=0),
-            place_replicated=self._place_replicated,
-            fragment=(idx.name, field_name, VIEW_STANDARD, row_id))
+        cont = self._cold_build(
+            view, [row_id], shards, lambda host: _containers.build(
+                host[0],
+                place_sharded=lambda a: self._place(a, shard_axis=0),
+                place_replicated=self._place_replicated,
+                fragment=(idx.name, field_name, VIEW_STANDARD, row_id)))
         self._cache_put(key, gens, cont, cont.nbytes, stamp)
         return _looked_up(span, "build", cont, len(shards))
 
     def _host_rows(self, view, row_ids, shards, pad=True):
         """Host [R, S_padded, W] uint32 gather of rows over shards
         (pad=False skips the device-multiple padding — patch gathers
-        address existing stack rows directly).
+        address existing stack rows directly): the `stack.gather` stage.
 
         The per-shard gathers fan out over the shared worker pool: each
         task fills its own out[:, j] column (disjoint slices, so the
@@ -1004,21 +1051,44 @@ class StackedEvaluator:
         copies before."""
         from ..utils.workpool import get_pool
 
-        n = self._padded_len(shards) if pad else len(shards)
-        out = np.zeros((len(row_ids), n, WORDS_PER_ROW), dtype=np.uint32)
+        planes = len(row_ids) * len(shards)
+        with _tracing.start_span("stack.gather", planes=planes):
+            n = self._padded_len(shards) if pad else len(shards)
+            out = np.zeros((len(row_ids), n, WORDS_PER_ROW),
+                           dtype=np.uint32)
 
-        def gather_column(j):
-            frag = view.fragment(shards[j])
-            if frag is None:
-                return
-            for i, row_id in enumerate(row_ids):
-                plane = frag.row_plane(row_id)
-                if plane is not None:
-                    out[i, j] = np.asarray(plane)
+            def gather_column(j):
+                frag = view.fragment(shards[j])
+                if frag is None:
+                    return
+                for i, row_id in enumerate(row_ids):
+                    plane = frag.row_plane(row_id)
+                    if plane is not None:
+                        out[i, j] = np.asarray(plane)
 
-        get_pool().map_ordered(gather_column, range(len(shards)))
-        self.planes_uploaded += len(row_ids) * len(shards)
+            get_pool().map_ordered(gather_column, range(len(shards)))
+        self.planes_uploaded += planes
         return out
+
+    def _cold_build(self, view, row_ids, shards, place):
+        """A lookup's outcome `build`: every plane gathered from the
+        fragments (`_host_rows`), then `place(host)` — container choice
+        and upload, the `stack.place` stage — whose device value is
+        returned. Feeds `builds`, `build_seconds`, `build_gather_seconds`;
+        always on: three clock reads a build, which takes a tenth of a
+        second and more."""
+        t0 = time.perf_counter()
+        host = self._host_rows(view, row_ids, shards)
+        gathered = time.perf_counter() - t0
+        with _tracing.start_span("stack.place") as span:
+            placed = place(host)
+            _placed(span, placed.nbytes, _containers.kind_of(placed))
+        seconds = time.perf_counter() - t0
+        with self._lock:
+            self.builds += 1
+            self.build_seconds += seconds
+            self.build_gather_seconds += gathered
+        return placed
 
     def _stale_entry(self, key, gens):
         """(old_gens, arrays, nbytes) of a cached entry whose generations
@@ -1119,16 +1189,19 @@ class StackedEvaluator:
                     block = self._host_rows(
                         view, list(row_chunk),
                         [shards[j] for j in changed], pad=False)
-                    stack = self._place(
-                        stale[1].at[:, np.asarray(changed)].set(
-                            jnp.asarray(block)), shard_axis=1)
+                    with _tracing.start_span("stack.place") as place:
+                        stack = self._place(
+                            stale[1].at[:, np.asarray(changed)].set(
+                                jnp.asarray(block)), shard_axis=1)
+                        _placed(place, stack.size * 4, "dense")
                     self._note_patch("read")
                     self._cache_put(key, gens, stack, stack.size * 4,
                                     stamp)
                     return _looked_up(span, "patch", stack,
                                       len(row_chunk) * len(changed))
-        host = self._host_rows(view, list(row_chunk), shards)
-        stack = self._place(host, shard_axis=1)
+        stack = self._cold_build(
+            view, list(row_chunk), shards,
+            lambda host: self._place(host, shard_axis=1))
         if cache:
             self._cache_put(key, gens, stack, stack.size * 4, stamp)
         return _looked_up(span, "build", stack,
@@ -1177,22 +1250,26 @@ class StackedEvaluator:
                 import jax.numpy as jnp
 
                 planes, sign, exists = stale[1]
-                block = jnp.asarray(self._host_rows(
-                    view, rows, [shards[j] for j in changed], pad=False))
-                jdx = np.asarray(changed)
-                arrays = (
-                    self._place(planes.at[:, jdx].set(block[2:]),
-                                shard_axis=1),
-                    self._place(sign.at[jdx].set(block[1]), shard_axis=0),
-                    self._place(exists.at[jdx].set(block[0]),
-                                shard_axis=0),
-                )
+                host = self._host_rows(
+                    view, rows, [shards[j] for j in changed], pad=False)
+                with _tracing.start_span("stack.place") as place:
+                    block = jnp.asarray(host)
+                    jdx = np.asarray(changed)
+                    arrays = (
+                        self._place(planes.at[:, jdx].set(block[2:]),
+                                    shard_axis=1),
+                        self._place(sign.at[jdx].set(block[1]),
+                                    shard_axis=0),
+                        self._place(exists.at[jdx].set(block[0]),
+                                    shard_axis=0),
+                    )
+                    _placed(place, stale[2], "dense")
                 self._note_patch("read")
                 self._cache_put(key, gens, arrays, stale[2], stamp)
                 return _looked_up(span, "patch", arrays,
                                   len(rows) * len(changed))
-        host = self._host_rows(view, rows, shards)
-        arr = self._place(host, shard_axis=1)
+        arr = self._cold_build(
+            view, rows, shards, lambda host: self._place(host, shard_axis=1))
         arrays = (arr[2:], arr[1], arr[0])  # planes, sign, exists
         self._cache_put(key, gens, arrays, arr.size * 4, stamp)
         return _looked_up(span, "build", arrays, len(rows) * len(shards))
@@ -2081,7 +2158,7 @@ class StackedEvaluator:
         # chunks transiently instead of churning out every cached chunk.
         total_bytes = (len(row_ids) * self._padded_len(shards)
                        * WORDS_PER_ROW * 4)
-        cache = total_bytes <= MAX_ROWS_STACK_BYTES
+        cache = total_bytes <= budgets()[1]
         fn = self._row_counts_fn(filt is not None)
         pending = []
         import jax
@@ -2141,8 +2218,9 @@ class StackedEvaluator:
             tile = self.row_chunk_size(shards)
         observe = _adaptive.enabled()
         row_bytes = self._padded_len(shards) * WORDS_PER_ROW * 4
-        cache_a = len(a_rows) * row_bytes <= MAX_ROWS_STACK_BYTES
-        cache_b = len(b_rows) * row_bytes <= MAX_ROWS_STACK_BYTES
+        rows_budget = budgets()[1]
+        cache_a = len(a_rows) * row_bytes <= rows_budget
+        cache_b = len(b_rows) * row_bytes <= rows_budget
         import jax
 
         for i in range(0, len(a_rows), tile):
@@ -2275,10 +2353,19 @@ class StackedEvaluator:
 
     def cache_stats(self):
         """Snapshot for /debug/vars: hit rate and byte pressure reveal
-        whether the HBM budgets (MAX_STACK_BYTES / MAX_ROWS_STACK_BYTES)
-        are thrashing under the live workload."""
+        whether the HBM budgets are thrashing under the live workload.
+        `stack_budget_bytes` / `rows_stack_budget_bytes` are the budgets
+        in force (`budgets()`: shares of the device's memory, or the
+        constants where it reports none); `builds`, `build_seconds` and
+        `build_gather_seconds` say what a miss costs and where."""
+        stack_budget, rows_budget = budgets()
         with self._lock:
             return {
+                "stack_budget_bytes": stack_budget,
+                "rows_stack_budget_bytes": rows_budget,
+                "builds": self.builds,
+                "build_seconds": self.build_seconds,
+                "build_gather_seconds": self.build_gather_seconds,
                 "hits": self.hits,
                 "misses": self.misses,
                 "evictions": self.evictions,
@@ -2339,6 +2426,7 @@ class StackedEvaluator:
         under the same lock — the acceptance stress test asserts it)."""
         now = time.time()
         entries = []
+        stack_budget, rows_budget = budgets()
         with self._lock:
             for pool_name, pool in (("stack", self._stacks),
                                     ("rows", self._rows_stacks)):
@@ -2379,10 +2467,11 @@ class StackedEvaluator:
                 "total_bytes": self._stack_bytes + self._rows_stack_bytes,
                 "stack_bytes": self._stack_bytes,
                 "stack_entries": len(self._stacks),
-                "stack_budget_bytes": MAX_STACK_BYTES,
+                "stack_budget_bytes": stack_budget,
                 "rows_stack_bytes": self._rows_stack_bytes,
                 "rows_stack_entries": len(self._rows_stacks),
-                "rows_stack_budget_bytes": MAX_ROWS_STACK_BYTES,
+                "rows_stack_budget_bytes": rows_budget,
+                "device_bytes_limit": _device.memory_bytes(),
                 "by_index_field": by_index_field,
                 "by_index_field_repr": by_index_field_repr,
                 "by_repr": by_repr,
@@ -2400,9 +2489,7 @@ class StackedEvaluator:
         """Per-device memory_stats() headroom, with the RuntimeMonitor
         guard: NEVER initializes a backend (jax absent or uninitialized
         -> None), and backends without memory_stats report nothing."""
-        from ..utils import device
-
-        if not device.backends_are_initialized():
+        if not _device.backends_are_initialized():
             return None
         import jax
 

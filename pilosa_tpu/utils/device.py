@@ -24,7 +24,7 @@ backend and nothing in it runs at import time.
 import os
 import sys
 
-__all__ = ["DEFAULT_CACHE_DIR", "boot", "facts", "cache_dir",
+__all__ = ["DEFAULT_CACHE_DIR", "boot", "facts", "memory_bytes", "cache_dir",
            "backends_are_initialized"]
 
 #: compile cache used when JAX_COMPILATION_CACHE_DIR is unset
@@ -33,6 +33,8 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 _FACTS = None  # resolved once per process (the backend cannot change)
+_UNREAD = object()
+_MEMORY = _UNREAD  # bytes of one local device, or None; read once, as _FACTS
 
 
 def backends_are_initialized():
@@ -65,6 +67,38 @@ def _read_facts():
     }
 
 
+def _read_memory():
+    """`memory_stats()["bytes_limit"]` of one local device (the least,
+    should they differ), or None where the backend reports none: the
+    host CPU does not."""
+    import jax
+
+    limits = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() if callable(
+            getattr(d, "memory_stats", None)) else None
+        if not stats or not stats.get("bytes_limit"):
+            return None
+        limits.append(int(stats["bytes_limit"]))
+    return min(limits) if limits else None
+
+
+def memory_bytes():
+    """How many bytes of memory one of this process's devices has, as the
+    device reports it, or None: the backend reports none (the host CPU),
+    or no backend exists yet. The one place that says so — the stack
+    budgets of `exec/stacked.py` are shares of it. `boot()` reads it
+    beside `facts()`; an in-process API (tests) reads it on the first call
+    after a backend exists, and a call before that initialises nothing
+    (the rule `backends_are_initialized` is for)."""
+    global _MEMORY
+    if _MEMORY is _UNREAD:
+        if not backends_are_initialized():
+            return None
+        _MEMORY = _read_memory()
+    return _MEMORY
+
+
 def facts():
     """{platform, deviceKind, deviceCount, localDeviceCount} as JAX
     reports them (`jax.devices()[0].platform`, `.device_kind`,
@@ -82,7 +116,7 @@ def boot():
     Returns `facts()`. Exits non-zero (SystemExit with a message) when
     the backend is not a TPU and `JAX_PLATFORMS=cpu` was not set
     explicitly."""
-    global _FACTS
+    global _FACTS, _MEMORY
     if _FACTS is not None:
         return _FACTS
     import jax
@@ -111,9 +145,11 @@ def boot():
             f"{jax.devices()}). Set JAX_PLATFORMS=cpu to run on the "
             f"host CPU on purpose.")
     _FACTS = _read_facts()
+    _MEMORY = _read_memory()
     print(f"pilosa_tpu device: platform={_FACTS['platform']} "
           f"device_kind={_FACTS['deviceKind']!r} "
           f"local_devices={_FACTS['localDeviceCount']} "
           f"global_devices={_FACTS['deviceCount']} "
+          f"device_memory_bytes={_MEMORY} "
           f"compile_cache={cache_dir()}", file=sys.stderr, flush=True)
     return _FACTS
