@@ -58,6 +58,12 @@ HASH_BLOCK_SIZE = 100
 # Default op threshold before snapshotting (reference: fragment.go:85).
 DEFAULT_MAX_OP_N = 10_000
 
+# Rows a fragment remembers a generation of its own for (see
+# Fragment.row_generation). Past it the floor rises instead: every row of
+# the fragment reads as changed once, and the memory stays bounded under
+# an ingest that walks a high-cardinality field.
+ROW_GENERATIONS_MAX = 1024
+
 # BSI row layout (reference: fragment.go:91-93).
 BSI_EXISTS_BIT = 0
 BSI_SIGN_BIT = 1
@@ -66,6 +72,15 @@ BSI_OFFSET_BIT = 2
 # Boolean field rows (reference: fragment.go:88-89).
 FALSE_ROW_ID = 0
 TRUE_ROW_ID = 1
+
+
+def _rows_of(positions):
+    """Distinct row ids of a non-empty uint64 array of storage positions.
+    Imports come row by row, so runs of one row collapse first (O(n), no
+    sort) and the set is built from what is left."""
+    rows = positions // np.uint64(SHARD_WIDTH)
+    starts = np.concatenate(([True], rows[1:] != rows[:-1]))
+    return set(rows[starts].tolist())
 
 
 class Fragment:
@@ -104,9 +119,15 @@ class Fragment:
         # (same path, fresh counter) with its predecessor.
         self._row_cache = {}
         self.generation = 0
+        # (floor, {row: generation at its last change}) — the row-granular
+        # fingerprint behind row_generation(). ONE tuple, replaced whole
+        # when the floor rises, so a lock-free reader never pairs a new
+        # floor with the old rows or the reverse.
+        self._row_gens = (0, {})
         self.uid = next(_fragment_uids)
-        # optional owner hook (View._bump_mutations): lets a container
-        # keep an O(1) any-fragment-changed fingerprint for serving caches
+        # optional owner hook (View._bump_mutations), called with the rows
+        # a mutation touched (None = extent unknown): lets a container
+        # keep O(1) changed-since fingerprints for serving caches
         self.on_mutate = None
 
         # Block checksums cache (anti-entropy; reference fragment.checksums).
@@ -392,29 +413,25 @@ class Fragment:
         fragment.go:2053). Returns changed count."""
         with self._lock:
             changed = 0
+            touched = set()  # rows of the batches that changed a bit
             if len(to_set):
                 arr = np.asarray(to_set, dtype=np.uint64)
                 n = self.storage.add_many(arr)
                 if n:
                     self._append_op(encode_op(OP_ADD_BATCH, values=arr))
                     changed += n
+                    touched.update(_rows_of(arr))
             if len(to_clear):
                 arr = np.asarray(to_clear, dtype=np.uint64)
                 n = self.storage.remove_many(arr)
                 if n:
                     self._append_op(encode_op(OP_REMOVE_BATCH, values=arr))
                     changed += n
+                    touched.update(_rows_of(arr))
             if changed:
-                self._invalidate_all_rows()
-                if self.cache is not None:
-                    touched = set()
-                    for arr in (to_set, to_clear):
-                        if len(arr):
-                            touched.update(
-                                (np.asarray(arr, dtype=np.uint64)
-                                 // np.uint64(SHARD_WIDTH)).tolist())
-                    for row_id in touched:
-                        self._cache_update(int(row_id))
+                self._invalidate_all_rows(touched)
+                for row_id in touched:
+                    self._cache_update(row_id)
             return changed
 
     def bulk_import(self, row_ids, column_ids, clear=False):
@@ -470,12 +487,11 @@ class Fragment:
             if changed:
                 op = OP_REMOVE_ROARING if clear else OP_ADD_ROARING
                 self._append_op(encode_op(op, roaring=serialize(other), op_n=changed))
-                self._invalidate_all_rows()
-                if self.cache is not None:
-                    touched = {
-                        key // CONTAINERS_PER_SHARD for key in other.keys()}
-                    for row_id in touched:
-                        self._cache_update(int(row_id))
+                touched = {
+                    int(key) // CONTAINERS_PER_SHARD for key in other.keys()}
+                self._invalidate_all_rows(touched)
+                for row_id in touched:
+                    self._cache_update(row_id)
             return changed
 
     # -- row planes (the device path) ----------------------------------------
@@ -624,20 +640,44 @@ class Fragment:
 
     # -- cache/invalidation ---------------------------------------------------
 
+    def row_generation(self, row_id):
+        """The generation at which row `row_id` may last have changed: its
+        own where a mutator named it, else the floor that mutations of
+        unknown extent raise. The invariant serving caches rest on: if
+        any bit of the row changed, (uid, row_generation(row)) changed.
+        Coarser than the truth is safe, finer never is. Lock-free: one
+        attribute read hands over a floor and the rows that go with it."""
+        floor, rows = self._row_gens
+        return rows.get(row_id, floor)
+
     def _invalidate_row(self, row_id):
         self._row_cache.pop(row_id, None)
         self._checksums.pop(row_id // HASH_BLOCK_SIZE, None)
-        self.generation += 1
-        if self.on_mutate is not None:
-            self.on_mutate()
+        self._note_change((row_id,))
 
-    def _invalidate_all_rows(self):
+    def _invalidate_all_rows(self, rows=None):
+        """A bulk mutation: the per-row host caches go whole (cheap to
+        refill); the generations move only for `rows` where the mutator
+        knows which it touched — a superset is fine — and for every row
+        (the floor) where it does not."""
         self._row_cache.clear()
         self._checksums.clear()
         self._drop_mutex_vec()  # bulk mutation: rebuild lazily
+        self._note_change(rows)
+
+    def _note_change(self, rows):
+        """Move the fingerprints after storage changed (caller holds
+        self._lock, so writers never race each other here)."""
         self.generation += 1
+        gen = self.generation
+        own = self._row_gens[1]
+        if rows is None or len(own) + len(rows) > ROW_GENERATIONS_MAX:
+            self._row_gens = (gen, {})
+        else:
+            for row_id in rows:
+                own[row_id] = gen
         if self.on_mutate is not None:
-            self.on_mutate()
+            self.on_mutate(rows)
 
     # -- anti-entropy blocks (reference: Blocks fragment.go:1778) -------------
 

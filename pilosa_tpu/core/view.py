@@ -13,6 +13,11 @@ from .fragment import Fragment
 
 _view_uids = itertools.count(1)
 
+# Rows a view keeps a change tick of its own for (see View.stamp); past
+# it the structure tick moves instead, which every leaf stack of the view
+# answers with one generation walk.
+ROW_STAMPS_MAX = 4096
+
 VIEW_STANDARD = "standard"
 VIEW_BSI_GROUP_PREFIX = "bsig_"
 
@@ -34,13 +39,24 @@ class View:
         self.cache_size = cache_size
         self.fragments = {}  # shard -> Fragment
         self._lock = threading.RLock()
-        # O(1) change fingerprint for the stacked serving caches: bumped
-        # on ANY fragment mutation or creation in this view, so a cache
-        # hit costs one counter compare instead of a per-shard generation
-        # walk (exec/stacked.py two-level fingerprint). uid distinguishes
-        # a recreated view (drop + re-create) whose counter restarts.
+        # O(1) change fingerprints for the stacked serving caches, so a
+        # cache hit costs one compare instead of a per-shard generation
+        # walk (exec/stacked.py two-level fingerprint). `mutations` moves
+        # on ANY fragment mutation, creation or removal in this view;
+        # `_row_stamps` = (structure tick, {row: tick of its last change})
+        # moves the structure tick where the extent is unknown (a fragment
+        # created, removed or closed; a mutator that names no rows) and
+        # one row's tick where the mutator named the row. The pair is ONE
+        # tuple, replaced whole with the structure tick, so a lock-free
+        # reader never pairs a new structure tick with the old rows. Every
+        # value comes from one itertools.count (atomic next()): a slot
+        # never holds the same value twice, whatever order racing writers
+        # store in. uid distinguishes a recreated view (drop + re-create)
+        # whose counters restart.
         self.uid = next(_view_uids)
+        self._ticks = itertools.count(1)
         self.mutations = 0
+        self._row_stamps = (0, {})
 
     def open(self):
         frag_dir = os.path.join(self.path, "fragments")
@@ -89,11 +105,32 @@ class View:
         self._bump_mutations()
         return frag
 
-    def _bump_mutations(self):
-        # benign-race increment: a stale read in the serving cache means
-        # one extra generation walk, never a stale result (the per-shard
-        # gens remain the ground truth)
-        self.mutations += 1
+    def _bump_mutations(self, rows=None):
+        """A fragment of this view changed (its own generations moved
+        first): `rows` are the rows it touched, None = extent unknown or
+        the set of fragments itself changed. Lock-free against readers
+        and other writers — a stamp read early means one extra generation
+        walk in the serving cache, never a stale result (the per-shard
+        gens remain the ground truth)."""
+        self.mutations = next(self._ticks)
+        own = self._row_stamps[1]
+        if rows is None or len(own) + len(rows) > ROW_STAMPS_MAX:
+            self._row_stamps = (next(self._ticks), {})
+        else:
+            for row_id in rows:
+                own[row_id] = next(self._ticks)
+
+    def stamp(self, row_id=None):
+        """First-level fingerprint of a cached serving stack: equal
+        stamps prove that nothing the stack holds changed in between. A
+        stack of one row (a leaf) names the row and is answered from
+        (uid, structure tick, the row's tick): writes to other rows of
+        the view leave it alone. Stacks of many rows (row chunks, BSI
+        planes) name none and get (uid, mutations)."""
+        if row_id is None:
+            return self.uid, self.mutations
+        structure, rows = self._row_stamps
+        return self.uid, structure, rows.get(row_id, 0)
 
     def fragment(self, shard):
         return self.fragments.get(shard)

@@ -263,9 +263,14 @@ class IngestEngine:
         return True
 
     def covers(self, index, field, view, shards, old_gens, gens):
-        """True when every drifted shard's current generation matches a
-        pending delta record — i.e. the merge will fold exactly the
-        drift this read sees."""
+        """True when every drifted shard's newest change is no newer than
+        a pending delta record of the same fragment — i.e. the merge will
+        fold the drift this read sees. `gens` are fragment-wide
+        generations (read now, so never under the record's: equal or not
+        covered) or, for a leaf stack, its row's generations (the row
+        last changed at or before the recorded write). Anything newer
+        than the record — a write that left none — is not covered and
+        falls to the read path's exact patch."""
         hit = False
         with self._plock:
             pending = self._pending
@@ -273,7 +278,7 @@ class IngestEngine:
                 if o == n:
                     continue
                 rec = pending.get((index, field, view, shards[j]))
-                if rec is None or (rec[0], rec[1]) != n:
+                if rec is None or rec[0] != n[0] or n[1] > rec[1]:
                     return False
                 hit = True
         return hit
@@ -419,8 +424,9 @@ class IngestEngine:
                 if ev.merge_drop(key, entry):
                     stats["drops"] += 1
                 continue
-            gens = ev._fragment_gens(idx, key[2], shards, view_name,
-                                     view=view)
+            gens = ev._fragment_gens(
+                idx, key[2], shards, view_name, view=view,
+                row_id=key[3] if kind == "leaf" else None)
             old_gens = entry[0]
             if gens is None or len(old_gens) != len(gens):
                 if ev.merge_drop(key, entry):

@@ -10,10 +10,15 @@ per-query work is a handful of dispatches and ONE host sync, independent of
 the shard count.
 
 Stacks are cached per (kind, index, field, rows, shard-set) and invalidated
-by the fragments' write-generation counters (fragment.generation — bumped by
-every mutation), so a stale stack can never serve a query. LRU-bounded: at
+by the fragments' write-generation counters, so a stale stack can never serve
+a query. A leaf stack (one row) is held to what happened to ITS ROW: per shard
+(fragment.uid, fragment.row_generation(row)), behind the view's per-row stamp
+(view.stamp(row)) — a write stales only the stacks of the rows it wrote, the
+reference's granularity (fragment.rowCache fragment.go:367). Row-chunk and BSI
+stacks hold many rows and keep the fragment-wide (uid, fragment.generation),
+which every mutation bumps, behind (view.uid, view.mutations). LRU-bounded: at
 SHARD_WIDTH=2^20 a 954-shard stack is ~120 MB of HBM, so only the hottest
-rows stay resident (the device analog of fragment.rowCache fragment.go:367).
+rows stay resident.
 
 On a multi-device host the stacks are placed sharded over a 1-D "shards"
 mesh (zero-padded to a device multiple — zero rows are count-neutral for
@@ -724,25 +729,29 @@ class StackedEvaluator:
     # -- stack cache ---------------------------------------------------------
 
     def _fragment_gens(self, idx, field_name, shards,
-                       view_name=VIEW_STANDARD, view=None):
+                       view_name=VIEW_STANDARD, view=None, row_id=None):
         """Cache-validation fingerprint: per-shard (fragment uid,
-        generation). The uid makes a recreated fragment (field dropped and
-        re-made at the same path) distinct from its predecessor even when
-        the generation counters collide. None when the field vanished
-        (concurrent DDL) — caller falls back to the general path. Callers
-        that already resolved the view pass it to skip the double
-        field/view lookup on the serving path."""
+        generation) — the generation of `row_id` alone where the stack
+        holds that one row (a leaf), so drift shows only where the row's
+        plane may have moved; the fragment-wide one otherwise. The uid
+        makes a recreated fragment (field dropped and re-made at the same
+        path) distinct from its predecessor even when the generation
+        counters collide. None when the field vanished (concurrent DDL) —
+        caller falls back to the general path. Callers that already
+        resolved the view pass it to skip the double field/view lookup on
+        the serving path."""
         if view is None:
             field = idx.field(field_name)
             view = field.view(view_name) if field is not None else None
             if view is None:
                 return None
-        gens = []
-        for shard in shards:
-            frag = view.fragment(shard)
-            gens.append((-1, -1) if frag is None
-                        else (frag.uid, frag.generation))
-        return tuple(gens)
+        frags = [view.fragment(shard) for shard in shards]
+        if row_id is None:
+            return tuple((-1, -1) if frag is None
+                         else (frag.uid, frag.generation) for frag in frags)
+        return tuple((-1, -1) if frag is None
+                     else (frag.uid, frag.row_generation(row_id))
+                     for frag in frags)
 
     def _pool(self, key):
         """(pool, its budget): row-chunk stacks live in their own LRU
@@ -765,9 +774,10 @@ class StackedEvaluator:
         return key[1], key[2], VIEW_STANDARD
 
     def _cache_get_fast(self, key, stamp):
-        """O(1) hit check via the view-level (uid, mutations) stamp — the
-        first level of the two-level fingerprint. A stamp match proves no
-        fragment in the view changed since the entry was stored, so the
+        """O(1) hit check via the view-level stamp (View.stamp) — the
+        first level of the two-level fingerprint. A stamp match proves
+        nothing the stack holds changed since the entry was stored (for a
+        leaf: its row, in any fragment of the view), so the
         per-shard generation walk (954 iterations at 1B columns — the
         dominant per-query Python cost) is skipped entirely on the hot
         serving path."""
@@ -979,28 +989,29 @@ class StackedEvaluator:
         view = field.view(VIEW_STANDARD) if field is not None else None
         if view is None:
             return None
-        hit = self._cache_get_fast(key, (view.uid, view.mutations))
+        stamp = view.stamp(row_id)
+        hit = self._cache_get_fast(key, stamp)
         if hit is not None:
             return _looked_up(span, "hit", hit)
-        stamp = (view.uid, view.mutations)
-        gens = self._fragment_gens(idx, field_name, shards, view=view)
+        gens = self._fragment_gens(idx, field_name, shards, view=view,
+                                   row_id=row_id)
         if gens is None:
             return None
         hit = self._cache_get(key, gens, stamp)
         if hit is not None:
             return _looked_up(span, "hit", hit)
         # Incremental maintenance: when k << S shards drifted (a write
-        # bumps only its fragment's generation), gather + upload ONLY
-        # those planes and scatter them into the cached device stack —
-        # the device analog of the reference's op-log-over-snapshot delta
-        # (roaring.go:228-249) — instead of re-uploading the whole [S, W]
-        # stack for a single set_bit. A compressed container has no
-        # per-shard planes to scatter into, so it decompresses ON
-        # DEVICE once and the fragment decays to dense under write
-        # churn — the same convert-on-mutation policy as the
-        # reference's roaring containers; the chooser re-compresses at
-        # the next full rebuild/readmission, when the density is known
-        # again.
+        # bumps only its fragment's generation of the rows it wrote),
+        # gather + upload ONLY those planes and scatter them into the
+        # cached device stack — the device analog of the reference's
+        # op-log-over-snapshot delta (roaring.go:228-249) — instead of
+        # re-uploading the whole [S, W] stack for a single set_bit. A
+        # compressed container has no per-shard planes to scatter into,
+        # so it decompresses ON DEVICE once and the fragment decays to
+        # dense under write churn — the same convert-on-mutation policy
+        # as the reference's roaring containers; the chooser
+        # re-compresses at the next full rebuild/readmission, when the
+        # density is known again.
         stale = self._stale_entry(key, gens)
         if stale is not None:
             if self._serve_stale(key, idx.name, field_name, VIEW_STANDARD,
@@ -1163,11 +1174,11 @@ class StackedEvaluator:
         view = field.view(view_name) if field is not None else None
         if view is None:
             return None
+        stamp = view.stamp()
         if cache:
-            hit = self._cache_get_fast(key, (view.uid, view.mutations))
+            hit = self._cache_get_fast(key, stamp)
             if hit is not None:
                 return _looked_up(span, "hit", hit)
-        stamp = (view.uid, view.mutations)
         gens = self._fragment_gens(idx, field_name, shards, view_name,
                                    view=view)
         if gens is None:
@@ -1226,10 +1237,10 @@ class StackedEvaluator:
         view = field.view(view_name)
         if view is None:
             return None
-        hit = self._cache_get_fast(key, (view.uid, view.mutations))
+        stamp = view.stamp()
+        hit = self._cache_get_fast(key, stamp)
         if hit is not None:
             return _looked_up(span, "hit", hit)
-        stamp = (view.uid, view.mutations)
         gens = self._fragment_gens(idx, field_name, shards, view_name,
                                    view=view)
         if gens is None:
@@ -2580,16 +2591,17 @@ class StackedEvaluator:
         if view is None:
             return False, 0, "dense"
         pool, _ = self._pool(key)
+        row_id = key[3] if key[0] == "leaf" else None
         with self._lock:
             hit = pool.get(key)
             if hit is None:
                 return False, 0, "dense"
-            if hit[3] == (view.uid, view.mutations):
+            if hit[3] == view.stamp(row_id):
                 return True, hit[2], _containers.kind_of(hit[1])
         # stamp drifted: fall back to the exact generation walk (done
         # outside the pool lock — it touches fragment containers)
         gens = self._fragment_gens(idx, field_name, key[-1], view_name,
-                                   view=view)
+                                   view=view, row_id=row_id)
         if gens is None:
             return False, 0, "dense"
         with self._lock:

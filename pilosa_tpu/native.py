@@ -50,7 +50,15 @@ def _load():
                     subprocess.run(
                         ["make", "-C", _NATIVE_DIR],
                         check=True, capture_output=True, timeout=120)
-                lib = ctypes.CDLL(_SO_PATH)
+                # PyDLL: the calls keep the interpreter lock. They run
+                # for microseconds (a container is 8 KiB, a row plane
+                # 128 KiB) and touch no Python object; releasing the lock
+                # around each one hands it to whichever thread is
+                # runnable, and the caller then queues for it again. A
+                # 64-pair import makes ~290 such calls: under 32 reading
+                # clients it waited 230 ms for the lock, 88 ms with it
+                # kept (PERF.md section 6, PR 29).
+                lib = ctypes.PyDLL(_SO_PATH)
                 _declare(lib)
             except Exception as e:
                 import warnings

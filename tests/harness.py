@@ -44,6 +44,34 @@ class ServerHarness:
         self.holder.close()
 
 
+def load_segment_index(h, segment, cfg, seed):
+    """Create the benchmark configuration `cfg`'s index on harness `h` and
+    send every fragment as the benchmark's loader sends it: a roaring blob
+    a field and shard (array containers) from `segment.shard_planes`.
+    Returns (bits sent, bits `import_roaring` acknowledged)."""
+    import numpy as np
+
+    from pilosa_tpu.roaring import Bitmap, serialize
+
+    h.client.create_index(cfg["index"])
+    for field in cfg["fields"]:
+        h.client.create_field(cfg["index"], field)
+    per_row = cfg["shard_width"] // (1 << 16)
+    sent = acknowledged = 0
+    for shard in range(cfg["shards"]):
+        planes = segment.shard_planes(cfg, seed, shard)
+        for field in cfg["fields"]:
+            bitmap = Bitmap()
+            for row in cfg["rows"]:
+                bitmap.replace_dense_words(row * per_row, per_row,
+                                           planes[field, row])
+                sent += int(np.bitwise_count(planes[field, row]).sum())
+            acknowledged += h.client.import_roaring(
+                cfg["index"], field, shard,
+                serialize(bitmap, optimize=False))["changed"]
+    return sent, acknowledged
+
+
 class ClusterHarness:
     """n in-process nodes with a shared static topology (reference:
     test.MustRunCluster test/pilosa.go:390 — real servers, real HTTP,

@@ -13,7 +13,6 @@ import threading
 import types
 import urllib.request
 
-import numpy as np
 import pytest
 
 from pilosa_tpu.exec import stacked
@@ -57,28 +56,12 @@ def served(bench, tmp_path_factory):
     sent as the benchmark's loader sends it (a roaring blob a field and
     shard, array containers), and the oracle's answer to each of the 64
     distinct queries."""
-    from pilosa_tpu.roaring import Bitmap, serialize
-    from tests.harness import ServerHarness
+    from tests.harness import ServerHarness, load_segment_index
 
     cfg = bench.config
     h = ServerHarness(data_dir=str(tmp_path_factory.mktemp("r8")))
     try:
-        h.client.create_index(cfg["index"])
-        for field in cfg["fields"]:
-            h.client.create_field(cfg["index"], field)
-        per_row = cfg["shard_width"] // (1 << 16)
-        sent = acknowledged = 0
-        for shard in range(SHARDS):
-            planes = bench.segment.shard_planes(cfg, SEED, shard)
-            for field in cfg["fields"]:
-                bitmap = Bitmap()
-                for row in cfg["rows"]:
-                    bitmap.replace_dense_words(row * per_row, per_row,
-                                               planes[field, row])
-                    sent += int(np.bitwise_count(planes[field, row]).sum())
-                acknowledged += h.client.import_roaring(
-                    cfg["index"], field, shard,
-                    serialize(bitmap, optimize=False))["changed"]
+        sent, acknowledged = load_segment_index(h, bench.segment, cfg, SEED)
         assert sent == acknowledged > 0
         pqls = bench.traffic.distinct_queries(bench.spec)
         h.expected = bench.segment.expected(cfg, SEED, pqls)

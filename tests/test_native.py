@@ -30,6 +30,15 @@ def test_library_builds_and_loads():
     assert native.enabled()
 
 
+def test_calls_keep_the_interpreter_lock():
+    """A foreign call that releases the lock costs its caller a place in
+    the queue for it; these calls are microseconds long and an import
+    makes hundreds (PERF.md section 6, PR 29)."""
+    import ctypes
+
+    assert isinstance(native._load(), ctypes.PyDLL)
+
+
 def test_fnv1a32_differential(rng):
     for size in (0, 1, 13, 1000):
         data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()

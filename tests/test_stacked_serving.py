@@ -398,6 +398,180 @@ def test_count_patch_on_single_shard_write(tmp_path):
     holder.close()
 
 
+ROWS_1_AND_2 = "Count(Union(Row(f=1), Row(f=2)))"
+
+
+class _Oracle:
+    """The host's own record of which columns each row of `f` holds."""
+
+    def __init__(self, n_shards=0):
+        self.rows = {}
+        for s in range(n_shards):       # what _build_index loads into f
+            for r in range(6):
+                self.add(r, [s * SHARD_WIDTH + r, s * SHARD_WIDTH + 64 + r])
+
+    def add(self, row, cols):
+        self.rows.setdefault(row, set()).update(cols)
+
+    def union(self, *rows):
+        return len(set().union(*(self.rows.get(r, set()) for r in rows)))
+
+
+def _cache_counters(st):
+    return {"hits": st.hits, "misses": st.misses, "patches": st.patches,
+            "planes_uploaded": st.planes_uploaded, "builds": st.builds}
+
+
+def test_a_write_stales_only_the_stacks_of_the_rows_it_wrote(tmp_path):
+    """A leaf stack is held to what happened to ITS row: an import into
+    row 100 leaves the stacks of rows 1 and 2 on the fast path (nothing
+    walked, gathered, copied or placed), an import into row 1 patches
+    that one stack's one plane, and the answers stay the host's."""
+    n_shards = 16
+    holder, api = _build_index(tmp_path, "rowgen", n_shards)
+    oracle = _Oracle(n_shards)
+    e = Executor(holder)
+    st = e._stacked
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2)
+    warm = _cache_counters(st)
+
+    # 64 pairs into row 100 of the fragment the warm stacks read (shard 3)
+    cols = [3 * SHARD_WIDTH + 1000 + k for k in range(64)]
+    assert api.import_bits("i", "f", [100] * 64, cols)
+    oracle.add(100, cols)
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2)
+    after = _cache_counters(st)
+    assert after == dict(warm, hits=warm["hits"] + 2), (warm, after)
+    # the written row is readable at once, from a stack of its own
+    assert e.execute("i", "Count(Row(f=100))")[0] == 64
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2)
+
+    # the same import into row 1: one stack is stale, by one plane
+    before = _cache_counters(st)
+    cols = [3 * SHARD_WIDTH + 2000 + k for k in range(64)]
+    api.import_bits("i", "f", [1] * 64, cols)
+    oracle.add(1, cols)
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2)
+    after = _cache_counters(st)
+    assert after["patches"] == before["patches"] + 1
+    assert after["planes_uploaded"] == before["planes_uploaded"] + 1
+    assert after["misses"] == before["misses"] + 1
+    assert after["builds"] == before["builds"]
+    # and row 2's answer alone never moved
+    assert e.execute("i", "Count(Row(f=2))")[0] == oracle.union(2)
+    holder.close()
+
+
+def test_a_field_made_again_and_a_new_fragment_still_invalidate(tmp_path):
+    """What a row's generation cannot see — the field dropped and made
+    again, a fragment appearing where the stack holds a zero plane — goes
+    through the view's structure tick: every leaf stack walks again."""
+    n_shards = 8
+    holder, api = _build_index(tmp_path, "structure", n_shards)
+    e = Executor(holder)
+    st = e._stacked
+    assert e.execute("i", ROWS_1_AND_2)[0] == _Oracle(n_shards).union(1, 2)
+
+    api.delete_field("i", "f")
+    api.create_field("i", "f")
+    oracle = _Oracle()
+    cols = [5, SHARD_WIDTH + 5]
+    api.import_bits("i", "f", [1, 1], cols)
+    oracle.add(1, cols)
+    # `flt` keeps the index at 8 shards: the stacks' keys are the same
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2) == 2
+
+    # f has fragments in shards 0 and 1 only; one appears in shard 5,
+    # made by a write to a row that no stack holds
+    warm = _cache_counters(st)
+    api.import_bits("i", "f", [100], [5 * SHARD_WIDTH + 9])
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2)
+    after = _cache_counters(st)
+    assert after["misses"] == warm["misses"] + 2     # both stacks walked
+    # then the new fragment's row 1 is written: seen at once
+    api.import_bits("i", "f", [1], [5 * SHARD_WIDTH + 10])
+    oracle.add(1, [5 * SHARD_WIDTH + 10])
+    assert e.execute("i", ROWS_1_AND_2)[0] == oracle.union(1, 2) == 3
+    holder.close()
+
+
+def test_readers_under_a_writer_see_the_before_or_the_after(tmp_path):
+    """8 readers of rows 1 and 2 while a writer alternates imports into
+    row 100 (stales nothing they read) and row 1 (one bit a write): a
+    Count of row 1 lies between what was acknowledged when it was sent
+    and what had been started when it came back, row 2 never moves, and
+    at rest every answer is the host's."""
+    import sys
+    import threading
+
+    n_shards = 8
+    holder, api = _build_index(tmp_path, "threads", n_shards)
+    oracle = _Oracle(n_shards)
+    e = Executor(holder)
+    base1, base2 = oracle.union(1), oracle.union(2)
+    assert e.execute("i", "Count(Row(f=1))")[0] == base1
+    assert e.execute("i", "Count(Row(f=2))")[0] == base2
+    started = [0]       # writes into row 1 begun
+    acked = [0]         # ... and returned
+    stop = threading.Event()
+    wrong, errors = [], []
+
+    def writer():
+        try:
+            for k in range(40):
+                shard = k % n_shards
+                col = shard * SHARD_WIDTH + 5000 + k
+                if k % 2:
+                    started[0] += 1
+                    api.import_bits("i", "f", [1], [col])
+                    oracle.add(1, [col])
+                    acked[0] += 1
+                else:
+                    api.import_bits("i", "f", [100], [col])
+                    oracle.add(100, [col])
+        except Exception as exc:  # noqa: BLE001 — the assert below says it
+            errors.append(repr(exc))
+        finally:
+            stop.set()
+
+    def reader(number):
+        try:
+            while not stop.is_set():
+                if number % 2:
+                    got = e.execute("i", "Count(Row(f=2))")[0]
+                    if got != base2:
+                        wrong.append(("row 2", got))
+                    continue
+                low = base1 + acked[0]
+                got = e.execute("i", "Count(Row(f=1))")[0]
+                high = base1 + started[0]
+                if not low <= got <= high:
+                    wrong.append(("row 1", low, got, high))
+        except Exception as exc:  # noqa: BLE001
+            errors.append(repr(exc))
+
+    threads = [threading.Thread(target=reader, args=(n,), daemon=True)
+               for n in range(8)]
+    threads.append(threading.Thread(target=writer, daemon=True))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not wrong, (errors, wrong[:5])
+    assert acked[0] == 20
+    for rows in ((1,), (2,), (100,), (1, 2)):
+        pql = ("Count(Row(f=%d))" % rows if len(rows) == 1
+               else ROWS_1_AND_2)
+        assert e.execute("i", pql)[0] == oracle.union(*rows), pql
+    holder.close()
+
+
 def test_sum_patch_on_single_shard_write(tmp_path):
     """BSI stacks patch incrementally too: a single set_value re-uploads
     one shard's D+2 planes, not depth x shards."""
