@@ -19,7 +19,7 @@ DISTRIBUTED = tests/test_clusterproc.py tests/test_spmd.py \
 	test-flightrec test-devhealth test-explain test-durability \
 	test-workload test-batching test-containers test-adaptive \
 	test-ingest test-admission test-fusion test-incident \
-	test-spmd-mesh test-meshobs lint bench-cpu
+	test-spmd-mesh test-meshobs lint
 
 test: test-core test-distributed test-flightrec test-devhealth \
 	test-explain test-durability test-workload test-batching \
@@ -62,12 +62,12 @@ test-durability:
 test-workload:
 	$(PY) -m pytest tests/test_workload.py $(PYTEST_FLAGS)
 
-# Batched dispatch pipeline surface: vmapped batch kernels (bucket
-# padding, bit-identity vs serial), the query coalescer (fusing,
-# overload 503s, window=0 legacy identity), the query-batch route,
-# /debug/batching, and batch= attribution in SLOW QUERY / ANALYZE.
+# Batching surface: GroupCommit (concurrent Counts sharing launches,
+# solo-equal answers, leader failure, unbuilt buckets), the query-batch
+# route, and batch= attribution in SLOW QUERY lines.
 test-batching:
-	$(PY) -m pytest tests/test_batching.py $(PYTEST_FLAGS)
+	$(PY) -m pytest tests/test_group_commit.py tests/test_query_batch.py \
+		$(PYTEST_FLAGS)
 
 # Query observability surface: per-query profiles, histograms, the
 # slow-query log, trace retention, and the exposition formats.
@@ -84,7 +84,7 @@ test-parallel:
 
 # Compressed container surface: representation builders/kernels, the
 # per-fragment chooser, the differential corpus (compressed == dense
-# bit-identity across densities, reprs, and batch buckets), and the
+# bit-identity across densities, reprs, and concurrent batches), and the
 # /debug compression surfaces.
 test-containers:
 	$(PY) -m pytest tests/test_containers.py $(PYTEST_FLAGS)
@@ -124,7 +124,7 @@ test-incident:
 # Mesh-resident SPMD serving surface: the fast in-process units plus the
 # 2-process gloo CPU mesh (marked slow, so deliberately NOT filtered by
 # -m 'not slow' here): on==off==http bit-exactness over the query mix,
-# K-coalesced Counts as ONE collective step, warm fused queries with
+# warm fused queries with
 # zero HTTP result bytes, step-stream lifecycle counters, and ?explain
 # mesh plans.
 test-spmd-mesh:
@@ -145,16 +145,8 @@ test-meshobs:
 # has no linters baked in and installs are not allowed).
 lint:
 	@if $(PY) -m ruff --version >/dev/null 2>&1; then \
-		$(PY) -m ruff check pilosa_tpu tests bench.py bench_suite.py \
-			bench_kernels.py; \
+		$(PY) -m ruff check pilosa_tpu tests; \
 	else \
 		echo "ruff not installed; falling back to compileall"; \
-		$(PY) -m compileall -q pilosa_tpu tests bench.py \
-			bench_suite.py bench_kernels.py; \
+		$(PY) -m compileall -q pilosa_tpu tests; \
 	fi
-
-# The north-star benchmark's control flow on the host, asked for explicitly
-# (a shrunken, CPU-labelled shape: it checks the harness, it is not a
-# measurement). One JSON line.
-bench-cpu:
-	JAX_PLATFORMS=cpu $(PY) bench.py

@@ -365,12 +365,18 @@ def phase_kernels(sm):
 
 
 def phase_build(sm):
-    """Delete and rebuild the native library; the servers load it."""
-    for name in os.listdir(NATIVE_DIR):
-        if name.startswith("libpilosa_native.so"):
-            os.unlink(os.path.join(NATIVE_DIR, name))
-    res = subprocess.run(["make", "-C", NATIVE_DIR], capture_output=True,
-                         text=True, timeout=300)
+    """Delete and rebuild the native library; the servers load it. Under
+    the lock pilosa_tpu/native.py builds under: another process's first
+    import must not run the same make between the unlink and the rename."""
+    import fcntl
+
+    with open(os.path.join(NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        for name in os.listdir(NATIVE_DIR):
+            if name.startswith("libpilosa_native.so"):
+                os.unlink(os.path.join(NATIVE_DIR, name))
+        res = subprocess.run(["make", "-C", NATIVE_DIR],
+                             capture_output=True, text=True, timeout=300)
     so = os.path.join(NATIVE_DIR, "libpilosa_native.so")
     if res.returncode != 0 or not os.path.exists(so):
         sm.fail("build", f"make -C native exited {res.returncode}: "

@@ -204,11 +204,6 @@ def cmd_server(args):
     # flag-merged by _apply_server_flags.
     lqt = config.get("long-query-time")
     mwpr = config.get("max-writes-per-request", 0)
-    # Query coalescer (batched dispatch pipeline): window 0 — the
-    # default — keeps the legacy per-query path bit-identical.
-    cw = config.get("coalesce-window")
-    coalesce_window = parse_duration(str(cw)) if cw else 0.0
-    coalesce_max_queue = int(config.get("coalesce-max-queue", 256))
     # Streaming ingest engine: interval 0 — the default — keeps the
     # legacy apply-then-invalidate write path byte-identical.
     imi = config.get("ingest-merge-interval")
@@ -243,8 +238,6 @@ def cmd_server(args):
               long_query_time=parse_duration(lqt) if lqt else None,
               max_writes_per_request=int(mwpr),
               spmd=spmd, oplog=oplog,
-              coalesce_window=coalesce_window,
-              coalesce_max_queue=coalesce_max_queue,
               ingest_interval=ingest_interval,
               admission=admission,
               admission_capacity=float(adm_cap) if adm_cap else None,
@@ -314,14 +307,12 @@ def cmd_server(args):
     # (exec/plan.py module state, like the flight recorder above).
     prs = config.get("plan-ring-size")
     emf = config.get("explain-misestimate-factor")
-    if prs is not None or emf is not None or coalesce_window > 0:
+    if prs is not None or emf is not None:
         from .exec import plan as _plan
 
         _plan.configure(
             ring_size=int(prs) if prs is not None else None,
-            misestimate_factor=float(emf) if emf is not None else None,
-            coalesce_window=coalesce_window if coalesce_window > 0
-            else None)
+            misestimate_factor=float(emf) if emf is not None else None)
 
     # Container representation policy (ops/containers.py module state):
     # "auto" lets the per-fragment chooser pick dense/sparse/rle by
@@ -558,8 +549,6 @@ def cmd_server(args):
         if monitor:
             monitor.stop()
         server.stop()
-        # AFTER server.stop(): in-flight handlers blocked on the
-        # coalescer wake with 503 instead of hanging the shutdown
         api.close()
         holder.close()
         if oplog is not None:
@@ -883,7 +872,6 @@ def _apply_server_flags(config, args):
                  "plan_ring_size", "explain_misestimate_factor",
                  "device_probe_interval", "device_probe_deadline",
                  "slo", "slo_burn_threshold",
-                 "coalesce_window", "coalesce_max_queue",
                  "container_repr", "adaptive",
                  "fusion", "fusion_cache_size", "fusion_min_hits",
                  "ingest_merge_interval",
@@ -1121,12 +1109,6 @@ def main(argv=None):
     p.add_argument("--device-probe-deadline", default=None,
                    help="per-canary deadline (e.g. 5s) before a probe "
                         "counts as a device-link failure (default 5s)")
-    p.add_argument("--coalesce-window", default=None,
-                   help="query coalescer window (e.g. 2ms): concurrent "
-                        "batchable queries arriving within it fuse into "
-                        "one vmapped batched dispatch, amortizing the "
-                        "dispatch RTT (default 0 = disabled, legacy "
-                        "per-query path)")
     p.add_argument("--container-repr", default=None,
                    choices=["auto", "dense", "sparse", "rle"],
                    help="device container representation policy: auto "
@@ -1134,10 +1116,6 @@ def main(argv=None):
                         "per fragment by measured density; dense forces "
                         "the legacy bit-identical planes; sparse/rle "
                         "force one compressed format where eligible")
-    p.add_argument("--coalesce-max-queue", type=int, default=None,
-                   help="coalesce queue cap: past it, queries get 503 + "
-                        "Retry-After instead of unbounded wait "
-                        "(default 256)")
     p.add_argument("--adaptive", default=None,
                    choices=["off", "on", "shadow"],
                    help="adaptive execution engine: on prices "
@@ -1303,8 +1281,6 @@ def main(argv=None):
     p.add_argument("--device-probe-deadline", default=None)
     p.add_argument("--slo", action="append", default=None)
     p.add_argument("--slo-burn-threshold", type=float, default=None)
-    p.add_argument("--coalesce-window", default=None)
-    p.add_argument("--coalesce-max-queue", type=int, default=None)
     p.add_argument("--container-repr", default=None,
                    choices=["auto", "dense", "sparse", "rle"])
     p.add_argument("--adaptive", default=None,

@@ -176,12 +176,6 @@ class ClusterExecutor:
     Wraps exec.Executor. With a single-node cluster (or none) it degrades
     to purely local execution."""
 
-    #: what the query coalescer may batch THROUGH a cluster coordinator:
-    #: only Count merges as one collective step (cluster/spmd.py
-    #: SpmdBatchRunner); the local Executor's wider set applies on
-    #: single nodes and fan-out legs
-    BATCHABLE_CALLS = frozenset(("Count",))
-
     def __init__(self, holder, cluster, client_factory, spmd=None,
                  logger=None, max_writes_per_request=0):
         from ..utils.logger import NopLogger
@@ -600,7 +594,7 @@ class ClusterExecutor:
                     shed = getattr(e, "shed", None)
                     if shed is not None:
                         # the peer is SHEDDING (X-Pilosa-Shed: admission /
-                        # coalesce / ingest back-pressure), not dead:
+                        # ingest back-pressure), not dead:
                         # honor its Retry-After (capped — a fan-out leg
                         # can't idle for seconds) and retry the SAME
                         # replica once before moving on

@@ -110,14 +110,26 @@ def sig_from_wire(wire):
 STEP_PHASES = ("announce_recv", "stack_gather", "device_enter", "psum",
                "result_fetch", "exit")
 
+#: padding buckets of a `count_batch` step: a fused query's K plans are
+#: padded up to the next bucket (repeating plan 0), so at most
+#: len(BATCH_BUCKETS) collective programs compile per signature set
+BATCH_BUCKETS = (1, 4, 16, 64)
+
+
+def batch_bucket(n):
+    """Smallest padding bucket holding `n` plans."""
+    for b in BATCH_BUCKETS:
+        if n <= b:
+            return b
+    return BATCH_BUCKETS[-1]
+
 
 class _StepClock:
     """Phase marks within one collective step — the PR-6 _PhaseClock
     contract (exec/stacked.py) lifted to the step plane: `mark(phase)`
     attributes the time since the previous mark (or the announcement
     receipt) to `phase`; `close()` folds any residual into the terminal
-    phase so the per-phase seconds sum EXACTLY to the step wall (the
-    bench meshobs leg asserts the 5% version of this cross-process)."""
+    phase so the per-phase seconds sum EXACTLY to the step wall."""
 
     __slots__ = ("t0", "_t", "phases")
 
@@ -333,7 +345,6 @@ class SpmdDataPlane:
         self.last_seq = 0
         # batched/fused collective accounting
         self.batch_steps = 0
-        self.batched_queries = 0
         self.fused_steps = 0
         self.fused_queries = 0
         # Count pre-flight epochs: {index: membership epoch} of the last
@@ -875,13 +886,10 @@ class SpmdDataPlane:
             return None
         return self._execute_step(step)
 
-    # -- batched collective steps (PR-9 coalescer x mesh) --------------------
-
-    def _cluster_ready(self, forwarded=False):
-        """The maybe_execute cluster gates, shared by the batch and fused
-        entries: coordinator-only (they are called from the coalescer /
-        executor on the serving node), every node READY, membership
-        unchanged since distributed init."""
+    def _cluster_ready(self):
+        """The maybe_execute_fused cluster gates: coordinator-only (the
+        executor calls it on the serving node), every node READY,
+        membership unchanged since distributed init."""
         cluster = self.cluster
         if cluster is None or len(cluster.nodes) < 2:
             return False
@@ -897,7 +905,7 @@ class SpmdDataPlane:
 
     def _count_plans(self, idx, calls):
         """Wire plans for a list of Count calls, or None when any call
-        isn't coverable (the whole batch falls back — splitting would
+        isn't coverable (the whole query falls back — splitting would
         break the one-announcement contract)."""
         plans = []
         for call in calls:
@@ -911,48 +919,6 @@ class SpmdDataPlane:
                           "leaves": [self._leaf_to_wire(k)
                                      for k in leaf_keys]})
         return plans
-
-    def maybe_execute_batch(self, idx, calls, shards):
-        """K eligible Count calls as ONE collective step: (used, counts).
-        The PR-9 coalescer's cluster adapter (SpmdBatchRunner) lands
-        here; serve_mode must be on — batching changes the control-plane
-        shape, so it never runs on the byte-identical legacy path."""
-        if self.serve_mode != "on" or not calls:
-            return False, None
-        if not self._cluster_ready():
-            return False, None
-        plans = self._count_plans(idx, calls)
-        if plans is None:
-            return False, None
-        from ..exec.stacked import batch_bucket
-
-        step = self._gate(idx, shards)
-        step["kind"] = "count_batch"
-        k = len(plans)
-        bucket = batch_bucket(k)
-        # pad to the bucket by repeating plan 0 — the mesh cache serves
-        # the repeats from device memory and the vmapped group evaluates
-        # them in the same walk, so padding is near-free (PR-9 contract)
-        step["plans"] = plans + [plans[0]] * (bucket - k)
-        step["bucket"] = bucket
-        if not self._ensure_count_epoch(step):
-            return False, None
-        from ..utils import tracing
-
-        try:
-            with tracing.start_span("spmd.step", kind="count_batch",
-                                    shards=len(shards), batch=k) as span:
-                counts = self._execute_step(step)
-                self._graft_span(span)
-        except Exception as e:
-            self.fallbacks += 1
-            self._count_epochs.pop(idx.name, None)
-            self.logger.printf(
-                "spmd: count_batch step failed (%s); epoch invalidated, "
-                "falling back to per-query path", e)
-            return False, None
-        self.batched_queries += k
-        return True, counts[:k]
 
     # -- fused collective programs (PR-16 fusion x mesh) ---------------------
 
@@ -983,12 +949,12 @@ class SpmdDataPlane:
         plans = self._count_plans(idx, calls)
         if plans is None:
             return False, None
-        from ..exec.stacked import batch_bucket
-
         step = self._gate(idx, shards)
         step["kind"] = "count_batch"
         k = len(plans)
         bucket = batch_bucket(k)
+        # pad to the bucket by repeating plan 0: the mesh cache serves
+        # the repeats from device memory
         step["plans"] = plans + [plans[0]] * (bucket - k)
         step["bucket"] = bucket
         if not self._ensure_count_epoch(step):
@@ -2030,8 +1996,7 @@ class SpmdDataPlane:
         """K Count trees in one program. Runs of IDENTICAL (sig, arity)
         — the common case after bucket padding repeats plans[0] — are
         stacked on a new leading axis and evaluated with ONE vmapped
-        tree walk (PR-9's batching shape, lifted to the collective
-        plane); distinct signatures evaluate inline in the same trace.
+        tree walk; distinct signatures evaluate inline in the same trace.
         Either way XLA sees a single program and inserts ONE
         cross-process reduce for all K outputs. Returns a single
         stacked [2, K] array — row 0 the hi halves, row 1 the lo
@@ -2239,7 +2204,6 @@ class SpmdDataPlane:
                 "forward_errors": self.forward_errors,
                 "fallbacks": self.fallbacks,
                 "batch_steps": self.batch_steps,
-                "batched_queries": self.batched_queries,
                 "fused_steps": self.fused_steps,
                 "fused_queries": self.fused_queries}
 
@@ -2275,7 +2239,6 @@ class SpmdDataPlane:
                 "fused": self.fused_steps,
             },
             "queries": {
-                "batched": self.batched_queries,
                 "fused": self.fused_queries,
                 "forwarded": self.forwarded,
                 "fallbacks": self.fallbacks,
@@ -2478,7 +2441,6 @@ class SpmdDataPlane:
                 "fused": self.fused_steps,
             },
             "queries": {
-                "batched": self.batched_queries,
                 "fused": self.fused_queries,
                 "forwarded": self.forwarded,
                 "fallbacks": self.fallbacks,
@@ -2508,114 +2470,3 @@ class SpmdDataPlane:
         except Exception as e:  # noqa: BLE001 — never fail the bundle
             snap["timeline_error"] = str(e)
         return snap
-
-
-class SpmdBatchRunner:
-    """PR-9 coalescer adapter for cluster coordinators (serve == on):
-    presents Executor.launch_batch/resolve_batch's (handle, state) ->
-    [(results, error, batch, fingerprint)] contract, but resolves
-    eligible Count batches as ONE collective step
-    (SpmdDataPlane.maybe_execute_batch) instead of local vmapped
-    dispatches — one announcement, one program, one psum for K queries.
-    Launch is deliberately cheap: the collective IS the fused dispatch
-    (there is no device enqueue to overlap), so the coalescer's
-    double-buffering degenerates to serial resolution without waste.
-    Anything ineligible or declined re-runs on the ordinary cluster
-    path per member (per-query error isolation, PR-9 contract)."""
-
-    #: what server.api._try_coalesce admits on a cluster coordinator —
-    #: only Count merges collectively; other batchable families stay on
-    #: the per-query cluster path
-    BATCHABLE_CALLS = frozenset(("Count",))
-
-    def __init__(self, api):
-        self.api = api
-        self.spmd = api.spmd
-
-    def launch_batch(self, index_name, queries, shards=None,
-                     options=None):
-        return None, (index_name, list(queries))
-
-    def resolve_batch(self, handle, state):
-        import copy
-        import time as _time
-
-        from ..exec.executor import validate_uint_args
-        from ..exec.stacked import BATCH_BUCKETS
-        from ..exec.translate import translate_calls, translate_results
-        from ..utils import workload as workload_mod
-
-        index_name, queries = state
-        executor = self.api.executor
-        idx = executor.holder.index(index_name)
-        entries = []
-        for query in queries:
-            # e["raw"] is the untranslated form every fallback must
-            # re-execute from — translation mutates the call tree in
-            # place and is not idempotent (exec.executor.launch_batch)
-            e = {"query": query, "raw": query, "error": None,
-                 "eligible": False, "out": None}
-            entries.append(e)
-            if idx is None:
-                e["error"] = SpmdError(f"index not found: {index_name}")
-                continue
-            try:
-                if isinstance(query, str):
-                    query = e["query"] = parse(query)
-                calls = query.calls
-                if len(calls) == 1 and calls[0].name == "Count" \
-                        and len(calls[0].children) == 1 \
-                        and not calls[0].writes():
-                    if not isinstance(e["raw"], str):
-                        e["raw"] = copy.deepcopy(query)
-                    translate_calls(idx, query.calls)
-                    validate_uint_args(calls[0])
-                    e["eligible"] = True
-            except Exception as exc:  # noqa: BLE001 — per-query isolation
-                e["error"] = exc
-        eligible = [e for e in entries
-                    if e["eligible"] and e["error"] is None]
-        if eligible:
-            cluster_shards = executor.cluster_shards(idx)
-            cap = BATCH_BUCKETS[-1]
-            for i in range(0, len(eligible), cap):
-                chunk = eligible[i:i + cap]
-                calls = [e["query"].calls[0] for e in chunk]
-                t0 = _time.perf_counter()
-                used, counts = self.spmd.maybe_execute_batch(
-                    idx, calls, cluster_shards)
-                if not used:
-                    continue  # whole chunk re-runs per-query below
-                wall = _time.perf_counter() - t0
-                k = len(chunk)
-                for j, (e, count) in enumerate(zip(chunk, counts)):
-                    try:
-                        wctx = workload_mod.begin_query(
-                            idx.name, e["query"])
-                        wctx.strategies.append("Count=spmd-collective")
-                        workload_mod.note_batch(k)
-                        # charge the step's one dispatch to exactly ONE
-                        # member (exec.executor.resolve_batch rule)
-                        workload_mod.end_query(wctx, wall / k, deltas={
-                            "dispatches": 1 if j == 0 else 0,
-                            "cache_hits": 0, "cache_misses": 0,
-                            "bytes_materialized": 0})
-                        results = translate_results(
-                            idx, e["query"].calls, [int(count)])
-                        e["out"] = (results, None, k, wctx.fingerprint)
-                    except Exception as exc:  # noqa: BLE001
-                        e["out"] = (None, exc, 0, None)
-        outs = []
-        for e in entries:
-            if e["out"] is not None:
-                outs.append(e["out"])
-            elif e["error"] is not None:
-                outs.append((None, e["error"], 0, None))
-            else:
-                try:
-                    results = executor.execute(index_name, e["raw"])
-                    outs.append((results, None, 0,
-                                 workload_mod.last_fingerprint()))
-                except Exception as exc:  # noqa: BLE001
-                    outs.append((None, exc, 0, None))
-        return outs

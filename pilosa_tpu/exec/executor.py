@@ -458,10 +458,10 @@ class Executor:
         run the whole query as one fused program, then graft the single
         dispatch's actuals — the whole-query delta lands on the first
         node and the rest graft a zero delta, so the summed per-node
-        `dispatches` actuals equal the real total (the ==1 claim the
-        bench leg asserts). Returns the results list, or None when the
-        query didn't fuse — the caller's legacy analyze loop then
-        builds its own nodes (the ones made here are discarded)."""
+        `dispatches` actuals equal the real total. Returns the results
+        list, or None when the query didn't fuse — the caller's legacy
+        analyze loop then builds its own nodes (the ones made here are
+        discarded)."""
         import time as _time
 
         from . import fusion as fusion_mod
@@ -598,10 +598,9 @@ class Executor:
             extra_missing_bytes=missing_rows * plane)
 
     def maybe_proactive_admit(self, max_rows=None, max_bytes=None):
-        """Bounded proactive admission of hot_but_not_resident fragments
-        — called from idle windows (the coalescer drain loop between
-        batches) so demand heat translates into residency BEFORE the
-        next query pays the cold build. Skips entirely when the adaptive
+        """Bounded proactive admission of hot_but_not_resident fragments,
+        so demand heat translates into residency BEFORE the next query
+        pays the cold build. Skips entirely when the adaptive
         engine is off or a dispatch is in flight (admission must never
         queue behind — or ahead of — real serving traffic). Returns the
         number of fragments admitted (shadow: candidates counted, none
@@ -721,210 +720,6 @@ class Executor:
             else idx.available_shards()
         self._explain_tls.shards = len(out)
         return out
-
-    # --------------------------------------------------- batched execution
-
-    #: single-call read families the batched pipeline can vectorize into
-    #: one vmapped dispatch; anything else (aggregates, TopN, writes,
-    #: multi-call requests) falls back to the per-query path per member
-    BATCHABLE_CALLS = frozenset((
-        "Count", "Row", "Range", "Intersect", "Union", "Difference",
-        "Xor"))
-
-    def launch_batch(self, index_name, queries, shards=None, options=None):
-        """Phase 1 of batched execution: parse/translate/classify every
-        query, gather leaf stacks for the batchable ones, and fuse them
-        into bucket-padded vmapped dispatches WITHOUT fetching results.
-        Returns (handle, state) for resolve_batch. Per-member failures
-        are captured in the member's slot, never raised — one bad query
-        must not sink its batchmates (per-query error isolation)."""
-        import copy
-        import time as _time
-
-        from ..utils import workload as workload_mod
-        from .translate import translate_calls
-
-        idx = self.holder.index(index_name)
-        if idx is None:
-            raise ExecError(f"index not found: {index_name}")
-        opt = options or ExecOptions()
-        shard_list = self._call_shards(idx, shards)
-        entries = []
-        items = []
-        # Coalesced traffic repeats hot queries, so identical PQL
-        # strings in one batch share a single parsed (and translated)
-        # AST: members only ever read it past this loop. Translation is
-        # tracked per AST so a shared tree is key-translated exactly
-        # once — it mutates in place and is not idempotent.
-        parsed_cache = {}
-        translated = set()
-        for query in queries:
-            # e["raw"] is the member's UNTRANSLATED form: key translation
-            # mutates the call tree in place and is not idempotent (a
-            # keyed row arg becomes an int; re-translating raises), so
-            # every fallback re-execution — not-batchable shape, gather
-            # miss, fused-dispatch failure — must start from this, never
-            # from e["query"], which execute() would translate again.
-            e = {"query": query, "raw": query, "error": None, "item": None,
-                 "fallback": False, "wctx": None, "deltas": None,
-                 "call": None, "kind": None, "t0": _time.perf_counter()}
-            entries.append(e)
-            try:
-                if isinstance(query, str):
-                    q = parsed_cache.get(query)
-                    if q is None:
-                        q = parsed_cache[query] = parse(query)
-                    query = e["query"] = q
-                check_write_limit(query, self.max_writes_per_request)
-                call = query.calls[0] if len(query.calls) == 1 else None
-                if call is None or call.name not in self.BATCHABLE_CALLS:
-                    # left untranslated: execute() runs translation
-                    e["fallback"] = True
-                    continue
-                if not opt.remote:
-                    if not isinstance(e["raw"], str):
-                        e["raw"] = copy.deepcopy(query)
-                    if id(query) not in translated:
-                        translate_calls(idx, query.calls)
-                        translated.add(id(query))
-                if call.name == "Count":
-                    if len(call.children) != 1:
-                        raise ExecError(
-                            "Count() takes exactly one row query")
-                    tree, kind = call.children[0], "count"
-                else:
-                    tree, kind = call, "plane"
-                self.validate_bitmap_call(idx, tree)
-                if kind == "plane":
-                    self._bump_fallback_heat(idx, call)
-                wctx = workload_mod.begin_query(idx.name, query)
-                e["wctx"] = wctx
-                wl_before = self._stacked.counters()
-                gathered = self._stacked.gather_for_batch(
-                    idx, tree, shard_list)
-                if gathered is None:
-                    # not stack-coverable: the per-query fallback opens
-                    # (and records) its own context
-                    workload_mod.abort_query(wctx)
-                    e["wctx"] = None
-                    e["fallback"] = True
-                    continue
-                wl_after = self._stacked.counters()
-                # gather-side deltas now, one dispatch at resolve: the
-                # fused launch serves the whole batch, so a per-member
-                # counter diff spanning it would bleed batchmates' work.
-                # dispatches stays 0 here — resolve_batch charges each
-                # fused dispatch to exactly ONE of the members that rode
-                # it, so per-shape dispatch counts don't inflate N× on
-                # the very path that exists to reduce them
-                e["deltas"] = {
-                    "dispatches": 0,
-                    "cache_hits": wl_after[1] - wl_before[1],
-                    "cache_misses": wl_after[2] - wl_before[2],
-                    "bytes_materialized":
-                        (wl_after[3] - wl_before[3]) * WORDS_PER_ROW * 4,
-                }
-                e["call"] = call
-                e["kind"] = kind
-                sig, stacks = gathered
-                e["item"] = len(items)
-                items.append((kind, sig, stacks))
-            except Exception as exc:  # noqa: BLE001 — per-query isolation
-                if e["wctx"] is not None:
-                    workload_mod.abort_query(e["wctx"])
-                    e["wctx"] = None
-                e["error"] = exc
-        handle = self._stacked.launch_query_batch(items) if items else []
-        return handle, (idx, opt, shards, shard_list, entries)
-
-    def resolve_batch(self, handle, state):
-        """Phase 2: ONE transfer resolves every fused dispatch, then the
-        per-member demux — counts to exact ints, plane stacks to Row
-        segments — and fallback members run the ordinary per-query path.
-        Returns a list of (results, error, batch_size, fingerprint)
-        tuples in submission order: error is the member's exception
-        (None on success), batch_size is the fused-dispatch occupancy
-        the member rode (0 = per-query path). If the fused dispatch
-        itself failed, batched members re-run individually on the legacy
-        path so an infrastructure fault degrades to per-query serving
-        instead of a batch-wide error."""
-        import time as _time
-
-        from ..utils import workload as workload_mod
-        from .translate import translate_results
-
-        idx, opt, shards, shard_list, entries = state
-        try:
-            resolved = self._stacked.resolve_query_batch(handle) \
-                if handle else {}
-        except Exception:  # noqa: BLE001 — degrade to per-query serving
-            resolved = None
-        out = []
-        charged = set()  # fused dispatches already attributed to a member
-        for e in entries:
-            query = e["query"]
-            wctx = e["wctx"]
-            fp = wctx.fingerprint if wctx is not None else None
-            try:
-                if e["error"] is not None:
-                    raise e["error"]
-                if e["fallback"] or resolved is None:
-                    if wctx is not None:
-                        workload_mod.abort_query(wctx)
-                    # re-execute from the untranslated form: e["query"]
-                    # may already be key-translated (see launch_batch),
-                    # and translation is not idempotent
-                    results = self.execute(
-                        idx.name, e["raw"], shards=shards, options=opt)
-                    out.append((results, None, 0,
-                                workload_mod.last_fingerprint()))
-                    continue
-                val, bsize, dseq = resolved[e["item"]]
-                if dseq not in charged:
-                    charged.add(dseq)
-                    e["deltas"]["dispatches"] = 1
-                if e["kind"] == "count":
-                    results = [val]
-                else:
-                    row = Row()
-                    for j, shard in enumerate(shard_list):
-                        seg = val[j]
-                        if seg.any():
-                            # copy: a view would pin the whole [B, S, W]
-                            # transfer buffer for the row's lifetime
-                            row.segments[shard] = np.array(seg)
-                    if opt.exclude_columns:
-                        row.segments = {}
-                    if not opt.remote:
-                        self.attach_row_attrs(idx, e["call"], row, opt)
-                    results = [row]
-                if not opt.remote:
-                    results = translate_results(idx, query.calls, results)
-                # strategy + batch attribution on the member's own ctx
-                # (the thread-local points at the LAST member begun, so
-                # write through the entry's handle, not note_strategy)
-                wctx.strategies.append(
-                    f"{e['call'].name}=stacked-batched")
-                wctx.batch = bsize
-                workload_mod.end_query(
-                    wctx, _time.perf_counter() - e["t0"],
-                    deltas=e["deltas"])
-                out.append((results, None, bsize, fp))
-            except Exception as exc:  # noqa: BLE001 — per-query isolation
-                if wctx is not None:
-                    workload_mod.abort_query(wctx)
-                out.append((None, exc, 0, fp))
-        return out
-
-    def execute_batch(self, index_name, queries, shards=None,
-                      options=None):
-        """Batched execution, launch + resolve in one call (the explicit
-        POST /index/{i}/query-batch route). The coalescer drives the two
-        phases separately so batch N+1's launch overlaps batch N's
-        resolve (double buffering)."""
-        handle, state = self.launch_batch(
-            index_name, queries, shards=shards, options=options)
-        return self.resolve_batch(handle, state)
 
     # ------------------------------------------------------- bitmap calls
 

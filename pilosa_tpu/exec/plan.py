@@ -66,19 +66,12 @@ _local = threading.local()
 _misestimate_factor = DEFAULT_MISESTIMATE_FACTOR
 _misestimates_flagged = 0  # cumulative, for the observability roll-up
 _repeats_collapsed = 0     # re-records absorbed by fingerprint dedupe
-#: --coalesce-window (seconds); > 0 means serving folds concurrent
-#: batchable queries into fused vmapped dispatches, and plans annotate
-#: the batched strategy
-_coalesce_window = 0.0
 
 
-def configure(ring_size=None, misestimate_factor=None,
-              coalesce_window=None):
-    """Apply --plan-ring-size / --explain-misestimate-factor /
-    --coalesce-window. Resizing keeps the newest entries (ring
-    semantics). The coalesce window lets plans annotate the batched
-    dispatch strategy (EXPLAIN shows what serving would do)."""
-    global _ring_max, _misestimate_factor, _coalesce_window
+def configure(ring_size=None, misestimate_factor=None):
+    """Apply --plan-ring-size / --explain-misestimate-factor. Resizing
+    keeps the newest entries (ring semantics)."""
+    global _ring_max, _misestimate_factor
     with _lock:
         if ring_size is not None:
             _ring_max = max(1, int(ring_size))
@@ -86,16 +79,10 @@ def configure(ring_size=None, misestimate_factor=None,
                 _ring.popitem(last=False)
         if misestimate_factor is not None:
             _misestimate_factor = float(misestimate_factor)
-        if coalesce_window is not None:
-            _coalesce_window = float(coalesce_window)
 
 
 def misestimate_factor():
     return _misestimate_factor
-
-
-def coalesce_window():
-    return _coalesce_window
 
 
 def record(plan, fingerprint=None):
@@ -420,11 +407,6 @@ class Planner:
 
         return MIN_SHARDS
 
-    def _batch_buckets(self):
-        from .stacked import BATCH_BUCKETS
-
-        return BATCH_BUCKETS
-
     def _plane_bytes(self, shard_tuple):
         return self.stacked._padded_len(shard_tuple) * WORDS_PER_ROW * 4
 
@@ -540,10 +522,6 @@ class Planner:
         node.annotations["stack_coverable"] = probe["covered"]
         if probe["covered"]:
             self._annotate_probe(node, probe)
-            if coalesce_window() > 0:
-                node.annotations["batched"] = True
-                node.annotations["batch_buckets"] = \
-                    list(self._batch_buckets())
             # residency bytes only matter if a stacked consumer builds
             # the stacks; the per-shard chain itself uploads nothing
             node.estimate.pop("bytes_materialized", None)
@@ -580,12 +558,6 @@ class Planner:
         if len(shard_list) >= self._min_shards() and probe["covered"]:
             node.strategy = "stacked"
             self._annotate_probe(node, probe)
-            if coalesce_window() > 0:
-                # serving would fold this query into a fused vmapped
-                # dispatch with concurrent same-shape arrivals
-                node.annotations["batched"] = True
-                node.annotations["batch_buckets"] = \
-                    list(self._batch_buckets())
             kernels = {"count": 1}
             node.estimate["dispatches"] = \
                 1 + self._merge_extras(kernels, probe)
@@ -1024,7 +996,7 @@ def graft_actual(node, wall_seconds, before, after, kernel_before,
         if mine:
             actual["strategy"] = mine[0]["strategy"]
             # fused-dispatch occupancy this execution rode (the count
-            # group-commit or the coalescer), so analyze distinguishes
+            # group commit), so analyze distinguishes
             # a query slowed by batching from one slowed by the kernel
             if "batch" in mine[0]:
                 actual["batch"] = mine[0]["batch"]

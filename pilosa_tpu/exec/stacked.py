@@ -239,8 +239,7 @@ class _PhaseClock:
     """Phase marks within one locked dispatch. `mark(phase)` attributes
     the time since the previous mark (or lock acquisition) to `phase`;
     _locked_dispatch folds any residual into the last mark on exit so
-    the per-phase seconds sum EXACTLY to the dispatch wall (the
-    bench_suite devhealth leg asserts the 5% version of this)."""
+    the per-phase seconds sum EXACTLY to the dispatch wall."""
 
     __slots__ = ("_t", "compiling", "phases")
 
@@ -371,14 +370,6 @@ MAX_TIME_VIEWS = 64
 
 _OPS = {"Intersect": "&", "Union": "|", "Difference": "-", "Xor": "^"}
 
-#: vmapped-batch padding buckets: a coalesced batch is padded up to the
-#: next bucket (repeating query 0) so at most len(BATCH_BUCKETS) programs
-#: compile per (kind, signature) while any concurrency level still fuses
-#: into one dispatch. 64 caps per-dispatch device time near the
-#: dispatch round trip it amortizes (same reasoning as MAX_COUNT_BATCH).
-BATCH_BUCKETS = (1, 4, 16, 64)
-
-
 def _pow2_chunks(n, cap):
     """`n` as the powers of two that add up to it, none over `cap`,
     largest first: 37 under 32 is 32 + 4 + 1."""
@@ -386,14 +377,6 @@ def _pow2_chunks(n, cap):
         size = min(cap, 1 << (n.bit_length() - 1))
         yield size
         n -= size
-
-
-def batch_bucket(n):
-    """Smallest padding bucket holding `n` queries."""
-    for b in BATCH_BUCKETS:
-        if n <= b:
-            return b
-    return BATCH_BUCKETS[-1]
 
 
 #: thread-local batch attribution: the batch paths stamp how many
@@ -622,10 +605,6 @@ class StackedEvaluator:
         # tests assert these, not wall time (which is noisy on CPU).
         self.pairwise_dispatches = 0
         self.pairwise_syncs = 0
-        # Batched-pipeline observability (GET /debug/batching): fused
-        # launch_query_batch dispatches vs the queries that rode them.
-        self.batch_dispatches = 0
-        self.batched_queries = 0
         # Whole-plan fusion observability (GET /debug/fusion): queries
         # whose every top-level Count rode ONE fused device program.
         self.fused_dispatches = 0
@@ -1386,9 +1365,9 @@ class StackedEvaluator:
         its own deadline before it queued, and the leader's must not
         fail its followers).
 
-        Always on (cheap — a few dict/deque ops vs ms-scale kernels;
-        the flightrec + devhealth bench legs hold the total under 2% of
-        kernel wall): per-kernel wall/bytes attribution
+        Always on (a few dict/deque ops a launch; PERF.md section 5
+        has what `dispatch.account` costs on the chip): per-kernel
+        wall/bytes attribution
         (`kernel_seconds{kernel}` histograms, /debug/kernels), dispatch
         start/end flight-recorder events, and a watchdog op covering the
         lock hold — a dispatch that never returns trips the stall dump
@@ -1590,9 +1569,8 @@ class StackedEvaluator:
     def _count_batch_fn(self, sig, csig, batch):
         """`batch` independent count trees of one signature fused into ONE
         program: args are batch*flat_arity container components, outputs
-        are [batch] (hi, lo) vectors. This is bench.py's batched-serving
-        trick productionized (VERDICT r3 item 5): one dispatch + one fetch
-        amortize the per-query round trip across every concurrent query.
+        are [batch] (hi, lo) vectors: one dispatch + one fetch amortize
+        the per-query round trip across every concurrent query.
         Returns the jitted program itself; what serves is its compilation
         for one group's shapes (_compile_count_bucket)."""
         import jax.numpy as jnp
@@ -1816,176 +1794,6 @@ class StackedEvaluator:
                     sig, csig, flat, self._tree_eval))
 
         return self._get_fn(("plane", sig, csig), build)
-
-    # -- vmapped batch kernels (query coalescer) -----------------------------
-    #
-    # The coalescer's serving programs: `bucket` independent queries of
-    # one tree signature evaluated with a leading query axis. Args are
-    # bucket*arity separate [S, W] leaf stacks (query-major, exactly the
-    # device arrays the stack cache already holds — no host restacking);
-    # the program stacks each leaf slot to [B, S, W] and vmaps the tree
-    # combine over axis 0, so XLA fuses the whole batch into ONE launch
-    # and the dispatch round trip is paid once per batch.
-
-    def _vmap_count_fn(self, sig, csig, bucket):
-        """`bucket` count trees -> (hi [B], lo [B]) popcount totals.
-        Queries in one vmapped bucket share a container signature AND
-        exact component shapes (launch_query_batch groups on gsig), so
-        each flat component slot stacks to a leading batch axis and the
-        per-query compressed count program vmaps over it."""
-        import jax
-        import jax.numpy as jnp
-
-        csig = _containers.norm_csig(csig)
-        af = _containers.flat_arity(csig)
-
-        def build():
-            vprog = jax.vmap(lambda *flat: _containers.count_program(
-                sig, csig, flat, self._tree_eval))
-
-            def fn(*flat):
-                # flat is query-major: flat[q*af + j] = query q's j-th
-                # component, so flat[j::af] gathers slot j across the
-                # batch
-                slots = [jnp.stack(flat[j::af]) for j in range(af)]
-                return vprog(*slots)
-
-            return _named_jit("count_vmap", fn)
-
-        return self._get_fn(("countV", sig, csig, bucket), build)
-
-    def _vmap_plane_fn(self, sig, csig, bucket):
-        """`bucket` bitmap trees -> combined [B, S, W] plane stacks."""
-        import jax
-        import jax.numpy as jnp
-
-        csig = _containers.norm_csig(csig)
-        af = _containers.flat_arity(csig)
-
-        def build():
-            vprog = jax.vmap(lambda *flat: _containers.plane_program(
-                sig, csig, flat, self._tree_eval))
-
-            def fn(*flat):
-                slots = [jnp.stack(flat[j::af]) for j in range(af)]
-                return vprog(*slots)
-
-            return _named_jit("plane_vmap", fn)
-
-        return self._get_fn(("planeV", sig, csig, bucket), build)
-
-    def gather_for_batch(self, idx, call, shards):
-        """Batch-member coverage + leaf-stack gather: (sig, stacks) or
-        None when the tree isn't batchable on the stacked path (caller
-        falls back to the per-query path)."""
-        shards = tuple(shards)
-        if len(shards) < MIN_SHARDS:
-            return None
-        return self._gather(idx, call, shards)
-
-    def launch_query_batch(self, items):
-        """Launch every gathered query in `items` — (kind, sig, stacks)
-        triples, kind "count" or "plane" — as bucket-padded vmapped
-        programs WITHOUT fetching anything back. Returns the opaque
-        handle resolve_query_batch() turns into per-item results with
-        ONE device->host transfer.
-
-        The split is the double buffer: the coalescer thread launches
-        batch N+1 (enqueue-only on accelerator backends) before
-        resolving batch N, overlapping batch N's host sync with batch
-        N+1's device execution. On the CPU test backend
-        _launch_barrier() serializes execution inside the lock, so the
-        overlap degenerates to FIFO — structurally identical, just
-        without the win."""
-        groups = {}
-        for pos, (kind, sig, stacks) in enumerate(items):
-            # group on gsig (repr kinds + exact component shapes):
-            # same-representation fragments keep fusing into one vmapped
-            # bucket exactly as before, while a mixed-repr batch SPLITS
-            # into per-representation groups — each degrades to its own
-            # (possibly solo) dispatch on the legacy program shape
-            # instead of failing the batch
-            gsig = tuple(c.gsig for c in stacks)
-            groups.setdefault((kind, sig, gsig), []).append(pos)
-        launched = []
-        for (kind, sig, _gsig), positions in groups.items():
-            csig = tuple(c.csig for c in items[positions[0]][2])
-            for i in range(0, len(positions), BATCH_BUCKETS[-1]):
-                chunk = positions[i:i + BATCH_BUCKETS[-1]]
-                bucket = batch_bucket(len(chunk))
-                args = []
-                nbytes_in = 0
-                for pos in chunk:
-                    args.extend(_containers.flatten(items[pos][2]))
-                    nbytes_in += sum(c.nbytes for c in items[pos][2])
-                for _ in range(bucket - len(chunk)):
-                    args.extend(  # pad: repeat q0
-                        _containers.flatten(items[chunk[0]][2]))
-                    nbytes_in += sum(
-                        c.nbytes for c in items[chunk[0]][2])
-                if kind == "count":
-                    fn = self._count_fn(sig, csig) if bucket == 1 \
-                        else self._vmap_count_fn(sig, csig, bucket)
-                    kname = "count_batched"
-                else:
-                    fn = self._plane_fn(sig, csig) if bucket == 1 \
-                        else self._vmap_plane_fn(sig, csig, bucket)
-                    kname = "plane_batched"
-                with self._lock:
-                    self.dispatches += 1
-                    self.batch_dispatches += 1
-                    self.batched_queries += len(chunk)
-                _flightrec.record("batch.dispatch", kernel=kname,
-                                  queries=len(chunk), bucket=bucket)
-                global_stats.count("batch_dispatch_total", 1, {
-                    "kernel": kname, "bucket": str(bucket)})
-                # batch-size histogram: occupancy per fused dispatch
-                global_stats.timing(
-                    "coalesce_batch_size", float(len(chunk)))
-                with self._locked_dispatch(
-                        kname, nbytes_in=nbytes_in, fn=fn) as ph:
-                    out = fn(*args)
-                    ph.mark("dispatch_ack")
-                    out = _launch_barrier(out)
-                    ph.mark("sync")
-                launched.append((kind, chunk, bucket, out))
-        return launched
-
-    def resolve_query_batch(self, launched):
-        """ONE device->host transfer for everything launch_query_batch
-        enqueued. Returns {item position: (result, fused-batch size,
-        dispatch index)}: count results are exact Python ints, plane
-        results are host [S_pad, W] uint32 arrays (row j = the j-th
-        shard the stacks were gathered over; padding rows are zero).
-        The dispatch index identifies which fused launch served the
-        item, so the caller can attribute each dispatch exactly once
-        across the members that rode it."""
-        flat = []
-        for kind, _, _, out in launched:
-            if kind == "count":
-                flat.extend(out)  # (hi, lo)
-            else:
-                flat.append(out)
-        vals = fetch(flat)
-        results = {}
-        i = 0
-        for di, (kind, chunk, bucket, _) in enumerate(launched):
-            if kind == "count":
-                # atleast_1d: the solo path returns 0-d scalars
-                his = np.atleast_1d(vals[i])
-                los = np.atleast_1d(vals[i + 1])
-                i += 2
-                for q, pos in enumerate(chunk):
-                    results[pos] = (combine_hi_lo(his[q], los[q]),
-                                    len(chunk), di)
-            else:
-                planes = vals[i]
-                i += 1
-                if bucket == 1:
-                    planes = planes[None]  # solo program: [S, W]
-                for q, pos in enumerate(chunk):
-                    results[pos] = (planes[q], len(chunk), di)
-        return results
 
     def _row_counts_fn(self, has_filt):
         """(rows [R,S,W], filt [S,W]?) -> (hi [R], lo [R]) counts of
@@ -2355,8 +2163,7 @@ class StackedEvaluator:
         pairwise_dispatches, pairwise_syncs) — the per-query delta source
         for the always-on workload table and for a query's profile. A
         bare tuple read instead of the full cache_stats() dict: this runs
-        twice per query, and the workload_overhead bench gates the sum at
-        <2% of query wall."""
+        twice per query."""
         with self._lock:
             return (self.dispatches, self.hits, self.misses,
                     self.planes_uploaded, self.pairwise_dispatches,
@@ -2392,8 +2199,6 @@ class StackedEvaluator:
                 "count_batched_queries": self._count_commit.batched,
                 "count_launches": self.count_launches,
                 "count_batch_fallbacks": self.count_batch_fallbacks,
-                "batch_dispatches": self.batch_dispatches,
-                "batched_queries": self.batched_queries,
                 "fused_dispatches": self.fused_dispatches,
                 "stack_bytes": self._stack_bytes,
                 "stack_entries": len(self._stacks),

@@ -31,7 +31,6 @@ __all__ = [
     "not_",
     "popcount",
     "popcount_rows",
-    "batch_popcount_hi_lo",
     "count_intersect",
     "union_rows",
     "any_set",
@@ -106,18 +105,6 @@ def popcount(a):
 def popcount_rows(stack):
     """Per-row popcount over a stack [R, W] -> [R] int32."""
     return jnp.sum(jax.lax.population_count(stack).astype(jnp.int32), axis=-1)
-
-
-def batch_popcount_hi_lo(stacks):
-    """Per-query popcount totals for a batched [B, S, W] plane stack ->
-    (hi [B], lo [B]). The per-(query, shard) partials fit int32 like any
-    single plane's; the cross-shard reduce routes through the hi_lo
-    overflow-splitting contract so totals stay exact past 2^31 (see
-    hi_lo). Traced inside the vmapped serving programs
-    (exec/stacked._vmap_count_fn) rather than jitted standalone."""
-    per_shard = jnp.sum(
-        jax.lax.population_count(stacks).astype(jnp.int32), axis=-1)
-    return hi_lo(per_shard, axis=-1)
 
 
 @jax.jit

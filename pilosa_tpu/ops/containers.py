@@ -309,9 +309,9 @@ class Container:
 
     @property
     def gsig(self):
-        """Vmapped-batch grouping signature: kind + exact component
-        shapes, because stacking a leaf slot across queries requires
-        identical shapes per component."""
+        """Program-cache grouping signature (exec/fusion.py): kind +
+        exact component shapes, because a compiled program takes one
+        shape per component."""
         return (self.kind, self.shape[0],
                 tuple(tuple(a.shape) for a in self.arrays))
 

@@ -73,8 +73,7 @@ class Call:
 
     def _shape_into(self, out):
         # append-based builder: shape() runs once per served query, and
-        # nested f-string joins were the single largest per-query cost
-        # in the workload_overhead bench
+        # nested f-string joins were its single largest per-query cost
         out.append(self.name)
         out.append("(")
         sep = ""

@@ -51,7 +51,7 @@ Three mechanisms, one controller:
 Default OFF: `--admission off` never constructs a controller, the
 query path's only residue is one `is None` check, and the legacy
 path stays byte-identical (the repo's escape-hatch convention, like
-coalesce-window=0 and ingest-merge-interval=0).
+ingest-merge-interval=0).
 """
 
 import threading
@@ -192,19 +192,6 @@ class TokenBucket:
         return max(0.0, (cost_ms - self.tokens) / self.rate)
 
 
-# live controllers (normally one per process) — bench attempt tagging
-_REGISTRY = []
-
-
-def mode():
-    """'off' or 'on state=<ladder rung>' — bench attempt tagging:
-    serving numbers are only comparable across runs measured under the
-    same admission policy and degradation rung."""
-    if not _REGISTRY:
-        return "off"
-    return f"on state={_REGISTRY[0].state}"
-
-
 class AdmissionController:
     """The QoS gate in front of the executor. One per API; every
     method is thread-safe. See the module docstring for the model."""
@@ -250,7 +237,6 @@ class AdmissionController:
         global_stats.gauge_fn(
             "admission_state",
             lambda: STATE_RANK.get(self.state, 0))
-        _REGISTRY.append(self)
 
     # -- pricing -----------------------------------------------------------
 
@@ -492,10 +478,6 @@ class AdmissionController:
         with self._lock:
             self._closed = True
             self._cond.notify_all()
-        try:
-            _REGISTRY.remove(self)
-        except ValueError:
-            pass
 
     def snapshot(self):
         """GET /debug/admission payload."""

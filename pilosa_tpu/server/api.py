@@ -69,8 +69,8 @@ class GatewayTimeoutError(ApiError):
 
 
 def shed_reject(site, message, retry_after, qclass=None):
-    """THE 503 rejection path for every load-shedding site — coalescer
-    overflow, ingest back-pressure, resize-queue overflow, admission.
+    """THE 503 rejection path for every load-shedding site — ingest
+    back-pressure, resize-queue overflow, admission.
     One shared `rejections_total{site,class}` counter, one jitter rule
     (x1.0-1.25, so a thundering herd of synchronized client retries
     decorrelates — the same reason server/client.py jitters its
@@ -186,251 +186,10 @@ def result_to_json(result):
     raise ApiError(f"unencodable result type {type(result)!r}")
 
 
-class QueryCoalescer:
-    """Folds concurrent batchable queries into fused vmapped dispatches
-    (exec/stacked.launch_query_batch) so the per-dispatch RTT is paid
-    once per batch instead of once per query (what that round trip
-    costs on the chip: not measured on this round's code).
-
-    Lifecycle: HTTP handler threads submit() parsed single-call queries
-    and block on a per-query event; one lazy-started daemon drain thread
-    owns the pipeline. On an idle→busy transition it holds the batch
-    open for `window` seconds so batchmates arriving within the window
-    fuse; while the pipeline is busy the launch+resolve of the previous
-    batch IS the accumulation window (no extra sleep). The loop is
-    double-buffered: batch N+1 is launched (device enqueue via
-    Executor.launch_batch) BEFORE batch N's results are transferred
-    back (resolve_batch), so host sync of batch N overlaps device
-    execution of batch N+1.
-
-    Overload: a queue past `max_queue` rejects with 503 + Retry-After
-    (ServiceUnavailableError headers path) and counts
-    batch_rejected_total — never an unbounded wait."""
-
-    def __init__(self, api, window, max_queue=256):
-        self.api = api
-        self.window = float(window)
-        self.max_queue = int(max_queue)
-        self._cond = threading.Condition()
-        self._queue = []  # member dicts, FIFO
-        self._thread = None
-        self._closed = False
-        # observability (GET /debug/batching)
-        self.batches = 0            # fused launches issued
-        self.coalesced = 0          # queries that rode a fused launch
-        self.rejected = 0           # overload 503s
-        self.max_occupancy = 0      # largest single batch seen
-        self.batch_hist = {}        # occupancy -> count
-
-    def submit(self, index_name, query, pql):
-        """Enqueue one parsed batchable query and wait for its slot of
-        the fused result. Returns (results, batch_size, fingerprint);
-        re-raises the member's own error (per-query isolation — a
-        batchmate's failure is not ours)."""
-        from ..utils.stats import global_stats
-
-        m = {"index": index_name, "query": query, "pql": pql,
-             "event": threading.Event(), "t0": time.monotonic(),
-             "results": None, "error": None, "batch": 0, "fp": None}
-        with self._cond:
-            if self._closed:
-                raise ServiceUnavailableError(
-                    "query coalescer shut down", retry_after=1)
-            if len(self._queue) >= self.max_queue:
-                self.rejected += 1
-                global_stats.count("batch_rejected_total", 1)
-                shed_reject(
-                    "coalesce",
-                    f"coalesce queue full ({self.max_queue}); shed load "
-                    "or raise --coalesce-max-queue", 1,
-                    qclass="interactive")
-            self._queue.append(m)
-            if self._thread is None:
-                self._start_thread_locked()
-            self._cond.notify()
-        # Bounded waits + a liveness check: the drain loop delivers
-        # every member's event even on internal errors (its whole body
-        # is exception-guarded), but if the thread is ever lost anyway,
-        # fail this handler fast instead of blocking it forever, and
-        # leave the coalescer usable for the next submit.
-        while not m["event"].wait(0.5):
-            with self._cond:
-                t = self._thread
-                if t is not None and t.is_alive():
-                    continue
-                if m in self._queue:
-                    self._queue.remove(m)
-                self._thread = None
-                if self._queue and not self._closed:
-                    self._start_thread_locked()
-            if not m["event"].is_set():
-                m["error"] = ServiceUnavailableError(
-                    "coalescer drain thread died; retry", retry_after=1)
-            break
-        if m["error"] is not None:
-            raise m["error"]
-        return m["results"], m["batch"], m["fp"]
-
-    def _start_thread_locked(self):
-        self._thread = threading.Thread(
-            target=self._drain_loop, daemon=True, name="query-coalescer")
-        self._thread.start()
-
-    def close(self):
-        """Shut down the pipeline: wake the drain thread, deliver
-        in-flight batches, fail queued members with 503 so blocked
-        handler threads return instead of hanging past server shutdown,
-        and refuse new submits. Idempotent."""
-        with self._cond:
-            if self._closed:
-                return
-            self._closed = True
-            t = self._thread
-            self._cond.notify_all()
-        if t is not None and t.is_alive():
-            t.join(timeout=5)
-        # no drain thread (never started, or already dead): fail the
-        # leftovers here; otherwise the loop's shutdown path did it
-        self._fail(self._pop_members(), ServiceUnavailableError(
-            "query coalescer shut down", retry_after=1))
-
-    @staticmethod
-    def _fail(members, exc):
-        for m in members:
-            if not m["event"].is_set():
-                m["error"] = exc
-                m["event"].set()
-
-    def stats(self):
-        with self._cond:
-            return {
-                "enabled": True,
-                "window_seconds": self.window,
-                "max_queue": self.max_queue,
-                "queue_depth": len(self._queue),
-                "batches": self.batches,
-                "coalesced_queries": self.coalesced,
-                "rejected": self.rejected,
-                "max_occupancy": self.max_occupancy,
-                "occupancy_hist": dict(sorted(self.batch_hist.items())),
-            }
-
-    def _pop_members(self):
-        """Drain everything queued right now (caller holds no lock)."""
-        with self._cond:
-            members, self._queue = self._queue, []
-            return members
-
-    def _drain_loop(self):
-        from ..utils import flightrec
-        from ..utils.stats import global_stats
-
-        ex = self.api.batch_executor()
-        pending = []  # [(handle, state, members)] launched, unresolved
-        while True:
-            idle = False
-            with self._cond:
-                idle = not self._queue and not pending and not self._closed
-            if idle:
-                # idle dispatch-lock window: bounded proactive admission
-                # of hot_but_not_resident fragments (exec/adaptive) —
-                # exception-guarded and a no-op with the engine off, so
-                # serving can never wedge on an admission failure
-                try:
-                    admit = getattr(ex, "maybe_proactive_admit", None)
-                    if admit is not None:
-                        admit()
-                except Exception:  # noqa: BLE001 — observability only
-                    pass
-            with self._cond:
-                while not self._queue and not pending \
-                        and not self._closed:
-                    self._cond.wait()
-                if self._closed:
-                    break
-            # Everything below is exception-guarded: an error ANYWHERE
-            # in the iteration (stats, flightrec, grouping — not just
-            # the launch/resolve calls, which guard themselves) is
-            # delivered to every affected member and the loop keeps
-            # serving. An unguarded escape here used to kill the
-            # singleton thread and wedge all future submits forever.
-            members = []
-            launched = []
-            try:
-                was_idle = not pending
-                members = self._pop_members()
-                if members and was_idle and self.window > 0:
-                    # idle→busy: hold the window open so concurrent
-                    # arrivals fuse into this batch (busy pipelines get
-                    # their window for free from the previous resolve);
-                    # close() cuts the wait short
-                    with self._cond:
-                        self._cond.wait_for(lambda: self._closed,
-                                            timeout=self.window)
-                    members += self._pop_members()
-                for index_name, group in self._group(members).items():
-                    now = time.monotonic()
-                    for m in group:
-                        global_stats.timing(
-                            "coalesce_wait_seconds", now - m["t0"])
-                    try:
-                        handle, state = ex.launch_batch(
-                            index_name, [m["query"] for m in group])
-                    except Exception as exc:  # noqa: BLE001 — deliver
-                        self._fail(group, exc)
-                        continue
-                    with self._cond:
-                        self.batches += 1
-                        self.coalesced += len(group)
-                        n = len(group)
-                        self.max_occupancy = max(self.max_occupancy, n)
-                        self.batch_hist[n] = self.batch_hist.get(n, 0) + 1
-                    flightrec.record("batch.coalesce", index=index_name,
-                                     queries=len(group))
-                    launched.append((handle, state, group))
-                # double buffer: batch N+1 is in flight; NOW sync batch N
-                for handle, state, group in pending:
-                    self._resolve(ex, handle, state, group)
-                pending = launched
-            except Exception as exc:  # noqa: BLE001 — deliver, don't die
-                self._fail(members, exc)
-                for _, _, group in pending + launched:
-                    self._fail(group, exc)
-                pending = []
-        # closed: deliver in-flight batches (already launched — the
-        # results are real), then fail whatever is still queued
-        for handle, state, group in pending:
-            self._resolve(ex, handle, state, group)
-        self._fail(self._pop_members(), ServiceUnavailableError(
-            "query coalescer shut down", retry_after=1))
-
-    def _group(self, members):
-        by_index = {}
-        for m in members:
-            by_index.setdefault(m["index"], []).append(m)
-        return by_index
-
-    def _resolve(self, ex, handle, state, group):
-        try:
-            outs = ex.resolve_batch(handle, state)
-        except Exception as exc:  # noqa: BLE001 — deliver, don't die
-            for m in group:
-                m["error"] = exc
-                m["event"].set()
-            return
-        for m, (results, error, bsize, fp) in zip(group, outs):
-            m["results"] = results
-            m["error"] = error
-            m["batch"] = bsize
-            m["fp"] = fp
-            m["event"].set()
-
-
 class API:
     def __init__(self, holder, cluster=None, client_factory=None,
                  long_query_time=None, logger=None, spmd=None,
                  max_writes_per_request=0, oplog=None,
-                 coalesce_window=0.0, coalesce_max_queue=256,
                  ingest_interval=0.0, ingest_max_rows=None,
                  ingest_max_bytes=None, admission="off",
                  admission_capacity=None, admission_queue_depth=None,
@@ -486,23 +245,6 @@ class API:
             self.executor = Executor(
                 holder, max_writes_per_request=max_writes_per_request)
             self.resize = None
-        # Query coalescer (batched dispatch pipeline): window 0 — the
-        # default — disables it entirely and keeps the legacy per-query
-        # path bit-identical. Cluster coordinators coalesce only when
-        # the SPMD mesh serves (serve-mode != off): eligible batches
-        # then execute as ONE collective step (SpmdBatchRunner); on the
-        # legacy HTTP fan-out path the legs are where dispatches happen,
-        # so coordinator coalescing would only add latency.
-        self.coalesce_window = float(coalesce_window or 0.0)
-        self.coalesce_max_queue = int(coalesce_max_queue)
-        if self.coalesce_window > 0 and (
-                cluster is None
-                or (spmd is not None
-                    and getattr(spmd, "serve_mode", "off") != "off")):
-            self._coalescer = QueryCoalescer(
-                self, self.coalesce_window, self.coalesce_max_queue)
-        else:
-            self._coalescer = None
         # Streaming ingest engine (exec/ingest.py): interval 0 — the
         # default — never constructs one, so the import path is a single
         # `is None` check and stays byte-identical to the legacy
@@ -558,16 +300,6 @@ class API:
         if self.spmd is None:
             raise ApiError("spmd mode not enabled on this node")
         return self.spmd.run_stream(step)
-
-    def batch_executor(self):
-        """The executor the coalescer drains into: the local vmapped
-        batch pipeline on a single node, the SPMD collective batch
-        adapter on a mesh-serving cluster coordinator."""
-        if self.cluster is not None and self.spmd is not None:
-            from ..cluster.spmd import SpmdBatchRunner
-
-            return SpmdBatchRunner(self)
-        return getattr(self.executor, "local", self.executor)
 
     def spmd_debug(self):
         """GET /debug/spmd payload."""
@@ -1055,14 +787,6 @@ class API:
         from ..utils import flightrec
         from ..utils import profile as profile_mod
         from ..utils import tracing
-        # Coalescer routing: batchable single-call reads with default
-        # options fuse with concurrent arrivals into one vmapped
-        # dispatch. Ineligible queries (and window=0 deployments, where
-        # _coalescer is None) continue on the bit-identical legacy path.
-        if self._coalescer is not None:
-            routed = self._try_coalesce(index_name, pql, shards, options)
-            if routed is not None:
-                return routed[0]
         # Profile when the request asked (?profile=true) or a slow-query
         # threshold is configured (so a slow query's log line carries the
         # full span tree, not just its total). Remote fan-out legs never
@@ -1120,60 +844,14 @@ class API:
             self._broadcast_shards_if_changed(index_name)
         return results
 
-    def _try_coalesce(self, index_name, pql, shards, options):
-        """Route one query through the coalescer when eligible. Returns
-        a 1-tuple (results,) on the coalesced path, or None to fall
-        through to the legacy per-query path (ineligible query — or a
-        parse error, which the legacy path re-raises with proper ApiError
-        wrapping)."""
-        from ..utils import flightrec
-        from ..utils import tracing
-        from ..utils import workload as workload_mod
-
-        if shards is not None or not isinstance(pql, str):
-            return None
-        o = options
-        if o is not None and (o.remote or o.profile or o.explain
-                              or o.column_attrs or o.exclude_columns
-                              or o.exclude_row_attrs
-                              or o.shards is not None
-                              or getattr(o, "deadline", None) is not None):
-            return None
-        try:
-            query = parse(pql)
-        except Exception:
-            return None
-        call = query.calls[0] if len(query.calls) == 1 else None
-        if call is None or call.writes() \
-                or call.name not in self.executor.BATCHABLE_CALLS:
-            return None
-        t0 = time.monotonic()
-        wtoken = flightrec.watch_begin("query", index=index_name)
-        try:
-            # the span is the HTTP handler's whole wait: queue time +
-            # fused execution + demux (coalesce-wait observability)
-            with tracing.start_span("coalesce.wait", index=index_name):
-                results, bsize, fp = self._coalescer.submit(
-                    index_name, query, pql)
-        except (ApiError,):
-            raise
-        except Exception as e:
-            raise ApiError(str(e)) from e
-        finally:
-            flightrec.watch_end(wtoken)
-        # end_query ran on the coalescer thread, so THIS thread's
-        # last_fingerprint() is stale — pass the member's own through
-        self._log_slow_query(index_name, pql, time.monotonic() - t0,
-                             batch=bsize, fp=fp)
-        workload_mod.maybe_sample_slo()
-        return (results,)
-
     def query_batch(self, index_name, pqls, shards=None):
-        """Execute a list of PQL queries as one batched dispatch (the
-        explicit POST /index/{i}/query-batch route, sharing the vmapped
-        executor path with the coalescer). Returns a list of
-        (results, error, batch_size, fingerprint) tuples in request
-        order — per-query error isolation, like the coalescer's."""
+        """POST /index/{i}/query-batch: each PQL string through query(),
+        its own exception caught into its own slot. Returns a list of
+        (results, error, batch_size) tuples in request order; batch_size
+        is how many concurrent queries shared the slot's count launch at
+        GroupCommit (0 where the query made none)."""
+        from ..exec.stacked import last_batch_size
+
         self._validate_state()
         if self.holder.index(index_name) is None:
             raise NotFoundError(f"index not found: {index_name}")
@@ -1183,36 +861,14 @@ class API:
             raise ServiceUnavailableError(
                 "device link DOWN (canary probes failing); "
                 f"retry in {retry:.0f}s", retry_after=retry)
-        if self.cluster is not None:
-            # cluster coordinators fan out per query; batching happens
-            # on the legs' own dispatch paths
-            out = []
-            for pql in pqls:
-                try:
-                    out.append((self.query(index_name, pql,
-                                           shards=shards), None, 0, None))
-                except Exception as exc:  # noqa: BLE001 — per-query
-                    out.append((None, exc, 0, None))
-            return out
-        return self.executor.execute_batch(
-            index_name, list(pqls), shards=shards)
-
-    def batching_stats(self):
-        """GET /debug/batching: coalescer occupancy/queue stats plus the
-        fused-dispatch counters from the stacked evaluator."""
-        if self._coalescer is not None:
-            co = self._coalescer.stats()
-        else:
-            co = {"enabled": False,
-                  "window_seconds": self.coalesce_window,
-                  "max_queue": self.coalesce_max_queue}
-        ex = getattr(self.executor, "local", self.executor)
-        st = ex.stacked_stats() if hasattr(ex, "stacked_stats") else {}
-        return {
-            "coalescer": co,
-            "batch_dispatches": st.get("batch_dispatches", 0),
-            "batched_queries": st.get("batched_queries", 0),
-        }
+        out = []
+        for pql in pqls:
+            try:
+                results = self.query(index_name, pql, shards=shards)
+                out.append((results, None, last_batch_size()))
+            except Exception as exc:  # noqa: BLE001 — per-query isolation
+                out.append((None, exc, 0))
+        return out
 
     def admission_stats(self):
         """GET /debug/admission: the controller's full snapshot —
@@ -1273,18 +929,15 @@ class API:
                 "tree": tracing.assemble_tree(merged)}
 
     def close(self):
-        """Release serving-side background state — the ingest merge
-        engine (final flush drains buffered deltas and releases any
-        group-committed oplog watermarks) and the query coalescer,
-        whose blocked waiters get a 503 instead of hanging on a daemon
-        thread that dies with the process. Idempotent; default
-        deployments (no engine, no coalescer) no-op."""
+        """Release serving-side background state — the admission
+        controller, the ingest merge engine (final flush drains buffered
+        deltas and releases any group-committed oplog watermarks) and
+        the SPMD plane. Idempotent; a default deployment has none of
+        them."""
         if self._admission is not None:
             self._admission.close()
         if self.ingest is not None:
             self.ingest.close()
-        if self._coalescer is not None:
-            self._coalescer.close()
         if self.spmd is not None:
             self.spmd.close()
 
@@ -1332,45 +985,32 @@ class API:
                 out.append({"id": c, "attrs": attrs})
         return out
 
-    def _log_slow_query(self, index_name, pql, elapsed, prof=None,
-                        batch=None, fp=None):
+    def _log_slow_query(self, index_name, pql, elapsed, prof=None):
         """Slow-query log (reference: LongQueryTime api.go:1157). With a
         profile in hand the line carries the full span tree + counters as
         JSON, so the log alone answers dispatch-count vs lock-wait vs
-        kernel-time vs fan-out. batch= attributes the fused-dispatch
-        occupancy the query rode (1 = solo) so a query slowed by
-        coalesce-wait is distinguishable from one slowed by the kernel;
-        the coalesced path passes batch/fp explicitly because its
-        end_query ran on the coalescer thread, not this one."""
+        kernel-time vs fan-out. batch= is how many queries shared this
+        one's count launch at GroupCommit (1 = solo), so a query slowed
+        by the wait for a batch is distinguishable from one slowed by
+        the kernel."""
         if (self.long_query_time is not None
                 and elapsed > self.long_query_time):
             import json as _json
 
+            from ..exec import fusion as fusion_mod
+            from ..exec.stacked import last_batch_size
             from ..utils import flightrec
             from ..utils import workload as workload_mod
 
             q = pql if isinstance(pql, str) else str(pql)
-            # coalesced members pass fp explicitly (executed on the
-            # coalescer thread) — this thread's fused stamp is theirs
-            # only on the direct path
-            coalesced = fp is not None
             # the executor just finished this query on THIS thread, so
-            # its fingerprint is in take-last position — slow lines for
-            # the same shape grep together across the fleet
-            if fp is None:
-                fp = workload_mod.last_fingerprint() or "-"
-            if batch is None:
-                from ..exec.stacked import last_batch_size
-                batch = last_batch_size()
-            batch = max(1, int(batch))
-            # whole-plan fusion stamp (same take-last handoff as the
-            # fingerprint): how many top-level calls rode ONE fused
-            # device program, 0 = the query ran interpreted. Coalesced
-            # members (explicit fp) executed on the coalescer thread,
-            # so THIS thread's stamp is stale — they report 0 (the
-            # coalescer path never fuses whole plans).
-            from ..exec import fusion as fusion_mod
-            fused = 0 if coalesced else fusion_mod.last_fused()
+            # its fingerprint, batch size and whole-plan fusion stamp
+            # (how many top-level calls rode ONE fused device program,
+            # 0 = interpreted) are in take-last position — slow lines
+            # for the same shape grep together across the fleet
+            fp = workload_mod.last_fingerprint() or "-"
+            batch = max(1, int(last_batch_size()))
+            fused = fusion_mod.last_fused()
             flightrec.record("query.slow", index=index_name,
                              seconds=round(elapsed, 3), pql=q[:200],
                              fingerprint=fp, batch=batch, fused=fused)

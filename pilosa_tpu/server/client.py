@@ -21,7 +21,7 @@ import urllib.request
 # -- HTTP data-plane byte accounting ------------------------------------
 # Response bytes of node-to-node REMOTE query fan-out — the cluster's
 # HTTP DATA plane (result payloads), as opposed to control traffic
-# (step announcements, validation, health). The SPMD serving bench
+# (step announcements, validation, health). tests/test_spmd_mesh.py
 # asserts this stays flat while collectives serve: result bytes ride
 # the fabric, not HTTP. Process-wide (every Client instance counts).
 _data_plane_lock = threading.Lock()
@@ -37,13 +37,6 @@ def _note_data_plane(n):
 def data_plane_bytes():
     with _data_plane_lock:
         return _data_plane_bytes
-
-
-def reset_data_plane_bytes():
-    """Bench/test isolation."""
-    global _data_plane_bytes
-    with _data_plane_lock:
-        _data_plane_bytes = 0
 
 
 class ClientError(Exception):
@@ -173,8 +166,8 @@ class Client:
                     err.retry_after = float(ra)
                 except ValueError:
                     pass
-            # which shedding site rejected us (admission, coalesce,
-            # ingest, resize_queue) — lets the cluster layer tell an
+            # which shedding site rejected us (admission, ingest,
+            # resize_queue) — lets the cluster layer tell an
             # OVERLOADED peer from an unready/dead one
             shed = e.headers.get("X-Pilosa-Shed") if e.headers else None
             if shed is not None:

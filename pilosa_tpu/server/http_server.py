@@ -168,7 +168,6 @@ class PilosaHTTPServer:
             Route("GET", r"/debug/device", self._get_debug_device,
                   args=("limit",)),
             Route("GET", r"/debug/dispatch", self._get_debug_dispatch),
-            Route("GET", r"/debug/batching", self._get_debug_batching),
             Route("GET", r"/debug/workload", self._get_debug_workload,
                   args=("top",)),
             Route("GET", r"/debug/heat", self._get_debug_heat,
@@ -357,11 +356,11 @@ class PilosaHTTPServer:
         return out
 
     def _post_query_batch(self, req):
-        """Batched query endpoint: a JSON list of PQL strings executed as
-        one fused dispatch (same vmapped executor path as the coalescer).
-        Body: {"queries": ["Count(Row(f=1))", ...]} — or a bare JSON
-        list. Per-query error isolation: each slot of "results" is
-        either {"results": [...], "batch": n} or {"error": "..."}."""
+        """Several queries in one request, each executed as
+        POST /index/{i}/query would execute it. Body:
+        {"queries": ["Count(Row(f=1))", ...]} — or a bare JSON list.
+        Per-query error isolation: each slot of "results" is either
+        {"results": [...], "batch": n} or {"error": "..."}."""
         import json
 
         try:
@@ -381,7 +380,7 @@ class PilosaHTTPServer:
         if "shards" in req.query:
             shards = [int(s) for s in req.query["shards"][0].split(",") if s]
         out = []
-        for results, error, bsize, _fp in self.api.query_batch(
+        for results, error, bsize in self.api.query_batch(
                 req.params["index"], queries, shards=shards):
             if error is not None:
                 out.append({"error": str(error)})
@@ -834,14 +833,6 @@ class PilosaHTTPServer:
             raise NotFoundError("no stacked evaluator on this node")
         return local.dispatch_phase_stats()
 
-    def _get_debug_batching(self, req):
-        """Batched-dispatch pipeline stats: coalescer queue depth /
-        occupancy histogram / rejects plus fused-dispatch counters."""
-        stats = getattr(self.api, "batching_stats", None)
-        if stats is None:
-            raise NotFoundError("no batching stats on this node")
-        return stats()
-
     #: every debug endpoint with a one-line description — served at
     #: GET /debug so discoverability doesn't depend on the README
     DEBUG_ENDPOINTS = {
@@ -865,9 +856,6 @@ class PilosaHTTPServer:
                          "machine, RTT samples, transitions",
         "/debug/dispatch": "dispatch-phase RTT decomposition (lock_wait "
                            "/ transfer_in / compile / ack / sync)",
-        "/debug/batching": "query coalescer: queue depth, batch "
-                           "occupancy histogram, rejects, fused-dispatch "
-                           "counters",
         "/debug/workload": "query fingerprint table: per-shape counts, "
                            "p50/p99, strategies, misestimates",
         "/debug/heat": "fragment heat vs HBM residency: admission and "

@@ -1,14 +1,14 @@
 """Device boot: the one place that decides what this process runs on.
 
-Every entry point that reaches a JAX backend — `cli server`, `bench.py`,
-`bench_suite.py`, `bench_kernels.py`, `__graft_entry__.py`, the children
-of `chip_smoke.py` — calls `boot()` before its first backend use. The
+Every entry point that reaches a JAX backend — `cli server` (which
+`benchmark/run.py` starts), `__graft_entry__.py`, the children of
+`chip_smoke.py` — calls `boot()` before its first backend use. The
 package is written for a TPU, so a TPU is what `boot()` demands: the host
 CPU is used only when the operator asked for it with `JAX_PLATFORMS=cpu`
-(the test suite, `make bench-cpu`), never because detection found nothing
-better. Installed JAX answers a missing chip by printing a libtpu error
-and handing back `[CpuDevice(id=0)]`; without this check a server would
-serve from the host and a benchmark would time it.
+(the test suite), never because detection found nothing better. Installed
+JAX answers a missing chip by printing a libtpu error and handing back
+`[CpuDevice(id=0)]`; without this check a server would serve from the host
+and a benchmark would time it.
 
 `boot()` also places the persistent compile cache. Where
 `JAX_COMPILATION_CACHE_DIR` is set JAX already uses that directory and

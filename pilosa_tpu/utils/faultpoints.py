@@ -5,7 +5,7 @@ Production code marks the instants a crash test wants to hit —
 raise, delay, or kill the process there. The discipline is the same as
 the nop tracer and the disabled device-link prober: when nothing is
 armed, the producer hook is ONE module-global check and returns, so the
-hot write path pays nothing (verified by a bench_suite gate).
+hot write path pays nothing.
 
 Arming:
   - env: ``PILOSA_TPU_FAULTPOINTS="import.post-append=exit@3;oplog.fsync=delay:0.2"``

@@ -47,9 +47,9 @@ Event taxonomy (kind prefixes; see docs/architecture.md):
                straggler (edge-triggered, coordinator-side) when one
                node's per-phase step wall exceeds the peer median by the
                configured factor in the merged /debug/spmd/steps
-               timeline. The enter/exit pairing is what lets bench.py
-               distinguish "peer never entered the collective" from
-               "collective hung".
+               timeline. The enter/exit pairing is what distinguishes
+               "peer never entered the collective" from "collective
+               hung".
   fusion.compile  whole-plan (and mesh collective) program compiles with
                   wall time; mesh programs carry a `mesh` tag
 """
@@ -284,9 +284,9 @@ class Watchdog:
     # -- detection -----------------------------------------------------------
 
     def check(self, now=None):
-        """One poll pass; factored out of the loop so tests (and the
-        bench stall leg) can force a check without waiting for the
-        thread. Returns the ops that tripped on THIS pass."""
+        """One poll pass; factored out of the loop so tests can force
+        a check without waiting for the thread. Returns the ops that
+        tripped on THIS pass."""
         now = time.monotonic() if now is None else now
         tripped = []
         with self._lock:

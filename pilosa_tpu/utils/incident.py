@@ -24,8 +24,7 @@ snapshots an operator would have curled (device, dispatch, workload,
 heat, slo, fusion, oplog...), recent query profiles, and the open-op
 table. Bundles are capped (--incident-max, oldest deleted), rate-limited
 per trigger kind, and written off-thread (except on the dying-process
-path). Served at GET /debug/incidents; bench.py attaches the newest
-bundle path to failed-attempt records.
+path). Served at GET /debug/incidents.
 
 Default path cost: with no manager configured every hook is one module
 global check (`maybe_trigger` / `note_deadline_expiry` return

@@ -119,7 +119,7 @@ class WorkloadTable:
         `deltas` carries the per-query stacked-counter diffs
         (dispatches, cache_hits, cache_misses, bytes_materialized);
         `batch` is the fused-batch size the query rode (0 or 1 = solo),
-        so the table answers which shapes actually coalesce."""
+        so the table answers which shapes actually share launches."""
         deltas = deltas or {}
         with self._lock:
             self.total_queries += 1

@@ -136,11 +136,10 @@ class SpmdMeshCluster:
     even on single-chip CI hosts: 2 virtual devices per process -> a
     4-device mesh whose psum actually crosses the process boundary.
 
-    Used by tests/test_spmd_mesh.py and the bench_suite spmd_serving
-    leg (same-cluster A/B via the runtime POST /debug/spmd switch)."""
+    Used by tests/test_spmd_mesh.py (same-cluster A/B via the runtime
+    POST /debug/spmd switch)."""
 
-    def __init__(self, n=2, serve_mode="on", coalesce_window="40ms",
-                 extra_flags=()):
+    def __init__(self, n=2, serve_mode="on", extra_flags=()):
         ports = _free_ports(n + 1)
         self.ports, spmd_port = ports[:n], ports[n]
         hosts = ",".join(f"127.0.0.1:{p}" for p in self.ports)
@@ -154,7 +153,6 @@ class SpmdMeshCluster:
                  "--spmd-serve", serve_mode,
                  "--spmd-cpu-collectives", "gloo",
                  "--fusion", "on",
-                 "--coalesce-window", coalesce_window,
                  *extra_flags]
         for i, port in enumerate(self.ports):
             log = open(os.path.join(self.dirs[i], "server.log"), "w")

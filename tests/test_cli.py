@@ -8,6 +8,7 @@ the introspection commands that only print.
 import io
 from contextlib import redirect_stdout
 
+import pytest
 import tomllib
 
 from pilosa_tpu.cli import main
@@ -79,3 +80,20 @@ def test_holder_command(tmp_path, monkeypatch):
     # a mistyped path must error, not be silently created and blessed
     rc, _out = _run(["holder", "--data-dir", str(tmp_path / "typo")])
     assert rc == 1
+
+
+@pytest.mark.parametrize("flag", ["--coalesce-window=2ms",
+                                  "--coalesce-max-queue=8"])
+@pytest.mark.parametrize("command", ["server", "config"])
+def test_the_coalescers_flags_are_refused(command, flag, capsys):
+    """One batcher (GroupCommit, no knob): both parsers refuse the
+    deleted coalescer's options before anything starts, and neither
+    help text lists them."""
+    with pytest.raises(SystemExit) as exit_:
+        main([command, flag])
+    assert exit_.value.code == 2
+    assert "unrecognized arguments: " + flag in capsys.readouterr().err
+    with pytest.raises(SystemExit) as exit_:
+        main([command, "--help"])
+    assert exit_.value.code == 0
+    assert "coalesce" not in capsys.readouterr().out

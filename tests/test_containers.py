@@ -5,14 +5,16 @@ chooser may pick must produce exactly the results the dense planes
 produce, across the density spectrum (empty plane, single bit, ~0.1%
 clustered, ~50% random, full, adversarial run patterns), every PQL read
 op the stacked path serves (Row/Intersect/Union/Count/TopN), and every
-PR-9 batch bucket. Dense-forced mode must BE the legacy path (same
+size of group-commit batch. Dense-forced mode must BE the legacy path (same
 program, same fn-cache keys), not merely agree with it.
 
 Alongside: chooser determinism (no repr flap on rebuild), the
 compression ledger feeding /debug/hbm and /debug/heat, EXPLAIN repr
-annotations with a dispatch-free plan path, and bench.py's wedge
-classifier (the forensics satellite rides this PR).
+annotations with a dispatch-free plan path.
 """
+
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -416,8 +418,11 @@ def test_differential_all_reprs_bit_identical(corpus):
 
 
 def test_differential_batch_buckets(corpus):
-    """Compressed containers through the PR-9 vmapped batch path: every
-    bucket size, homogeneous and mixed-repr groups, == serial dense."""
+    """Compressed containers through GroupCommit: concurrent Counts of
+    every batch size, homogeneous and mixed-repr groups in one batch,
+    == serial dense. The test holds the dispatch lock, so the first
+    caller leads alone and waits for it while every other caller queues
+    behind it: they ride ONE batch once the lock is let go."""
     holder, _api = corpus
     want_all = _dense_want(holder)
     want = {q: w for q, w in zip(QUERIES, want_all)}
@@ -426,12 +431,34 @@ def test_differential_batch_buckets(corpus):
     ex = Executor(holder)
     for q in counts:
         ex.execute("i", q)  # warm so batches group on real containers
-    for bucket in (1, 4, 16, 64):
-        batch = [counts[i % len(counts)] for i in range(bucket)]
-        outs = ex.execute_batch("i", batch)
-        for i, (res, err, _, _) in enumerate(outs):
-            assert err is None, (bucket, batch[i], err)
-            assert _normalize(res) == want[batch[i]], (bucket, batch[i])
+    ev = ex._stacked
+    for size in (1, 4, 16, 64):
+        batch = [counts[i % len(counts)] for i in range(size)]
+        got = [None] * size
+
+        def run(i):
+            got[i] = _normalize(ex.execute("i", batch[i]))
+
+        before = ev.cache_stats()
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(size)]
+        with ev._dispatch_lock:
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 60
+            while len(ev._count_commit._queue) < size - 1 \
+                    and time.monotonic() < deadline:
+                time.sleep(0.001)
+            assert len(ev._count_commit._queue) == size - 1
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+        after = ev.cache_stats()
+        assert got == [want[q] for q in batch], size
+        assert after["count_batched_queries"] \
+            - before["count_batched_queries"] == size
+        assert after["count_batches"] - before["count_batches"] \
+            == min(size, 2)
 
 
 def test_serving_reprs_and_no_flap(corpus):

@@ -135,9 +135,7 @@ def test_backends_are_initialized_never_initialises_one():
 
 @pytest.mark.parametrize("argv", [
     ["-m", "pilosa_tpu.cli", "server", "--bind", "127.0.0.1:0"],
-    ["bench.py"],
-    ["bench_suite.py", "star_trace"],
-    ["bench_kernels.py"],
+    ["-c", "import __graft_entry__; __graft_entry__.entry()"],
 ])
 def test_entry_points_refuse_to_run_without_a_chip(argv, tmp_path):
     """No TPU here and JAX_PLATFORMS unset: every entry point exits

@@ -1,8 +1,7 @@
 """Golden PQL suite on REAL multi-process clusters — the BASELINE.md
 config-5 analog (the reference's 4-node full-suite benchmark runs its
 black-box executor suite against a live cluster; real multi-chip isn't
-available here, so this is the CPU-cluster equivalent, and
-bench_suite.py's config-5 entry times the same golden run).
+available here, so this is the CPU-cluster equivalent).
 
 Cases live in tests/testdata/golden_pql.json (~35 ported from
 /root/reference/executor_test.go's 4,138-LoC black-box suite), with

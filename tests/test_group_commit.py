@@ -229,24 +229,36 @@ def sig_of(op):
     return (op, (("leaf", 0), ("leaf", 1)))
 
 
+def clustered(rng, shards):
+    """A plane that compresses: a shard holds three of its first eight
+    blocks of 128 words (so that two planes meet) — one full, one of
+    half-set words (a run a word), one of nibbles (four runs a word)."""
+    plane = np.zeros((shards, WORDS_PER_ROW), dtype=np.uint32)
+    for shard in range(shards):
+        blocks = 128 * rng.choice(8, size=3, replace=False)
+        for at, word in zip(blocks, (0xFFFFFFFF, 0x0000FFFF, 0x0F0F0F0F)):
+            plane[shard, at:at + 128] = word
+    return plane
+
+
 class Planes:
-    """Dense leaf stacks of two shard counts on the evaluator's devices,
-    their numpy originals beside them."""
+    """Leaf stacks of two shard counts on the evaluator's devices, their
+    numpy originals beside them: dense random planes, or with `reprs`
+    (a representation a leaf) clustered planes built as the serving
+    path builds them, each forced into its representation."""
 
-    def __init__(self, ev, seed=5):
-        import jax
-
+    def __init__(self, ev, seed=5, reprs=None):
         rng = np.random.default_rng(seed)
         self.host, self.dev = {}, {}
-        sharding = ev._stack_sharding()
         for shards in (8, 16):
             for leaf in range(3):
-                plane = rng.integers(0, 2**32, (shards, WORDS_PER_ROW),
-                                     dtype=np.uint32)
+                plane = clustered(rng, shards) if reprs else rng.integers(
+                    0, 2**32, (shards, WORDS_PER_ROW), dtype=np.uint32)
                 self.host[shards, leaf] = plane
-                self.dev[shards, leaf] = containers.dense_container(
-                    jax.device_put(plane, sharding) if sharding is not None
-                    else jax.numpy.asarray(plane))
+                self.dev[shards, leaf] = containers.build(
+                    plane, lambda a: ev._place(a, shard_axis=0),
+                    ev._place_replicated,
+                    mode=reprs[leaf] if reprs else "dense")
 
     def payload(self, op, shards, a, b):
         return (sig_of(op), (self.dev[shards, a], self.dev[shards, b]))
@@ -280,7 +292,8 @@ def mixed_batch(planes, n, programs=8):
 def expected_chunks(payloads):
     groups = {}
     for sig, stacks in payloads:
-        key = (sig, stacks[0].shape)
+        key = (sig, tuple((c.kind, c.shape, tuple(a.shape for a in c.arrays))
+                          for c in stacks))
         groups[key] = groups.get(key, 0) + 1
     return [list(stacked._pow2_chunks(n, StackedEvaluator.MAX_COUNT_BATCH))
             for n in groups.values()]
@@ -345,6 +358,50 @@ def test_a_batch_gives_the_solo_answers_in_the_fewest_launches(
     # no padded read: the bytes sent in are the answered queries' own
     assert after["bytes_in"] - before["bytes_in"] == sum(
         c.nbytes for _, stacks in payloads for c in stacks)
+
+
+@pytest.mark.parametrize("reprs", [
+    ("dense", "sparse"), ("dense", "rle"), ("sparse", "sparse"),
+    ("sparse", "rle"), ("rle", "rle")], ids="-".join)
+def test_a_batch_over_mixed_representations_groups_by_representation(reprs):
+    """Leaves 0 and 1 in the two representations named, leaf 2 dense:
+    eleven queries over the pairs (0, 1), (1, 2), (2, 0), (2, 2) are
+    one group a pair of representations (and operator), each sent as
+    its own chunks under one lock hold — and answer what numpy and the
+    solo program answer."""
+    ev = StackedEvaluator()
+    planes = Planes(ev, reprs=reprs + ("dense",))
+    assert [planes.dev[8, leaf].kind for leaf in range(3)] \
+        == list(reprs) + ["dense"]
+    picks = [("&|^-"[i // 4 % 4], 8, *((0, 1), (1, 2), (2, 0), (2, 2))[i % 4])
+             for i in range(11)]
+    payloads = [planes.payload("&", *p[1:]) for p in picks]
+    payloads += [planes.payload(*p) for p in picks[4:8]]
+    answers = [planes.answer("&", *p[1:]) for p in picks]
+    answers += [planes.answer(*p) for p in picks[4:8]]
+    assert len(set(answers)) > 4
+    assert [ev._process_count_batch([p])[0][0] for p in payloads] == answers
+    ev._process_count_batch(payloads)  # builds the buckets it needs
+    finish_builds(ev)
+    ev._dispatch_lock = lock = CountingLock()
+    before = ev.cache_stats()
+    try:
+        got = ev._process_count_batch(payloads)
+    finally:
+        ev._dispatch_lock = stacked._DISPATCH_LOCK
+    after = ev.cache_stats()
+    chunks = expected_chunks(payloads)
+    assert [count for count, _ in got] == answers
+    # a group of its own for each pair of representations and operator,
+    # whichever leaves a query names
+    assert len(chunks) == len({
+        (sig, tuple(c.kind for c in stacks)) for sig, stacks in payloads})
+    assert sorted(size for _, size in got) == sorted(
+        c for group in chunks for c in group for _ in range(c))
+    assert lock.holds == 1
+    assert after["count_launches"] - before["count_launches"] == sum(
+        len(group) for group in chunks)
+    assert after["count_batch_fallbacks"] == before["count_batch_fallbacks"]
 
 
 def test_an_unbuilt_bucket_goes_out_as_solos_and_is_built_afterwards():

@@ -711,3 +711,24 @@ def test_stage_spans_reach_the_profilers_trace(tmp_path):
                  "PjitFunction(count_tree)"):
         assert want in names, want
     assert "PjitFunction(fn)" not in names
+
+
+def test_every_debug_endpoint_is_in_the_operations_inventory():
+    """docs/operations.md names, in backticks, every path of the /debug
+    index — and the index holds no path whose route is gone."""
+    import os
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "docs", "operations.md")) as f:
+        named = set(re.findall(r"`(/debug[^`]*)`", f.read()))
+    h = ServerHarness()
+    try:
+        index = h.client._request("GET", "/debug")["endpoints"]
+        paths = {e["path"] for e in index}
+        assert len(paths) > 20 and all(e["description"] for e in index)
+        assert paths - named == set()
+        assert "/debug/batching" not in paths
+        with pytest.raises(Exception, match="404|not found"):
+            h.client._request("GET", "/debug/batching")
+    finally:
+        h.close()

@@ -22,7 +22,6 @@ from pilosa_tpu.cluster.meshstacks import (  # noqa: E402
     leaf_views,
 )
 from pilosa_tpu.cluster.spmd import (  # noqa: E402
-    SpmdBatchRunner,
     SpmdDataPlane,
     SpmdError,
 )
@@ -74,7 +73,6 @@ def test_debug_snapshot_shape():
     assert snap["steps"]["entered"] == 0
     assert snap["steps"]["exited"] == 0
     assert snap["stream"]["errors"] == 0
-    assert snap["queries"]["batched"] == 0
     assert snap["queries"]["fused"] == 0
     assert snap["mesh_cache"]["entries"] == 0
     assert "http_data_plane_bytes" in snap
@@ -207,30 +205,6 @@ def test_count_batch_fn_matches_serial_counts():
     assert len(p._fns) == 1
 
 
-# -- coalescer adapter --------------------------------------------------------
-
-
-def test_spmd_batch_runner_contract():
-    """The drain loop's executor contract: Count-only batchability, and
-    launch defers all work to resolve (launch runs under the coalescer
-    lock; the collective must not)."""
-
-    class _Api:
-        spmd = _plane("on")
-
-    r = SpmdBatchRunner(_Api())
-    assert r.BATCHABLE_CALLS == frozenset(("Count",))
-    handle, state = r.launch_batch("i", ["Count(Row(f=1))"] * 3)
-    assert handle is None
-    assert state == ("i", ["Count(Row(f=1))"] * 3)
-
-
-def test_cluster_executor_exposes_batchable_calls():
-    from pilosa_tpu.cluster.executor import ClusterExecutor
-
-    assert ClusterExecutor.BATCHABLE_CALLS == frozenset(("Count",))
-
-
 # -- EXPLAIN annotations ------------------------------------------------------
 
 
@@ -301,17 +275,5 @@ def test_debug_spmd_disabled_node():
             h.client._request("POST", "/debug/spmd",
                               body=json.dumps(
                                   {"serve_mode": "on"}).encode())
-    finally:
-        h.close()
-
-
-def test_api_batch_executor_single_node_is_local():
-    """Without a cluster the coalescer drains into the local vmapped
-    pipeline exactly as before this PR."""
-    h = ServerHarness()
-    try:
-        ex = h.api.batch_executor()
-        assert not isinstance(ex, SpmdBatchRunner)
-        assert hasattr(ex, "launch_batch")
     finally:
         h.close()

@@ -132,8 +132,6 @@ PROGRAMS = {  # PERF.md section 3: builder -> the name its program carries
         _jit_fn=ev._count_batch_fn(SIG, 2, 2)),
     "fused_count": lambda ev: ev.fused_count_fn(((SIG, 2),))[0],
     "plane_tree": lambda ev: ev._plane_fn(SIG, 2),
-    "count_vmap": lambda ev: ev._vmap_count_fn(SIG, 2, 4),
-    "plane_vmap": lambda ev: ev._vmap_plane_fn(SIG, 2, 4),
     "row_counts": lambda ev: ev._row_counts_fn(True),
     "bsi_sum": lambda ev: ev._sum_fn(False),
     "bsi_minmax": lambda ev: ev._minmax_fn(True, True),
