@@ -23,7 +23,7 @@ from .fragment import (
     FALSE_ROW_ID,
     TRUE_ROW_ID,
 )
-from .view import VIEW_BSI_GROUP_PREFIX, VIEW_STANDARD, View
+from .view import VIEW_BSI_GROUP_PREFIX, VIEW_STANDARD, ShardList, View
 
 FIELD_TYPE_SET = "set"
 FIELD_TYPE_INT = "int"
@@ -110,7 +110,7 @@ class FieldOptions:
                    cache_size=0)
 
 
-class Field:
+class Field(ShardList):
     def __init__(self, path, index_name, name, options=None,
                  max_op_n=None, snapshot_queue=None, row_attr_store=None,
                  translate_configurer=None):
@@ -166,6 +166,7 @@ class Field:
             for v in self.views.values():
                 v.close()
             self.views.clear()
+            self.structure_changed()
             if self.row_attr_store is not None:
                 self.row_attr_store.close()
                 self.row_attr_store = None
@@ -183,7 +184,9 @@ class Field:
             mutexed=self.options.type in (FIELD_TYPE_MUTEX, FIELD_TYPE_BOOL),
             cache_type=self.options.cache_type,
             cache_size=self.options.cache_size)
+        view.on_structure = self.structure_changed
         self.views[name] = view
+        self.structure_changed()
         return view
 
     def view(self, name=VIEW_STANDARD):
@@ -207,11 +210,8 @@ class Field:
     def time_quantum(self):
         return self.options.time_quantum
 
-    def available_shards(self):
-        shards = set()
-        for v in self.views.values():
-            shards.update(v.available_shards())
-        return sorted(shards)
+    def _shard_children(self):
+        return self.views
 
     # -- bit ops ------------------------------------------------------------
 

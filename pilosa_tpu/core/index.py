@@ -9,10 +9,17 @@ import json
 import os
 import re
 import threading
+import time
 
 from .field import Field, FieldOptions
+from .view import ShardList
 
 EXISTENCE_FIELD_NAME = "_exists"  # reference: holder.go:46
+
+# Rebuilds of an index's kept shard list and the walks' own seconds, over
+# every index of the process (/debug/vars holder). Counted on a rebuild
+# only, so a hit pays nothing; the levels below rebuild inside the walk.
+shard_list_stats = {"shard_list_rebuilds": 0, "shard_list_seconds": 0.0}
 
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_-]{0,63}$")  # reference: pilosa.go:121
 
@@ -41,7 +48,7 @@ class IndexOptions:
         return cls(**d)
 
 
-class Index:
+class Index(ShardList):
     def __init__(self, path, name, options=None, max_op_n=None,
                  snapshot_queue=None, column_attr_store=None,
                  row_attr_stores=None, translate_configurer=None):
@@ -102,6 +109,7 @@ class Index:
             for f in self.fields.values():
                 f.close()
             self.fields.clear()
+            self.structure_changed()
             if self.column_attr_store is not None:
                 self.column_attr_store.close()
                 self.column_attr_store = None
@@ -117,7 +125,9 @@ class Index:
             max_op_n=self.max_op_n, snapshot_queue=self.snapshot_queue,
             row_attr_store=self._row_attr_stores.get(name),
             translate_configurer=self.translate_configurer)
+        field.on_structure = self.structure_changed
         self.fields[name] = field
+        self.structure_changed()
         return field
 
     def _create_existence_field(self):
@@ -152,6 +162,7 @@ class Index:
             field = self.fields.pop(name, None)
             if field is None:
                 raise IndexError_(f"field not found: {name}")
+            self.structure_changed()
             field.close()
             shutil.rmtree(field.path, ignore_errors=True)
 
@@ -161,12 +172,16 @@ class Index:
 
     # -- shards -------------------------------------------------------------
 
-    def available_shards(self):
+    def _shard_children(self):
+        return self.fields
+
+    def _walk_shards(self):
         """(reference: Index.AvailableShards index.go:292)"""
-        shards = set()
-        for f in self.fields.values():
-            shards.update(f.available_shards())
-        return sorted(shards)
+        t0 = time.perf_counter()
+        shards = super()._walk_shards()
+        shard_list_stats["shard_list_rebuilds"] += 1
+        shard_list_stats["shard_list_seconds"] += time.perf_counter() - t0
+        return shards
 
     # -- existence tracking --------------------------------------------------
 

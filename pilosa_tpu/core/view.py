@@ -12,6 +12,7 @@ import threading
 from .fragment import Fragment
 
 _view_uids = itertools.count(1)
+_structure_ticks = itertools.count(1)  # ShardList's, one for every level
 
 # Rows a view keeps a change tick of its own for (see View.stamp); past
 # it the structure tick moves instead, which every leaf stack of the view
@@ -22,7 +23,52 @@ VIEW_STANDARD = "standard"
 VIEW_BSI_GROUP_PREFIX = "bsig_"
 
 
-class View:
+class ShardList:
+    """The sorted shards of one level (view, field, index), kept until
+    the level's structure changes: a query that names no shards asks
+    the index for this once per call, and a walk of every fragment
+    dictionary costs more than the rest of its plan at 954 shards.
+
+    `_kept` = (structure tick the walk started under, its result) is
+    ONE tuple; `structure_changed` is called AFTER the dictionary
+    changed, by the thread that changed it, and moves the level's tick
+    before its parent's. So a reader takes no lock: a walk that raced a
+    writer is stored under the tick it read first, which the writer has
+    moved since, and the next call walks again; a reader that comes
+    after the parent's tick moved finds every level below moved too.
+    Ticks come from one itertools.count (atomic next()), so a slot never
+    holds one value twice, whatever order racing writers store in. The
+    result is one immutable tuple, the same object until the structure
+    changes: callers share it and must not expect a copy.
+    """
+
+    on_structure = None  # the parent level's structure_changed
+    _structure = 0
+    _kept = (None, ())
+
+    def structure_changed(self):
+        self._structure = next(_structure_ticks)
+        if self.on_structure is not None:
+            self.on_structure()
+
+    def available_shards(self):
+        tick, shards = self._kept
+        if tick == self._structure:
+            return shards
+        tick = self._structure
+        shards = self._walk_shards()
+        self._kept = (tick, shards)
+        return shards
+
+    def _walk_shards(self):
+        """Union of the levels below (field, index)."""
+        shards = set()
+        for child in list(self._shard_children().values()):
+            shards.update(child.available_shards())
+        return tuple(sorted(shards))
+
+
+class View(ShardList):
     def __init__(self, path, index, field, name, max_op_n=None,
                  snapshot_queue=None, mutexed=False, cache_type="none",
                  cache_size=0):
@@ -77,6 +123,7 @@ class View:
                 f.close()
             self.fragments.clear()
             self._bump_mutations()
+            self.structure_changed()
 
     def remove_fragment(self, shard):
         """Detach and return one fragment (resize cleanup). Bumps the
@@ -86,6 +133,7 @@ class View:
             frag = self.fragments.pop(shard, None)
             if frag is not None:
                 self._bump_mutations()
+                self.structure_changed()
             return frag
 
     def fragment_path(self, shard):
@@ -103,6 +151,7 @@ class View:
         frag.on_mutate = self._bump_mutations
         self.fragments[shard] = frag
         self._bump_mutations()
+        self.structure_changed()
         return frag
 
     def _bump_mutations(self, rows=None):
@@ -144,8 +193,8 @@ class View:
                 frag.open()
             return frag
 
-    def available_shards(self):
-        return sorted(self.fragments.keys())
+    def _walk_shards(self):
+        return tuple(sorted(self.fragments))
 
     # -- routed ops ---------------------------------------------------------
 

@@ -716,6 +716,8 @@ class Executor:
     # ------------------------------------------------------- shard selection
 
     def _call_shards(self, idx, shards):
+        # the index's kept tuple as it is (core/view.py ShardList): the
+        # same object from call to call, for callers that only read it
         out = list(shards) if shards is not None \
             else idx.available_shards()
         self._explain_tls.shards = len(out)

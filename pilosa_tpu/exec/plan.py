@@ -400,7 +400,7 @@ class Planner:
     # -- shared helpers ------------------------------------------------------
 
     def _shards(self, idx, shards):
-        return list(self.ex._call_shards(idx, shards))
+        return self.ex._call_shards(idx, shards)
 
     def _min_shards(self):
         from .stacked import MIN_SHARDS

@@ -12,7 +12,7 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
-from ..core.index import IndexOptions
+from ..core.index import IndexOptions, shard_list_stats
 from ..core import timeq
 from .api import ApiError, GatewayTimeoutError, NotFoundError, \
     ServiceUnavailableError, field_options_from_json, \
@@ -705,9 +705,10 @@ class PilosaHTTPServer:
             out["stacked"] = local.stacked_stats()
         # finished live spans by name (wall, self, thread CPU); all CPU of
         # the process, runtime threads and edge included; cache flushes
+        # and rebuilds of the indexes' kept shard lists
         out["spans"] = tracing.span_stats()
         out["process"] = {"cpu_seconds": time.process_time()}
-        out["holder"] = self.api.holder.flush_stats()
+        out["holder"] = {**self.api.holder.flush_stats(), **shard_list_stats}
         if self.api.spmd is not None:
             out["spmd"] = self.api.spmd.stats()
         from ..utils import workpool

@@ -261,8 +261,8 @@ def test_field_bulk_import_multi_shard(holder, rng):
     cols = rng.integers(0, 4 * SHARD_WIDTH, 2000).astype(np.uint64)
     rows = rng.integers(0, 10, 2000).astype(np.uint64)
     fld.import_bits(rows, cols)
-    assert fld.available_shards() == sorted(
-        {int(c) // SHARD_WIDTH for c in cols})
+    assert fld.available_shards() == tuple(sorted(
+        {int(c) // SHARD_WIDTH for c in cols}))
     # spot-check membership
     for r, c in list(zip(rows, cols))[:20]:
         frag = fld.view().fragment(int(c) // SHARD_WIDTH)
