@@ -370,13 +370,47 @@ MAX_TIME_VIEWS = 64
 
 _OPS = {"Intersect": "&", "Union": "|", "Difference": "-", "Xor": "^"}
 
-def _pow2_chunks(n, cap):
-    """`n` as the powers of two that add up to it, none over `cap`,
-    largest first: 37 under 32 is 32 + 4 + 1."""
-    while n:
-        size = min(cap, 1 << (n.bit_length() - 1))
-        yield size
-        n -= size
+#: a count signature's root operator as data: the code a slot of a bucket
+#: program carries in its `ops` vector, in the order of the program's
+#: branches. A spare slot carries PAD_OP, the branch that reads nothing.
+ROOT_OPS = ("&", "|", "^", "-")
+PAD_OP = len(ROOT_OPS)
+
+
+def _split_root(sig):
+    """(shape, operator code) of a count signature: the shape is the
+    signature with its root operator taken out — (None, subs), or a bare
+    leaf as it is, under code 0. Operators below the root stay in the
+    shape."""
+    if sig[0] == "leaf":
+        return sig, 0
+    return (None, sig[1]), ROOT_OPS.index(sig[0])
+
+
+def _bucket_args(chunk, size):
+    """A `size`-slot bucket program's arguments for `chunk`, (position,
+    signature, operator code, flat leaves) of each query it answers: the
+    `ops` vector, the queries' leaves, and for each spare slot PAD_OP and
+    the first query's leaves (references: the slot reads none)."""
+    ops = np.full(size, PAD_OP, dtype=np.int32)
+    ops[:len(chunk)] = [op for _, _, op, _ in chunk]
+    args = [ops]
+    for _, _, _, flat in chunk:
+        args.extend(flat)
+    args.extend(chunk[0][3] * (size - len(chunk)))
+    return args
+
+
+def _launch_plan(n, cap):
+    """(slots, queries) of each launch that sends a group of `n`: whole
+    launches of `cap`, then the rest in ONE launch of the next power of
+    two (37 under 32 is 32 + 8 with three spare slots); one slot is the
+    solo program."""
+    plan = [(cap, cap)] * (n // cap)
+    rest = n % cap
+    if rest:
+        plan.append((1 << (rest - 1).bit_length(), rest))
+    return plan
 
 
 #: thread-local batch attribution: the batch paths stamp how many
@@ -532,10 +566,12 @@ class StackedEvaluator:
         # fuse into ONE program per signature bucket + ONE fetch).
         self._fetch_commit = GroupCommit()
         self._count_commit = GroupCommit()
-        # device launches made for count batches, and chunks that went out
-        # as solo programs because their bucket was not built yet (its
-        # build thread is in _count_builds until the program is in _fns)
+        # device launches made for count batches, the spare slots they
+        # carried, and chunks that went out in other programs because
+        # their bucket was not built yet (its build thread is in
+        # _count_builds until the program is in _fns)
         self.count_launches = 0
+        self.count_pad_slots = 0
         self.count_batch_fallbacks = 0
         self._count_builds = {}
         self._lock = threading.Lock()
@@ -1566,24 +1602,42 @@ class StackedEvaluator:
 
         return self._get_fn(("count", sig, csig), build)
 
-    def _count_batch_fn(self, sig, csig, batch):
-        """`batch` independent count trees of one signature fused into ONE
-        program: args are batch*flat_arity container components, outputs
-        are [batch] (hi, lo) vectors: one dispatch + one fetch amortize
-        the per-query round trip across every concurrent query.
+    def _count_batch_fn(self, shape, csig, size):
+        """`size` independent count trees of one SHAPE (_split_root) in
+        ONE program: args are `ops` int32[size], each slot's root
+        operator as data, then size*flat_arity container components;
+        outputs are [size] (hi, lo) vectors. A slot switches on its code
+        between the whole count programs of the four operators —
+        count_program on the concrete signature, popcount and (hi, lo)
+        split included, so each branch is the fusion the solo program
+        runs and keeps its own strategy — and the spare slot's branch,
+        which touches no operand. A bare leaf has the one count branch;
+        lax.switch clamps PAD_OP to the last branch, the spare one.
         Returns the jitted program itself; what serves is its compilation
         for one group's shapes (_compile_count_bucket)."""
+        import jax
         import jax.numpy as jnp
 
         csig = _containers.norm_csig(csig)
         af = _containers.flat_arity(csig)
+        sigs = [shape] if shape[0] == "leaf" else [
+            (op, shape[1]) for op in ROOT_OPS]
 
-        def fn(*all_flat):
+        def count(sig):
+            return lambda *flat: _containers.count_program(
+                sig, csig, flat, self._tree_eval)
+
+        def spare(*flat):
+            zero = jnp.zeros((), jnp.int32)
+            return zero, zero
+
+        branches = [count(sig) for sig in sigs] + [spare]
+
+        def fn(ops, *all_flat):
             his, los = [], []
-            for q in range(batch):
-                flat = all_flat[q * af:(q + 1) * af]
-                hi, lo = _containers.count_program(
-                    sig, csig, flat, self._tree_eval)
+            for q in range(size):
+                hi, lo = jax.lax.switch(
+                    ops[q], branches, *all_flat[q * af:(q + 1) * af])
                 his.append(hi)
                 los.append(lo)
             return jnp.stack(his), jnp.stack(los)
@@ -1654,20 +1708,20 @@ class StackedEvaluator:
 
     #: largest fused count program: 32 bounds device time per launch (32
     #: passes over the leaf stacks) near the round trip it amortizes. A
-    #: group is sent as the power-of-two chunks that add up to it
-    #: (5 = 4 + 1), so at most log2(MAX) + 1 programs compile per
-    #: signature and no launch reads a plane twice to pad itself
+    #: group goes out as ONE launch of the next power of two, its spare
+    #: slots reading nothing (_launch_plan), so at most log2(MAX) bucket
+    #: programs compile per (shape, containers, argument shapes)
     MAX_COUNT_BATCH = 32
 
     def _batched_count(self, sig, stacks):
         """Group-commit count execution: the batch leader takes every
         count query that queued during the previous batch, groups them
-        by program (signature and stack shapes), launches each group as
-        its power-of-two chunks under ONE hold of the dispatch lock,
-        fetches ALL results in one transfer, and distributes. A lone
-        query leads at once and pays nothing extra; a follower makes no
-        launch, takes no lock and fetches nothing; leader failures
-        propagate to every waiter (GroupCommit contract).
+        by program (shape, containers and stack shapes — the root
+        operator is data), launches each group under ONE hold of the
+        dispatch lock, fetches ALL results in one transfer, and
+        distributes. A lone query leads at once and pays nothing extra;
+        a follower makes no launch, takes no lock and fetches nothing;
+        leader failures propagate to every waiter (GroupCommit contract).
 
         The caller looked its stacks up itself, before it queues here: a
         read that follows an acknowledged write is answered from stacks
@@ -1688,37 +1742,46 @@ class StackedEvaluator:
         (sig, stacks) pairs; returns (count, fused-batch size) pairs in
         order — the size is how many queries shared the payload's
         launch. Every query reads its own leaves (flat arguments, one
-        count_program a query, as _count_batch_fn builds them)."""
+        count_program a slot, as _count_batch_fn builds them); a spare
+        slot is handed its group's first leaves and reads none."""
         groups = {}
         nbytes_in = 0
         for pos, (sig, stacks) in enumerate(payloads):
             flat = _containers.flatten(stacks)
+            shape, op = _split_root(sig)
             # a group is one program: the dense csig carries no shape,
             # and a compiled bucket takes one shape and sharding only
-            key = (sig, tuple(c.csig for c in stacks),
+            key = (shape, tuple(c.csig for c in stacks),
                    tuple((a.shape, a.dtype, a.sharding) for a in flat))
-            groups.setdefault(key, []).append((pos, flat))
+            groups.setdefault(key, []).append((pos, sig, op, flat))
             nbytes_in += sum(c.nbytes for c in stacks)
-        launches = []  # (program, flat arguments, positions answered)
+        launches = []  # (program, arguments, positions answered)
         unbuilt = []
+        spare = 0
         for key, members in groups.items():
-            solo = self._count_fn(key[0], key[1])
             at = 0
-            for size in _pow2_chunks(len(members), self.MAX_COUNT_BATCH):
-                chunk = members[at:at + size]
-                at += size
-                fn = solo if size == 1 else self._cached_fn(
-                    ("countB", key, size))
-                if fn is None:
-                    # never compile with followers waiting: send the
-                    # chunk as the program that exists, build afterwards
-                    unbuilt.append((key, size))
-                    launches.extend(
-                        (solo, flat, (pos,)) for pos, flat in chunk)
+            for slots, n in _launch_plan(len(members), self.MAX_COUNT_BATCH):
+                fn = None if slots == 1 else self._cached_fn(
+                    ("countB", key, slots))
+                if fn is not None or slots == 1:
+                    cover = [(fn, slots, n)]
                 else:
-                    launches.append(
-                        (fn, [a for _, flat in chunk for a in flat],
-                         [pos for pos, _ in chunk]))
+                    # never compile with followers waiting: send the
+                    # chunk in the programs that exist, build afterwards
+                    unbuilt.append((key, slots))
+                    cover = self._built_cover(key, n)
+                for fn, size, real in cover:
+                    chunk = members[at:at + real]
+                    at += real
+                    positions = [pos for pos, _, _, _ in chunk]
+                    if fn is None:  # one slot: the solo program
+                        _, sig, _, flat = chunk[0]
+                        launches.append(
+                            (self._count_fn(sig, key[1]), flat, positions))
+                    else:
+                        spare += size - real
+                        launches.append(
+                            (fn, _bucket_args(chunk, size), positions))
         first = next((fn for fn, _, _ in launches
                       if fn._spec_key not in self._fn_specs), None)
         with self._locked_dispatch("count", nbytes_in=nbytes_in, fn=first,
@@ -1733,6 +1796,7 @@ class StackedEvaluator:
                 span.set_tag("launches", len(launches))
         with self._lock:
             self.count_launches += len(launches)
+            self.count_pad_slots += spare
             self.count_batch_fallbacks += len(unbuilt)
         vals = fetch([a for out in outs for a in out])  # ONE transfer
         results = [None] * len(payloads)
@@ -1743,15 +1807,34 @@ class StackedEvaluator:
             for q, pos in enumerate(positions):
                 results[pos] = (combine_hi_lo(his[q], los[q]),
                                 len(positions))
-        for key, size in unbuilt:
-            self._build_count_bucket(key, size)
+        for key, slots in unbuilt:
+            self._build_count_bucket(key, slots)
         return results
 
+    def _built_cover(self, key, n):
+        """(program, slots, queries) launches that send `n` queries of
+        group `key` while the bucket they ask for is not built: the
+        smallest built bucket that holds them all, else the largest
+        built one as often as it fills and the rest likewise; solos
+        (no program, one slot) where none is built or one query is
+        left."""
+        built = [(slots, fn) for slots, fn in (
+            (1 << k, self._cached_fn(("countB", key, 1 << k)))
+            for k in range(1, self.MAX_COUNT_BATCH.bit_length()))
+            if fn is not None]
+        cover = []
+        while built and n > 1:
+            slots, fn = next((b for b in built if b[0] >= n), built[-1])
+            cover.append((fn, slots, min(n, slots)))
+            n -= min(n, slots)
+        return cover + [(None, 1, 1)] * n
+
     def _build_count_bucket(self, key, size):
-        """Compile the `size`-query program of group `key` on a thread
+        """Compile the `size`-slot program of group `key` on a thread
         of its own: off the dispatch lock, with nobody waiting on it.
         One build a bucket; a failed build goes to the flight recorder
-        and the bucket's chunks keep going out as solos."""
+        and the bucket's chunks keep going out in the programs that
+        exist."""
         fkey = ("countB", key, size)
         with self._lock:
             if fkey in self._count_builds or fkey in self._fns:
@@ -1764,15 +1847,16 @@ class StackedEvaluator:
     def _compile_count_bucket(self, fkey):
         import jax
 
-        _, (sig, csig, args), size = fkey
-        specs = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-                      for shape, dtype, sharding in args) * size
+        _, (shape, csig, leaves), size = fkey
+        specs = (jax.ShapeDtypeStruct((size,), np.int32),) + tuple(
+            jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+            for dims, dtype, sharding in leaves) * size
         try:
-            jitted = self._count_batch_fn(sig, csig, size)
+            jitted = self._count_batch_fn(shape, csig, size)
             compiled = jitted.lower(*specs).compile()
         except Exception as exc:  # noqa: BLE001 — the solos keep serving
             _flightrec.record("count_bucket.build_failed", size=size,
-                              sig=repr(sig), error=repr(exc))
+                              sig=repr(shape), error=repr(exc))
             return
         fn = self._wrap_spec_capture(fkey, compiled)
         fn._jit_fn = jitted
@@ -2198,6 +2282,7 @@ class StackedEvaluator:
                 "count_batches": self._count_commit.batches,
                 "count_batched_queries": self._count_commit.batched,
                 "count_launches": self.count_launches,
+                "count_pad_slots": self.count_pad_slots,
                 "count_batch_fallbacks": self.count_batch_fallbacks,
                 "fused_dispatches": self.fused_dispatches,
                 "stack_bytes": self._stack_bytes,

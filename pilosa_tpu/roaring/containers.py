@@ -142,11 +142,18 @@ class Container:
         """Bulk union of a sorted-or-not uint16 batch; returns change count."""
         if len(values) == 0:
             return 0
-        words = self.to_dense_words().copy()
+        from .. import native
+
+        # every step keeps the interpreter lock (_zero_words says why):
+        # the values are scattered into the dense words in place and
+        # counted by one native call each, no 8 KiB numpy temporary
+        words = self.to_dense_words()
+        if self.typ == TYPE_BITMAP:  # the container's own buffer: copy it
+            words = np.frombuffer(bytearray(np.ascontiguousarray(
+                words, dtype=np.uint32)), dtype=np.uint32)
         before = self.n
-        add = values_to_words(np.asarray(values, dtype=np.uint16))
-        words |= add
-        n = int(np.sum(popcount32(words)))
+        native.scatter_u16(np.asarray(values, dtype=np.uint16), words)
+        n = native.popcount(words)
         self._become_dense(words, n)
         return n - before
 
@@ -186,7 +193,7 @@ class Container:
             return self.words
         if self.typ == TYPE_ARRAY:
             return values_to_words(self.values)
-        words = np.zeros(WORDS, dtype=np.uint32)
+        words = _zero_words()
         for s, l in self.runs:
             _fill_run(words, int(s), int(l))
         return words
@@ -271,10 +278,20 @@ def popcount32(words):
     return native.popcount_per_word(words)
 
 
+def _zero_words():
+    """[2048] uint32 zeros, allocated with the interpreter lock kept.
+    numpy gives the lock up around a zeroed allocation of 1 KiB or more
+    (and around a copy or a loop over more than 500 elements); under 32
+    reading threads each such hand-over queues the writer behind every
+    runnable reader (PERF.md section 6, PR 36: 93 % of an import's wall
+    time stood at four such lines). A bytearray's does not."""
+    return np.frombuffer(bytearray(BITMAP_BYTES), dtype=np.uint32)
+
+
 def values_to_words(values):
     from .. import native
 
-    words = np.zeros(WORDS, dtype=np.uint32)
+    words = _zero_words()
     if len(values):
         native.scatter_u16(np.asarray(values, dtype=np.uint16), words)
     return words

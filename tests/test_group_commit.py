@@ -1,6 +1,7 @@
-"""Group commit on the serving path (ISSUE 27): one leader at a time in
-`GroupCommit`, and `_process_count_batch` — one hold of the dispatch lock,
-one fetch and no padded read a batch, never a compile with followers
+"""Group commit on the serving path (ISSUE 27, ISSUE 36): one leader at a
+time in `GroupCommit`, and `_process_count_batch` — one hold of the
+dispatch lock, one fetch and one launch a group (the root operator is
+data, a spare slot reads nothing), never a compile with followers
 waiting. Nothing below is timed: threads meet on events, and `queued`
 polls the commit's own queue."""
 
@@ -241,6 +242,10 @@ def clustered(rng, shards):
     return plane
 
 
+def popcount(words):
+    return int(np.unpackbits(words.view(np.uint8)).sum())
+
+
 class Planes:
     """Leaf stacks of two shard counts on the evaluator's devices, their
     numpy originals beside them: dense random planes, or with `reprs`
@@ -264,8 +269,7 @@ class Planes:
         return (sig_of(op), (self.dev[shards, a], self.dev[shards, b]))
 
     def answer(self, op, shards, a, b):
-        words = OPS[op](self.host[shards, a], self.host[shards, b])
-        return int(np.unpackbits(words.view(np.uint8)).sum())
+        return popcount(OPS[op](self.host[shards, a], self.host[shards, b]))
 
 
 class CountingLock:
@@ -281,21 +285,26 @@ class CountingLock:
 
 
 def mixed_batch(planes, n, programs=8):
-    """`n` queries over three leaves and `programs` of the eight programs
-    that four operators and two shard sets make."""
+    """`n` queries over three leaves, `programs` of the eight pairs that
+    four operators and two shard sets make: whatever the operators, one
+    group a shard set."""
     picks = [("&|^-"[i % programs % 4], (8, 16)[i % programs // 4],
               i % 3, (i + 1) % 3) for i in range(n)]
     return ([planes.payload(*p) for p in picks],
             [planes.answer(*p) for p in picks])
 
 
-def expected_chunks(payloads):
+def expected_launches(payloads):
+    """The (slots, queries) launches of a batch: one group a (shape x
+    representation x shard set) — the root operator is no part of it —
+    and each group's launch plan."""
     groups = {}
     for sig, stacks in payloads:
-        key = (sig, tuple((c.kind, c.shape, tuple(a.shape for a in c.arrays))
-                          for c in stacks))
+        key = (stacked._split_root(sig)[0],
+               tuple((c.kind, c.shape, tuple(a.shape for a in c.arrays))
+                     for c in stacks))
         groups[key] = groups.get(key, 0) + 1
-    return [list(stacked._pow2_chunks(n, StackedEvaluator.MAX_COUNT_BATCH))
+    return [stacked._launch_plan(n, StackedEvaluator.MAX_COUNT_BATCH)
             for n in groups.values()]
 
 
@@ -305,13 +314,33 @@ def finish_builds(ev):
         assert not thread.is_alive()
 
 
+def run_counted(ev, payloads):
+    """One `_process_count_batch` under a lock that counts its holds:
+    (results, holds, what the counters gained)."""
+    ev._dispatch_lock = lock = CountingLock()
+    before = dict(ev.cache_stats(), **ev._kernels.get("count", {}))
+    try:
+        got = ev._process_count_batch(payloads)
+    finally:
+        ev._dispatch_lock = stacked._DISPATCH_LOCK
+    after = dict(ev.cache_stats(), **ev._kernels["count"])
+    gained = {k: after[k] - before.get(k, 0) for k in (
+        "count", "bytes_in", "count_launches", "count_pad_slots",
+        "count_batch_fallbacks")}
+    return got, lock.holds, gained
+
+
+def built_sizes(ev):
+    return sorted(key[2] for key in ev._fns if key[0] == "countB")
+
+
 BATCHES = [(1, 8), (3, 8), (5, 8), (17, 8), (33, 8), (5, 1), (33, 1)]
 
 
 @pytest.fixture(scope="module")
 def warm():
     """An evaluator that has seen every batch below once, so that every
-    bucket they decompose into is built."""
+    bucket they ask for is built."""
     ev = StackedEvaluator()
     planes = Planes(ev)
     for n, programs in BATCHES:
@@ -321,42 +350,64 @@ def warm():
     return ev, planes
 
 
-def test_pow2_chunks_add_up_and_pad_nothing():
-    for n in range(1, 100):
-        chunks = list(stacked._pow2_chunks(n, 32))
-        assert sum(chunks) == n
-        assert all(c & (c - 1) == 0 and c <= 32 for c in chunks)
-        assert chunks == sorted(chunks, reverse=True)
-        assert len([c for c in chunks if c < 32]) <= 5
-    assert list(stacked._pow2_chunks(37, 32)) == [32, 4, 1]
+@pytest.mark.parametrize("n", range(1, 71))
+def test_a_launch_plan_is_one_padded_launch_after_the_full_ones(n):
+    plan = stacked._launch_plan(n, 32)
+    assert sum(queries for _, queries in plan) == n
+    assert all(slots & (slots - 1) == 0 and slots <= 32
+               and slots // 2 < queries <= slots for slots, queries in plan)
+    assert [slots for slots, _ in plan[:-1]] == [32] * (len(plan) - 1)
+    assert len([1 for slots, queries in plan if slots > queries]) <= 1
+    assert len(plan) == -(-n // 32)
+
+
+def test_the_plans_the_issue_names():
+    assert stacked._launch_plan(37, 32) == [(32, 32), (8, 5)]
+    assert stacked._launch_plan(33, 32) == [(32, 32), (1, 1)]
+    assert stacked._launch_plan(16, 32) == [(16, 16)]
+    assert stacked._launch_plan(1, 32) == [(1, 1)]
+
+
+@pytest.mark.parametrize("sig,shape,code", [
+    (("leaf", 0), ("leaf", 0), 0),
+    (sig_of("&"), (None, sig_of("&")[1]), 0),
+    (sig_of("|"), (None, sig_of("&")[1]), 1),
+    (sig_of("^"), (None, sig_of("&")[1]), 2),
+    (sig_of("-"), (None, sig_of("&")[1]), 3),
+    (("|", (sig_of("-"), ("leaf", 2))), (None, (sig_of("-"), ("leaf", 2))), 1),
+], ids=["leaf", "and", "or", "xor", "andnot", "nested"])
+def test_a_signature_splits_into_shape_and_root_operator(sig, shape, code):
+    assert stacked._split_root(sig) == (shape, code)
+    assert stacked.PAD_OP == 4 and code < stacked.PAD_OP
 
 
 @pytest.mark.parametrize("n,programs", BATCHES)
-def test_a_batch_gives_the_solo_answers_in_the_fewest_launches(
+def test_a_batch_gives_the_solo_answers_in_one_launch_a_group(
         warm, n, programs):
     ev, planes = warm
     payloads, answers = mixed_batch(planes, n, programs)
     solo = [ev._process_count_batch([p])[0][0] for p in payloads]
     assert solo == answers
-    ev._dispatch_lock = lock = CountingLock()
-    before = dict(ev.cache_stats(), **ev._kernels["count"])
-    try:
-        got = ev._process_count_batch(payloads)
-    finally:
-        ev._dispatch_lock = stacked._DISPATCH_LOCK
-    after = dict(ev.cache_stats(), **ev._kernels["count"])
-    chunks = expected_chunks(payloads)
+    got, holds, gained = run_counted(ev, payloads)
+    launches = expected_launches(payloads)
+    # one launch a (shape x representation x shard set) group, two for 33
+    assert [len(group) for group in launches] == [
+        2 if sum(queries for _, queries in group) > 32 else 1
+        for group in launches]
     assert [count for count, _ in got] == answers
-    # the size a query reports is the size of the launch it rode
+    # the size a query reports is the number of real queries in its launch
     assert sorted(size for _, size in got) == sorted(
-        c for group in chunks for c in group for _ in range(c))
-    assert lock.holds == 1
-    assert after["count"] - before["count"] == 1
-    assert after["count_launches"] - before["count_launches"] == sum(
-        len(group) for group in chunks)
-    assert after["count_batch_fallbacks"] == before["count_batch_fallbacks"]
-    # no padded read: the bytes sent in are the answered queries' own
-    assert after["bytes_in"] - before["bytes_in"] == sum(
+        queries for group in launches for _, queries in group
+        for _ in range(queries))
+    assert holds == 1
+    assert gained["count"] == 1
+    assert gained["count_launches"] == sum(len(g) for g in launches)
+    assert gained["count_pad_slots"] == sum(
+        slots - queries for group in launches for slots, queries in group)
+    assert gained["count_batch_fallbacks"] == 0
+    # a spare slot reads nothing: the bytes sent in are the answered
+    # queries' own
+    assert gained["bytes_in"] == sum(
         c.nbytes for _, stacks in payloads for c in stacks)
 
 
@@ -365,10 +416,11 @@ def test_a_batch_gives_the_solo_answers_in_the_fewest_launches(
     ("sparse", "rle"), ("rle", "rle")], ids="-".join)
 def test_a_batch_over_mixed_representations_groups_by_representation(reprs):
     """Leaves 0 and 1 in the two representations named, leaf 2 dense:
-    eleven queries over the pairs (0, 1), (1, 2), (2, 0), (2, 2) are
-    one group a pair of representations (and operator), each sent as
-    its own chunks under one lock hold — and answer what numpy and the
-    solo program answer."""
+    fifteen queries over the pairs (0, 1), (1, 2), (2, 0), (2, 2) under
+    all four operators are one group a pair of representations whatever
+    the operator, each sent as one launch under one lock hold — and
+    answer what numpy and the solo program answer (each branch keeps
+    count_program's own strategy for its operator)."""
     ev = StackedEvaluator()
     planes = Planes(ev, reprs=reprs + ("dense",))
     assert [planes.dev[8, leaf].kind for leaf in range(3)] \
@@ -383,58 +435,222 @@ def test_a_batch_over_mixed_representations_groups_by_representation(reprs):
     assert [ev._process_count_batch([p])[0][0] for p in payloads] == answers
     ev._process_count_batch(payloads)  # builds the buckets it needs
     finish_builds(ev)
-    ev._dispatch_lock = lock = CountingLock()
-    before = ev.cache_stats()
-    try:
-        got = ev._process_count_batch(payloads)
-    finally:
-        ev._dispatch_lock = stacked._DISPATCH_LOCK
-    after = ev.cache_stats()
-    chunks = expected_chunks(payloads)
+    got, holds, gained = run_counted(ev, payloads)
+    launches = expected_launches(payloads)
     assert [count for count, _ in got] == answers
-    # a group of its own for each pair of representations and operator,
-    # whichever leaves a query names
-    assert len(chunks) == len({
-        (sig, tuple(c.kind for c in stacks)) for sig, stacks in payloads})
+    # a group of its own for each pair of representations, whichever
+    # leaves and whichever operator a query names
+    assert len(launches) == len({
+        tuple(c.kind for c in stacks) for _, stacks in payloads})
+    assert all(len(group) == 1 for group in launches)
     assert sorted(size for _, size in got) == sorted(
-        c for group in chunks for c in group for _ in range(c))
-    assert lock.holds == 1
-    assert after["count_launches"] - before["count_launches"] == sum(
-        len(group) for group in chunks)
-    assert after["count_batch_fallbacks"] == before["count_batch_fallbacks"]
+        queries for group in launches for _, queries in group
+        for _ in range(queries))
+    assert holds == 1
+    assert gained["count_launches"] == len(launches)
+    assert gained["count_batch_fallbacks"] == 0
+
+
+def test_every_operator_and_a_bare_leaf_ride_buckets_against_numpy():
+    """Two of each operator are ONE bucket of eight (one `countB` program
+    whatever the operators); three bare leaves are a group of their own
+    (another arity), one bucket of four with a spare slot."""
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    picks = [(op, 8, a, b) for op in "&|^-" for a, b in ((0, 1), (2, 1))]
+    payloads = [planes.payload(*p) for p in picks]
+    answers = [planes.answer(*p) for p in picks]
+    for leaf in range(3):
+        payloads.append((("leaf", 0), (planes.dev[8, leaf],)))
+        answers.append(popcount(planes.host[8, leaf]))
+    assert len(set(answers)) == len(answers)
+    ev._process_count_batch(payloads)
+    finish_builds(ev)
+    assert built_sizes(ev) == [4, 8]
+    got, holds, gained = run_counted(ev, payloads)
+    assert [count for count, _ in got] == answers
+    assert [size for _, size in got] == [8] * 8 + [3] * 3
+    assert holds == 1
+    assert gained["count_launches"] == 2
+    assert gained["count_pad_slots"] == 1
+    assert gained["count_batch_fallbacks"] == 0
+
+
+@pytest.mark.parametrize("subs", [
+    (("leaf", 0), ("leaf", 1), ("leaf", 2)),
+    (("-", (("leaf", 0), ("leaf", 1))), ("leaf", 2)),
+    (("leaf", 2), ("^", (("leaf", 1), ("|", (("leaf", 0), ("leaf", 2)))))),
+], ids=["three-leaf-root", "nested-left", "nested-right"])
+def test_the_root_is_data_and_inner_operators_stay_in_the_shape(subs):
+    """All four root operators over one shape — a 3-leaf root, or a
+    nested tree whose inner operators stay in the shape — share ONE
+    bucket and answer what the solo program and numpy answer."""
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    stacks = tuple(planes.dev[8, leaf] for leaf in range(3))
+    host = [planes.host[8, leaf] for leaf in range(3)]
+
+    def numpy_eval(sig):
+        if sig[0] == "leaf":
+            return host[sig[1]]
+        acc = numpy_eval(sig[1][0])
+        for sub in sig[1][1:]:
+            acc = OPS[sig[0]](acc, numpy_eval(sub))
+        return acc
+
+    payloads = [((op, subs), stacks) for op in "&|^-"]
+    answers = [popcount(numpy_eval(sig)) for sig, _ in payloads]
+    assert len(set(answers)) == 4
+    solo = [ev._process_count_batch([p])[0][0] for p in payloads]
+    assert solo == answers
+    batch, want = payloads + payloads[:1], answers + answers[:1]
+    ev._process_count_batch(batch)
+    finish_builds(ev)
+    got, _, gained = run_counted(ev, batch)
+    assert [count for count, _ in got] == want
+    assert gained["count_launches"] == 1
+    assert gained["count_pad_slots"] == 3      # 5 ride a bucket of 8
+    assert built_sizes(ev) == [8]
+    assert len({key[1][0] for key in ev._fns if key[0] == "countB"}) == 1
+
+
+def test_spare_slots_answer_nobody_and_are_counted():
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    payloads, answers = mixed_batch(planes, 5, 4)   # one group of five
+    ev._process_count_batch(payloads)
+    finish_builds(ev)
+    assert built_sizes(ev) == [8]
+    got, _, gained = run_counted(ev, payloads)
+    assert [count for count, _ in got] == answers
+    assert len(got) == 5 and {size for _, size in got} == {5}
+    assert gained["count_launches"] == 1
+    assert gained["count_pad_slots"] == 3
+    assert ev.cache_stats()["count_pad_slots"] == 3
+    # the program itself: a spare slot's count is zero whatever leaves
+    # it was handed
+    fn = ev._cached_fn(next(k for k in ev._fns if k[0] == "countB"))
+    ops = np.full(8, stacked.PAD_OP, dtype=np.int32)
+    flat = containers.flatten(payloads[0][1])
+    his, los = fn(ops, *flat * 8)
+    assert not np.asarray(his).any() and not np.asarray(los).any()
+
+
+@pytest.mark.parametrize("size", [2, 16])
+def test_a_bucket_is_one_program_of_one_case_a_slot(size):
+    """The lowered bucket holds exactly `size` `stablehlo.case` — a
+    five-way switch a slot, nothing vmapped or selected — and is the one
+    program of its size for all four operators."""
+    import jax
+
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    shape, _ = stacked._split_root(sig_of("&"))
+    leaf = jax.ShapeDtypeStruct((8, WORDS_PER_ROW), np.uint32)
+    text = ev._count_batch_fn(shape, 2, size).lower(
+        jax.ShapeDtypeStruct((size,), np.int32),
+        *[leaf] * (2 * size)).as_text()
+    assert text.count("stablehlo.case") == size
+    assert "stablehlo.select" not in text
+    batch = [planes.payload("&|^-"[i % 4], 8, i % 3, (i + 1) % 3)
+             for i in range(size)]
+    ev._process_count_batch(batch)
+    finish_builds(ev)
+    ev._process_count_batch(batch[::-1])
+    assert built_sizes(ev) == [size]
 
 
 def test_an_unbuilt_bucket_goes_out_as_solos_and_is_built_afterwards():
     ev = StackedEvaluator()
     planes = Planes(ev)
     payloads, answers = mixed_batch(planes, 4)
-    batch = [payloads[0]] * 5 + [payloads[1]]
-    want = [answers[0]] * 5 + [answers[1]]
-    ev._dispatch_lock = lock = CountingLock()
-    try:
-        got = ev._process_count_batch(batch)
-    finally:
-        ev._dispatch_lock = stacked._DISPATCH_LOCK
+    other = planes.payload("&", 16, 0, 1)
+    batch = payloads + payloads[:1] + [other]
+    want = answers + answers[:1] + [planes.answer("&", 16, 0, 1)]
+    got, holds, gained = run_counted(ev, batch)
     assert [count for count, _ in got] == want
     assert {size for _, size in got} == {1}
-    stats = ev.cache_stats()
-    assert stats["count_batch_fallbacks"] == 1   # the chunk of 4
-    assert stats["count_launches"] == 6          # 4 solos + 1 + 1
-    assert lock.holds == 1
+    assert gained["count_batch_fallbacks"] == 1   # the group of 5
+    assert gained["count_launches"] == 6          # 5 solos + the other's
+    assert gained["count_pad_slots"] == 0
+    assert holds == 1
     finish_builds(ev)
     assert not ev._count_builds
-    built = [key for key in ev._fns if key[0] == "countB"]
-    assert [key[2] for key in built] == [4]
-    # the same batch now rides the bucket: 4 + 1, and the other group's 1
-    got = ev._process_count_batch(batch)
+    assert built_sizes(ev) == [8]
+    # the same batch now rides the bucket: 5 in 8, and the other group's 1
+    got, _, gained = run_counted(ev, batch)
     assert [count for count, _ in got] == want
-    assert sorted(size for _, size in got) == [1, 1, 4, 4, 4, 4]
-    stats = ev.cache_stats()
-    assert stats["count_batch_fallbacks"] == 1
-    assert stats["count_launches"] == 6 + 3
+    assert sorted(size for _, size in got) == [1, 5, 5, 5, 5, 5]
+    assert gained["count_batch_fallbacks"] == 0
+    assert gained["count_launches"] == 2
+    assert gained["count_pad_slots"] == 3
     # /debug/kernels prices the bucket from the shapes it was built for
     assert any("countB" in entry["key"]
                for entry in ev.kernels_snapshot()["compiled"])
+
+
+def test_an_unbuilt_16_rides_two_built_8s():
+    """While a bucket compiles its chunk goes out in the buckets that
+    exist — the smallest built one that holds it, else the largest as
+    often as it fills — and as solos only where none does."""
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    payloads, answers = mixed_batch(planes, 16, 4)   # one group
+    ev._process_count_batch(payloads[:8])
+    finish_builds(ev)
+    assert built_sizes(ev) == [8]
+    started = []
+    ev._build_count_bucket = lambda key, size: started.append(size)
+    got, holds, gained = run_counted(ev, payloads)
+    assert [count for count, _ in got] == answers
+    assert {size for _, size in got} == {8}
+    assert holds == 1
+    assert gained["count_launches"] == 2
+    assert gained["count_batch_fallbacks"] == 1
+    assert gained["count_pad_slots"] == 0
+    assert started == [16]       # the missing bucket is built afterwards
+    # 13 = a full 8 and 5 in another 8; 3 fit the one built bucket
+    got, _, gained = run_counted(ev, payloads[:13])
+    assert [count for count, _ in got] == answers[:13]
+    assert sorted({size for _, size in got}) == [5, 8]
+    assert (gained["count_launches"], gained["count_pad_slots"]) == (2, 3)
+    got, _, gained = run_counted(ev, payloads[:3])
+    assert [count for count, _ in got] == answers[:3]
+    assert (gained["count_launches"], gained["count_pad_slots"]) == (1, 5)
+    assert gained["count_batch_fallbacks"] == 1
+    assert started == [16, 16, 4]
+    # 9 with one left over: a full 8 and a solo
+    got, _, gained = run_counted(ev, payloads[:9])
+    assert [count for count, _ in got] == answers[:9]
+    assert sorted(size for _, size in got) == [1] + [8] * 8
+    assert (gained["count_launches"], gained["count_pad_slots"]) == (2, 0)
+
+
+def test_a_batch_of_one_is_the_solo_program_and_builds_no_operator_vector(
+        monkeypatch):
+    ev = StackedEvaluator()
+    planes = Planes(ev)
+    payload, answer = planes.payload("^", 8, 0, 2), planes.answer("^", 8, 0, 2)
+    calls = []
+    solo = ev._count_fn(payload[0], 2)
+    monkeypatch.setattr(
+        ev, "_count_fn", lambda sig, csig: calls.append(sig) or solo)
+    monkeypatch.setattr(stacked.np, "full", lambda *a, **k: pytest.fail(
+        "a lone query built an operator vector"))
+    monkeypatch.setattr(ev, "_build_count_bucket", lambda *a: pytest.fail(
+        "a lone query asked for a bucket"))
+    got, holds, gained = run_counted(ev, [payload])
+    assert got == [(answer, 1)]
+    assert calls == [payload[0]]      # the concrete signature, its operator in it
+    assert holds == 1
+    assert gained["count_launches"] == 1
+    assert gained["count_pad_slots"] == 0
+    assert built_sizes(ev) == []
+    # the solo program is today's: the operator in the signature, no switch
+    import jax
+    leaf = jax.ShapeDtypeStruct((8, WORDS_PER_ROW), np.uint32)
+    text = solo._jit_fn.lower(leaf, leaf).as_text()
+    assert "stablehlo.case" not in text and "count_tree" in text
 
 
 def test_a_leaders_lapsed_deadline_does_not_fail_its_batch(warm):
@@ -499,5 +715,6 @@ def test_32_http_clients_share_batches(tmp_path):
         assert after["count_batches"] - before["count_batches"] < queries_n
         assert after["count_launches"] >= after["count_batches"]
         assert "count_batch_fallbacks" in after
+        assert "count_pad_slots" in after
     finally:
         h.close()

@@ -61,6 +61,54 @@ def test_container_conversions(rng):
     assert set(c.to_values()) == set(range(4000, 5000))
 
 
+@pytest.mark.parametrize("kind,base", [
+    ("empty", []), ("array", range(0, 6000, 60)),
+    ("bitmap", range(0, 65536, 3)), ("run", None)])
+@pytest.mark.parametrize("batch", [[7], [7, 7, 65535, 0], range(100, 9000, 7)],
+                         ids=["one", "repeats", "many"])
+def test_container_add_many_differential(kind, base, batch):
+    """Bulk add against a Python set, from every representation; the
+    dense words a bitmap container handed out before are not written."""
+    if kind == "run":
+        c = Container.from_runs([[10, 20], [4000, 4999]])
+        have = set(range(10, 21)) | set(range(4000, 5000))
+    else:
+        c = Container.from_values(np.array(list(base), dtype=np.uint16))
+        have = set(base)
+    handed_out = c.to_dense_words()
+    before = handed_out.copy()
+    want = have | set(batch)
+    assert c.add_many(np.array(list(batch), dtype=np.uint16)) \
+        == len(want) - len(have)
+    assert c.n == len(want)
+    assert set(int(v) for v in c.to_values()) == want
+    assert c.typ == (TYPE_BITMAP if len(want) > 4096 else TYPE_ARRAY)
+    assert np.array_equal(handed_out, before)
+    assert c.add_many(np.array(list(batch), dtype=np.uint16)) == 0
+
+
+def test_dense_words_are_made_with_the_interpreter_lock_kept(monkeypatch):
+    """No 8 KiB numpy zero-fill, copy or loop on the bulk-add path: numpy
+    gives the interpreter lock up around each, and under 32 reading
+    threads an import queued for it ~130 times (PERF.md section 6,
+    PR 36)."""
+    from pilosa_tpu.roaring import containers
+
+    for name in ("zeros", "sum"):
+        monkeypatch.setattr(containers.np, name, lambda *a, **k: pytest.fail(
+            f"np.{name} on the bulk-add path"))
+    words = values_to_words(np.array([1, 40000], dtype=np.uint16))
+    assert words.dtype == np.uint32 and words.shape == (2048,)
+    assert words.flags.writeable and words.flags.c_contiguous
+    assert values_to_words(np.array([], dtype=np.uint16)).sum() == 0
+    for c in (Container.from_values(np.arange(0, 300, 3, dtype=np.uint16)),
+              Container.from_dense_words(np.full(2048, 0x0F0F0F0F, np.uint32),
+                                         n=2048 * 16)):
+        was = c.n
+        assert c.add_many(np.array([4, 5, 40004], dtype=np.uint16)) \
+            == c.n - was == 3
+
+
 def test_container_runs_roundtrip():
     c = Container.from_runs([[3, 10], [100, 100], [65530, 65535]])
     assert c.n == 8 + 1 + 6

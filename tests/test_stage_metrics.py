@@ -129,7 +129,7 @@ SIG = ("&", (("leaf", 0), ("leaf", 1)))
 PROGRAMS = {  # PERF.md section 3: builder -> the name its program carries
     "count_tree": lambda ev: ev._count_fn(SIG, 2),
     "count_batch": lambda ev: types.SimpleNamespace(
-        _jit_fn=ev._count_batch_fn(SIG, 2, 2)),
+        _jit_fn=ev._count_batch_fn((None, SIG[1]), 2, 2)),
     "fused_count": lambda ev: ev.fused_count_fn(((SIG, 2),))[0],
     "plane_tree": lambda ev: ev._plane_fn(SIG, 2),
     "row_counts": lambda ev: ev._row_counts_fn(True),
