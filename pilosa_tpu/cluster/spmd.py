@@ -1357,11 +1357,11 @@ class SpmdDataPlane:
 
     def _topn_candidates(self, idx, field_name):
         """This node's TopN candidate rows (shared policy:
-        exec.executor.fragment_topn_candidates), capped at
+        core.view.fragment_topn_candidates), capped at
         TOPN_MAX_ROWS+1: a single node already past the cap forces the
         HTTP fallback regardless of the union, so shipping more ids in
         the validate response would be pure wasted payload."""
-        from ..exec.executor import fragment_topn_candidates
+        from ..core.view import fragment_topn_candidates
 
         field = idx.field(field_name)
         view = field.view(VIEW_STANDARD) if field is not None else None

@@ -183,6 +183,10 @@ class Fragment:
             self.cache.clear()
             for row_id in self.row_ids():
                 self.cache.add(row_id, self.row_count(row_id))
+            # no row changed, the TopN candidates may have: the view's
+            # tick moves and its row stamps stay (View._bump_mutations)
+            if self.on_mutate is not None:
+                self.on_mutate(())
 
     def close(self):
         with self._lock:
@@ -235,8 +239,8 @@ class Fragment:
             if self.mutexed and vec is not None:
                 vec[column_id % SHARD_WIDTH] = row_id
             self._append_op(encode_op(OP_ADD, value=pos))
+            self._cache_update(row_id)  # before the view's tick moves
             self._invalidate_row(row_id)
-            self._cache_update(row_id)
         return changed
 
     def clear_bit(self, row_id, column_id):
@@ -253,8 +257,8 @@ class Fragment:
                 if int(vec[off]) == row_id:
                     vec[off] = -1
             self._append_op(encode_op(OP_REMOVE, value=pos))
+            self._cache_update(row_id)  # before the view's tick moves
             self._invalidate_row(row_id)
-            self._cache_update(row_id)
         return changed
 
     def _handle_mutex(self, row_id, column_id):
@@ -429,9 +433,9 @@ class Fragment:
                     changed += n
                     touched.update(_rows_of(arr))
             if changed:
-                self._invalidate_all_rows(touched)
-                for row_id in touched:
+                for row_id in touched:  # before the view's tick moves
                     self._cache_update(row_id)
+                self._invalidate_all_rows(touched)
             return changed
 
     def bulk_import(self, row_ids, column_ids, clear=False):
@@ -489,9 +493,9 @@ class Fragment:
                 self._append_op(encode_op(op, roaring=serialize(other), op_n=changed))
                 touched = {
                     int(key) // CONTAINERS_PER_SHARD for key in other.keys()}
-                self._invalidate_all_rows(touched)
-                for row_id in touched:
+                for row_id in touched:  # before the view's tick moves
                     self._cache_update(row_id)
+                self._invalidate_all_rows(touched)
             return changed
 
     # -- row planes (the device path) ----------------------------------------
@@ -587,9 +591,9 @@ class Fragment:
                 OP_REMOVE_ROARING, roaring=serialize(full), op_n=0))
             self._append_op(encode_op(
                 OP_ADD_ROARING, roaring=serialize(row_bitmap), op_n=0))
+            self._cache_update(row_id)  # before the view's tick moves
             self._invalidate_row(row_id)
             self._drop_mutex_vec()  # whole-row overwrite: rebuild lazily
-            self._cache_update(row_id)
             return True
 
     # -- persistence ---------------------------------------------------------
@@ -666,8 +670,10 @@ class Fragment:
         self._note_change(rows)
 
     def _note_change(self, rows):
-        """Move the fingerprints after storage changed (caller holds
-        self._lock, so writers never race each other here)."""
+        """Move the fingerprints after storage and the TopN cache changed
+        (caller holds self._lock, so writers never race each other here):
+        the view keeps unions of both under its tick (View.row_ids,
+        View.topn_candidates)."""
         self.generation += 1
         gen = self.generation
         own = self._row_gens[1]

@@ -8,7 +8,9 @@ Reference: view.go:44. Names: "standard", time-quantum views
 import itertools
 import os
 import threading
+import time
 
+from ..utils.workpool import shard_map_reduce
 from .fragment import Fragment
 
 _view_uids = itertools.count(1)
@@ -21,6 +23,21 @@ ROW_STAMPS_MAX = 4096
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_GROUP_PREFIX = "bsig_"
+
+# Walks of the views' kept row sets (View.row_ids / topn_candidates), the
+# walks' own seconds, and the calls a kept set answered without a walk,
+# over every view of the process (/debug/vars holder).
+row_set_stats = {"row_set_walks": 0, "row_set_hits": 0,
+                 "row_set_seconds": 0.0}
+
+
+def fragment_topn_candidates(frag, use_cache=True):
+    """THE per-fragment TopN candidate policy: cache ids when a cache is
+    populated (the reference's approximation), else every present row.
+    Shared by View.topn_candidates and the SPMD data plane."""
+    if use_cache and frag.cache is not None and len(frag.cache):
+        return frag.cache.ids()
+    return frag.row_ids()
 
 
 class ShardList:
@@ -103,6 +120,14 @@ class View(ShardList):
         self._ticks = itertools.count(1)
         self.mutations = 0
         self._row_stamps = (0, {})
+        # The kept unions over the fragments (row_ids, topn_candidates):
+        # ONE tuple each, (mutation tick read before the walk, shards,
+        # sorted row ids). Every mutator moves `mutations` after the rows
+        # and the TopN cache it changed, so a walk that raced a writer is
+        # kept under a tick the writer has moved since, and the next call
+        # walks again; a reader takes no lock.
+        self._kept_rows = (None, None, ())
+        self._kept_candidates = (None, None, ())
 
     def open(self):
         frag_dir = os.path.join(self.path, "fragments")
@@ -114,7 +139,7 @@ class View(ShardList):
                 shard = int(name)
             except ValueError:
                 continue
-            self._new_fragment(shard).open()
+            self._new_fragment(shard)
         return self
 
     def close(self):
@@ -140,6 +165,8 @@ class View(ShardList):
         return os.path.join(self.path, "fragments", str(shard))
 
     def _new_fragment(self, shard):
+        """Open a fragment, then publish it: a reader never finds one
+        whose rows and cache have not been read from disk yet."""
         kwargs = {}
         if self.max_op_n is not None:
             kwargs["max_op_n"] = self.max_op_n
@@ -149,6 +176,7 @@ class View(ShardList):
             cache_type=self.cache_type, cache_size=self.cache_size,
             **kwargs)
         frag.on_mutate = self._bump_mutations
+        frag.open()
         self.fragments[shard] = frag
         self._bump_mutations()
         self.structure_changed()
@@ -190,11 +218,51 @@ class View(ShardList):
             frag = self.fragments.get(shard)
             if frag is None:
                 frag = self._new_fragment(shard)
-                frag.open()
             return frag
 
     def _walk_shards(self):
         return tuple(sorted(self.fragments))
+
+    # -- kept row sets --------------------------------------------------------
+
+    def row_ids(self, shards):
+        """Sorted union of the fragments' row ids over `shards` (Rows()),
+        kept until a fragment of the view changes. An immutable tuple,
+        the same object until then: callers must not expect a copy."""
+        return self._kept_union("_kept_rows", shards, Fragment.row_ids)
+
+    def topn_candidates(self, shards, use_cache=True):
+        """Sorted union of fragment_topn_candidates over `shards` (the
+        TopN candidate set), kept as row_ids is."""
+        if not use_cache:  # the policy is then every present row
+            return self.row_ids(shards)
+        return self._kept_union("_kept_candidates", shards,
+                                fragment_topn_candidates)
+
+    def _kept_union(self, slot, shards, of_fragment):
+        tick = self.mutations  # before the walk: see _kept_rows
+        if not isinstance(shards, tuple):
+            shards = tuple(shards)
+        kept_tick, kept_shards, rows = getattr(self, slot)
+        if kept_tick == tick and (kept_shards is shards
+                                  or kept_shards == shards):
+            row_set_stats["row_set_hits"] += 1
+            return rows
+        t0 = time.perf_counter()
+
+        def shard_rows(shard):
+            frag = self.fragments.get(shard)
+            return None if frag is None else of_fragment(frag)
+
+        found = set()
+        for ids in shard_map_reduce(shards, shard_rows):
+            if ids is not None:
+                found.update(ids)
+        rows = tuple(sorted(found))
+        setattr(self, slot, (tick, shards, rows))
+        row_set_stats["row_set_walks"] += 1
+        row_set_stats["row_set_seconds"] += time.perf_counter() - t0
+        return rows
 
     # -- routed ops ---------------------------------------------------------
 

@@ -182,15 +182,6 @@ def unwrap_options(call, opt):
     return call, opt
 
 
-def fragment_topn_candidates(frag, use_cache=True):
-    """THE per-fragment TopN candidate policy: cache ids when a cache is
-    populated (the reference's approximation), else every present row.
-    Shared by the local executor and the SPMD data plane."""
-    if use_cache and frag.cache is not None and len(frag.cache):
-        return frag.cache.ids()
-    return frag.row_ids()
-
-
 class Executor:
     """Single-node executor over a Holder. The cluster layer (parallel/)
     wraps this with shard->node fan-out."""
@@ -1431,25 +1422,16 @@ class Executor:
     def _candidate_rows(self, field, shard_list, restrict_ids, use_cache,
                         view_name):
         """Global candidate row set: union over fragments of their TopN
-        cache ids (when populated) or all present rows."""
+        cache ids (when populated) or all present rows, as the view keeps
+        it (core/view.py View.topn_candidates)."""
         view = field.view(view_name)
         if view is None:
             return []
-
-        def shard_rows(shard):
-            frag = view.fragment(shard)
-            if frag is None:
-                return None
-            return fragment_topn_candidates(frag, use_cache)
-
-        rows = set()
-        for cand in shard_map_reduce(shard_list, shard_rows):
-            if cand is not None:
-                rows.update(cand)
+        rows = view.topn_candidates(shard_list, use_cache)
         if restrict_ids is not None:
             wanted = {int(r) for r in restrict_ids}
-            rows &= wanted
-        return sorted(rows)
+            return [r for r in rows if r in wanted]
+        return list(rows)
 
     def _row_counts(self, idx, field, call, shards, restrict_ids=None,
                     view_name=VIEW_STANDARD, use_cache=False):
@@ -1589,17 +1571,16 @@ class Executor:
             view = field.view(view_name)
             if view is None:
                 continue
+            if column is None:  # the view's kept union (View.row_ids)
+                rows.update(view.row_ids(shard_list))
+                continue
 
             def shard_rows(shard, view=view):
                 frag = view.fragment(shard)
-                if frag is None:
+                if frag is None or column // SHARD_WIDTH != shard:
                     return None
-                if column is not None:
-                    if column // SHARD_WIDTH != shard:
-                        return None
-                    return {r for r in frag.row_ids()
-                            if frag.contains(r, column)}
-                return set(frag.row_ids())
+                return {r for r in frag.row_ids()
+                        if frag.contains(r, column)}
 
             for found in shard_map_reduce(shard_list, shard_rows):
                 if found is not None:

@@ -13,6 +13,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
 from ..core.index import IndexOptions, shard_list_stats
+from ..core.view import row_set_stats
 from ..core import timeq
 from .api import ApiError, GatewayTimeoutError, NotFoundError, \
     ServiceUnavailableError, field_options_from_json, \
@@ -704,11 +705,13 @@ class PilosaHTTPServer:
         if hasattr(local, "stacked_stats"):
             out["stacked"] = local.stacked_stats()
         # finished live spans by name (wall, self, thread CPU); all CPU of
-        # the process, runtime threads and edge included; cache flushes
-        # and rebuilds of the indexes' kept shard lists
+        # the process, runtime threads and edge included; cache flushes,
+        # rebuilds of the indexes' kept shard lists and walks of the
+        # views' kept row sets
         out["spans"] = tracing.span_stats()
         out["process"] = {"cpu_seconds": time.process_time()}
-        out["holder"] = {**self.api.holder.flush_stats(), **shard_list_stats}
+        out["holder"] = {**self.api.holder.flush_stats(), **shard_list_stats,
+                         **row_set_stats}
         if self.api.spmd is not None:
             out["spmd"] = self.api.spmd.stats()
         from ..utils import workpool

@@ -175,6 +175,9 @@ class TestFragmentCacheIntegration:
         # (the reference's cache approximation)
         frag = f.view("standard").fragment(0)
         frag.cache.invalidate(8)
+        # outside the fragment's mutators: move the view's tick as they
+        # do, or the view's kept candidate set stands (View.topn_candidates)
+        frag.on_mutate(())
         pairs = ex.execute("i", "TopN(f, n=5)")[0]
         assert [(p.id, p.count) for p in pairs] == [(5, 3)]
 

@@ -26,7 +26,8 @@ SHARDS = 8
 WAIT = 120
 REHEARSAL_EXIT = 3
 NEW_METRICS = ("path.call_ms.groupby", "path.call_ms.topn",
-               "stack.rows_hit_rate", "kernel.roofline_share.scan")
+               "stack.rows_hit_rate", "kernel.roofline_share.scan",
+               "holder.row_set_hit_rate")
 # accepted metrics this cell is listed on besides the generic ones: the
 # CPU split of the spans table and the dispatch lock's wait (PR 38's review)
 LISTED = ("exec.dispatches_per_query", "api.cpu_ms", "exec.cpu_ms",
@@ -322,17 +323,18 @@ def test_the_counters_see_the_scans(served):
     assert after["rows_stack_bytes"] <= after["rows_stack_budget_bytes"]
 
 
-def test_a_239_shard_groupby_profile_keeps_its_stages(tmp_path):
-    """At the configuration's 239 shards a GroupBy's `Rows()` walk opens
-    ≈ 480 `workpool.task` spans before any stage span: past the profile's
-    task leaves they are dropped, so the lock wait, kernel and fetch that
-    follow (what `dispatch.*` and `exec.self_ms` read) stay in the tree."""
-    from pilosa_tpu.utils import profile
+GROUPBY_239 = "GroupBy(Rows(a), Rows(b), filter=Row(m=1))"
+
+
+@pytest.fixture(scope="module")
+def at_239_shards(tmp_path_factory):
+    """The configuration's 239 shards: fields a and b with rows 0 and 1,
+    the filter m, and every stack resident after one GroupBy."""
     from tests.harness import ServerHarness
 
     shards = 239
     cols = [s << 20 for s in range(shards)]
-    h = ServerHarness(data_dir=str(tmp_path))
+    h = ServerHarness(data_dir=str(tmp_path_factory.mktemp("t239")))
     try:
         h.client.create_index("t")
         for field in ("a", "b", "m"):
@@ -341,23 +343,53 @@ def test_a_239_shard_groupby_profile_keeps_its_stages(tmp_path):
             h.client.import_bits("t", field, [rows[0]] * shards
                                  + [rows[1]] * shards,
                                  cols + [c + 1 for c in cols])
-        pql = "GroupBy(Rows(a), Rows(b), filter=Row(m=1))"
-        h.client.query("t", pql)        # stacks resident, as in a window
-        reply = h.client.query("t", pql, profile=True)
+        h.client.query("t", GROUPBY_239)  # stacks resident, as in a window
+        h.shards = shards
+        yield h
     finally:
         h.close()
-    assert [g["count"] for g in reply["results"][0]] == [shards, shards]
+
+
+def _profiled_groupby(h):
+    reply = h.client.query("t", GROUPBY_239, profile=True)
+    assert [g["count"] for g in reply["results"][0]] == [h.shards] * 2
     names = collections.Counter()
     todo = [reply["profile"]["spans"]]
     while todo:
         node = todo.pop()
         names[node["name"]] += 1
         todo += node.get("children", [])
-    assert names["workpool.task"] == profile.MAX_TASK_LEAVES
-    assert reply["profile"]["spansDropped"] > 0
     for stage in ("executor.executeGroupBy", "stack.lookup",
                   "dispatch.lock_wait", "stacked.kernel", "dispatch.fetch"):
         assert names[stage] >= 1, stage
+    return reply, names
+
+
+def test_a_239_shard_groupby_profile_keeps_its_stages(at_239_shards):
+    """The first GroupBy after a write to `a` walks `a`'s 239 fragments
+    again (its view's tick moved): ≈ 240 `workpool.task` spans before any
+    stage span. Past the profile's task leaves they are dropped, so the
+    lock wait, kernel and fetch that follow (what `dispatch.*` and
+    `exec.self_ms` read) stay in the tree."""
+    from pilosa_tpu.utils import profile
+
+    h = at_239_shards
+    # row 0 of `a` in a column that neither `b` nor `m` holds: the groups
+    # and their counts stay, the view's tick moves
+    h.client.import_bits("t", "a", [0], [5])
+    reply, names = _profiled_groupby(h)
+    assert names["workpool.task"] == profile.MAX_TASK_LEAVES
+    assert reply["profile"]["spansDropped"] > 0
+
+
+def test_a_warm_239_shard_groupby_walks_nothing(at_239_shards):
+    """Nothing written since the last GroupBy: both `Rows()` answer from
+    their views' kept sets and the call opens no `workpool.task`."""
+    h = at_239_shards
+    h.client.query("t", GROUPBY_239)
+    reply, names = _profiled_groupby(h)
+    assert names["workpool.task"] == 0
+    assert reply["profile"].get("spansDropped", 0) == 0
 
 
 @pytest.mark.parametrize("fault", SCAN_FAULTS)
@@ -424,31 +456,38 @@ def test_the_pairwise_program_carries_its_name(backend, has_filt):
 
 
 def _context(bench, before, after, profiles=(), trace=None, queries=(),
-             device=None, config=None):
+             device=None, config=None, section="stacked"):
     run = types.SimpleNamespace(
         cell={}, config=config or {}, spec={},
         device=device or {"platform": "cpu"}, opened=0.0, closed=40.0,
         t0=-80.0, window=[], profiles=list(profiles),
-        before={"vars": {"stacked": before}},
-        after={"vars": {"stacked": after}}, trace=trace,
+        before={"vars": {section: before}},
+        after={"vars": {section: after}}, trace=trace,
         traced_queries=list(queries))
     return bench.cell.Context(run)
 
 
-BEFORE = {"rows_lookups": 10, "rows_hits": 8}
-AFTER = {"rows_lookups": 410, "rows_hits": 408}
-COUNTED = {"stack.rows_hit_rate": 1.0}
+# name: (the /debug/vars section, before, after, what the reader gives)
+COUNTED = {
+    "stack.rows_hit_rate": ("stacked", {"rows_lookups": 10, "rows_hits": 8},
+                            {"rows_lookups": 410, "rows_hits": 408}, 1.0),
+    "holder.row_set_hit_rate": (
+        "holder", {"row_set_walks": 7, "row_set_hits": 3},
+        {"row_set_walks": 9, "row_set_hits": 201, "row_set_seconds": 0.5},
+        198 / 200),
+}
 
 
 @pytest.mark.parametrize("name", sorted(COUNTED))
 def test_reader_of_a_counter(bench, name):
+    section, before, after, want = COUNTED[name]
     read = bench.manifest.reader(name)
-    assert read(_context(bench, BEFORE, AFTER)) == pytest.approx(
-        COUNTED[name])
+    assert read(_context(bench, before, after, section=section)) \
+        == pytest.approx(want)
     # a parent commit has neither counter: nothing, and no error
-    parent = {"hits": 5, "misses": 0}
-    assert read(_context(bench, parent, parent)) is None
-    assert read(_context(bench, BEFORE, BEFORE)) is None
+    parent = {"hits": 5, "misses": 0, "cache_flush_seconds": 1.0}
+    assert read(_context(bench, parent, parent, section=section)) is None
+    assert read(_context(bench, before, before, section=section)) is None
 
 
 @pytest.mark.parametrize("name,span", [

@@ -635,7 +635,8 @@ def test_debug_vars_spans_process_and_holder(tmp_path):
         assert cpu == sorted(cpu) and cpu[0] > 0
         assert set(v2["holder"]) == {
             "cache_flushes", "cache_flush_seconds",
-            "shard_list_rebuilds", "shard_list_seconds"}
+            "shard_list_rebuilds", "shard_list_seconds",
+            "row_set_walks", "row_set_hits", "row_set_seconds"}
         assert v2["holder"]["cache_flushes"] == 0
         assert v2["holder"]["cache_flush_seconds"] == 0.0
         h.holder.flush_caches()
