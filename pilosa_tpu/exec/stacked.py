@@ -1465,7 +1465,7 @@ class StackedEvaluator:
         finally:
             _flightrec.watch_end(token)
         # dispatch.account: the always-on bookkeeping after a launch
-        # (kernel and phase tables, their histograms, the flight
+        # (kernel and phase tables, the kernel histogram, the flight
         # recorder, the profile's tags), off the lock
         with _tracing.start_span("dispatch.account", op=kind):
             wait, wall = t1 - t0, t2 - t1
@@ -1523,9 +1523,6 @@ class StackedEvaluator:
                     p = fam[phase] = {"count": 0, "seconds": 0.0}
                 p["count"] += 1
                 p["seconds"] += dt
-        for phase, dt in phases:
-            global_stats.timing("dispatch_phase_seconds", dt,
-                                {"kernel": kind, "phase": phase})
 
     def dispatch_phases(self):
         """{kernel: {phase: {count, seconds}}} snapshot — the RTT

@@ -17,7 +17,7 @@ import time
 import numpy as np
 
 from ..cluster.broadcast import MessageType, Serializer
-from ..utils import faultpoints
+from ..utils import faultpoints, tracing
 from ..utils import incident as incident_mod
 from ..core import FieldOptions, Holder, IndexOptions
 from ..core.field import (
@@ -665,8 +665,6 @@ class API:
             return
 
         def run():
-            from ..utils import tracing
-
             try:
                 with tracing.annotate("oplog.rotate_checkpoint"):
                     self.holder.sync_fragments()
@@ -694,7 +692,6 @@ class API:
 
         from ..utils import flightrec
         from ..utils import profile as profile_mod
-        from ..utils import tracing
 
         self._validate_state()
         if self.holder.index(index_name) is None:
@@ -786,7 +783,6 @@ class API:
 
         from ..utils import flightrec
         from ..utils import profile as profile_mod
-        from ..utils import tracing
         # Profile when the request asked (?profile=true) or a slow-query
         # threshold is configured (so a slow query's log line carries the
         # full span tree, not just its total). Remote fan-out legs never
@@ -894,8 +890,6 @@ class API:
         the trace (client.debug_trace → the peers' ?local=true form, so
         the fan-out cannot recurse) and merges it with per-node
         clock-skew correction — see utils/tracing.estimate_skew."""
-        from ..utils import tracing
-
         local = tracing.get_trace(trace_id)
         tracer = tracing.get_tracer()
         if hasattr(tracer, "to_dicts"):
@@ -1363,7 +1357,8 @@ class API:
                       timestamps=timestamps, clear=clear,
                       remote=remote, row_keys=row_keys,
                       column_keys=column_keys)
-        lsn = self._oplog_append("bits", kwargs)
+        with tracing.start_span("oplog.append"):
+            lsn = self._oplog_append("bits", kwargs)
         faultpoints.reached("import.post-append")
         if self._queue_resize_write("bits", kwargs, lsn=lsn):
             return 0
@@ -1376,14 +1371,16 @@ class API:
                 if t_cols is not None:
                     column_ids = t_cols
             if remote or self.cluster is None or len(self.cluster.nodes) <= 1:
-                changed = field.import_bits(
-                    row_ids, column_ids, timestamps=timestamps, clear=clear)
-                self.holder.index(index_name).add_existence(column_ids)
-                if self.ingest is not None:
-                    self._ingest_record(
-                        index_name, field,
-                        self._ingest_shard_rows(column_ids),
-                        16 * len(column_ids))
+                with tracing.start_span("field.import"):
+                    changed = field.import_bits(
+                        row_ids, column_ids, timestamps=timestamps,
+                        clear=clear)
+                    self.holder.index(index_name).add_existence(column_ids)
+                    if self.ingest is not None:
+                        self._ingest_record(
+                            index_name, field,
+                            self._ingest_shard_rows(column_ids),
+                            16 * len(column_ids))
                 self._broadcast_shards_if_changed(index_name)
                 faultpoints.reached("import.pre-ack")
                 return changed
@@ -1408,10 +1405,12 @@ class API:
                     ts_arr = np.asarray(timestamps, dtype=object)
                     slice_ts = ts_arr[mask].tolist()
                 if local:
-                    changed += field.import_bits(
-                        slice_rows, slice_cols, timestamps=slice_ts,
-                        clear=clear)
-                    self.holder.index(index_name).add_existence(slice_cols)
+                    with tracing.start_span("field.import"):
+                        changed += field.import_bits(
+                            slice_rows, slice_cols, timestamps=slice_ts,
+                            clear=clear)
+                        self.holder.index(index_name).add_existence(
+                            slice_cols)
                     covered.add(shard)
                 else:
                     remote_only.add(shard)
@@ -1443,7 +1442,8 @@ class API:
             # needs no replay guarantee — mark it applied either way so
             # one failed import can't pin the checkpoint watermark
             # forever (a process crash skips this; that's the point)
-            self._oplog_applied_or_defer(lsn)
+            with tracing.start_span("oplog.applied"):
+                self._oplog_applied_or_defer(lsn)
 
     def import_values(self, index_name, field_name, column_ids, values,
                       remote=False, column_keys=None, clear=False):
@@ -1458,7 +1458,8 @@ class API:
                       column_ids=column_ids, values=values,
                       remote=remote, column_keys=column_keys,
                       clear=clear)
-        lsn = self._oplog_append("values", kwargs)
+        with tracing.start_span("oplog.append"):
+            lsn = self._oplog_append("values", kwargs)
         faultpoints.reached("import.post-append")
         if self._queue_resize_write("values", kwargs, lsn=lsn):
             return 0
@@ -1467,14 +1468,17 @@ class API:
                 _, column_ids = self._translate_import_keys(
                     index_name, field_name, None, column_keys)
             if remote or self.cluster is None or len(self.cluster.nodes) <= 1:
-                changed = field.import_values(column_ids, values, clear=clear)
-                if not clear:
-                    self.holder.index(index_name).add_existence(column_ids)
-                if self.ingest is not None:
-                    self._ingest_record(
-                        index_name, field,
-                        self._ingest_shard_rows(column_ids),
-                        16 * len(column_ids), existence=not clear)
+                with tracing.start_span("field.import"):
+                    changed = field.import_values(
+                        column_ids, values, clear=clear)
+                    if not clear:
+                        self.holder.index(index_name).add_existence(
+                            column_ids)
+                    if self.ingest is not None:
+                        self._ingest_record(
+                            index_name, field,
+                            self._ingest_shard_rows(column_ids),
+                            16 * len(column_ids), existence=not clear)
                 self._broadcast_shards_if_changed(index_name)
                 faultpoints.reached("import.pre-ack")
                 return changed
@@ -1491,11 +1495,12 @@ class API:
                 mask = shards == shard
                 local, remotes = self._route_import(index_name, shard)
                 if local:
-                    changed += field.import_values(
-                        column_ids[mask], values[mask], clear=clear)
-                    if not clear:
-                        self.holder.index(index_name).add_existence(
-                            column_ids[mask])
+                    with tracing.start_span("field.import"):
+                        changed += field.import_values(
+                            column_ids[mask], values[mask], clear=clear)
+                        if not clear:
+                            self.holder.index(index_name).add_existence(
+                                column_ids[mask])
                     covered.add(shard)
                 else:
                     remote_only.add(shard)
@@ -1518,7 +1523,8 @@ class API:
             faultpoints.reached("import.pre-ack")
             return changed + remote_changed
         finally:
-            self._oplog_applied_or_defer(lsn)
+            with tracing.start_span("oplog.applied"):
+                self._oplog_applied_or_defer(lsn)
 
     def import_roaring(self, index_name, field_name, shard, data,
                        clear=False, view="standard", remote=False):
@@ -1528,18 +1534,20 @@ class API:
         field = self._field(index_name, field_name)
         shard = int(shard)
         self._ingest_admit(1, len(data))
-        lsn = self._oplog_append("roaring", dict(
-            index_name=index_name, field_name=field_name, shard=shard,
-            data=data, clear=clear, view=view, remote=remote))
+        with tracing.start_span("oplog.append"):
+            lsn = self._oplog_append("roaring", dict(
+                index_name=index_name, field_name=field_name, shard=shard,
+                data=data, clear=clear, view=view, remote=remote))
         faultpoints.reached("import.post-append")
         try:
             local, remotes = (True, []) if remote else \
                 self._route_import(index_name, shard)
             changed = 0
             if local:
-                v = field.create_view_if_not_exists(view)
-                frag = v.create_fragment_if_not_exists(shard)
-                changed = frag.import_roaring(data, clear=clear)
+                with tracing.start_span("field.import"):
+                    v = field.create_view_if_not_exists(view)
+                    frag = v.create_fragment_if_not_exists(shard)
+                    changed = frag.import_roaring(data, clear=clear)
                 if self.ingest is not None:
                     self._ingest_record(
                         index_name, field, {shard: 1}, len(data),
@@ -1556,7 +1564,8 @@ class API:
             faultpoints.reached("import.pre-ack")
             return changed if local else remote_changed
         finally:
-            self._oplog_applied_or_defer(lsn)
+            with tracing.start_span("oplog.applied"):
+                self._oplog_applied_or_defer(lsn)
 
     def _field(self, index_name, field_name):
         idx = self.holder.index(index_name)

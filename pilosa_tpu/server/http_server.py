@@ -15,16 +15,17 @@ from urllib.parse import parse_qs, urlparse
 from ..core.index import IndexOptions, shard_list_stats
 from ..core.view import row_set_stats
 from ..core import timeq
+from ..utils import threadcpu, tracing
 from .api import ApiError, GatewayTimeoutError, NotFoundError, \
     ServiceUnavailableError, field_options_from_json, \
     field_options_to_json, result_to_json
 
 
 class Route:
-    def __init__(self, method, pattern, fn, args=None):
+    def __init__(self, method, pattern, fn, args=None, traced=False):
         self.method = method
-        # the raw pattern doubles as the route's metrics label: bounded
-        # cardinality, unlike raw request paths (satellite: per-route tags)
+        # the raw pattern doubles as the route's metrics label and its
+        # edge span's name: bounded cardinality, unlike raw request paths
         self.pattern = pattern
         self.regex = re.compile("^" + pattern + "$")
         self.fn = fn
@@ -33,6 +34,9 @@ class Route:
         # the per-route queryValidationSpec table :174-200 — unknown args
         # 400 instead of being silently ignored)
         self.args = frozenset(args) if args is not None else None
+        # every request opens its edge span, profiled or not (the
+        # imports: milliseconds of work, a few spans cost nothing there)
+        self.traced = traced
 
 
 class PilosaHTTPServer:
@@ -83,12 +87,12 @@ class PilosaHTTPServer:
             Route("POST",
                   r"/index/(?P<index>[^/]+)/field/(?P<field>[^/]+)/import",
                   self._post_import,
-                  args=("clear", "remote", "ignoreKeyCheck")),
+                  args=("clear", "remote", "ignoreKeyCheck"), traced=True),
             Route("POST",
                   r"/index/(?P<index>[^/]+)/field/(?P<field>[^/]+)"
                   r"/import-roaring/(?P<shard>[0-9]+)",
                   self._post_import_roaring,
-                  args=("view", "clear", "remote")),
+                  args=("view", "clear", "remote"), traced=True),
             Route("GET", r"/export", self._get_export,
                   args=("index", "field", "shard")),
             Route("GET", r"/schema", self._get_schema),
@@ -696,7 +700,6 @@ class PilosaHTTPServer:
         http/handler.go:281), plus the stacked-evaluator cache gauges."""
         import json as _json
 
-        from ..utils import tracing
         from ..utils.stats import registry_of
 
         out = _json.loads(registry_of(self.stats).expvar_json())
@@ -705,11 +708,15 @@ class PilosaHTTPServer:
         if hasattr(local, "stacked_stats"):
             out["stacked"] = local.stacked_stats()
         # finished live spans by name (wall, self, thread CPU); all CPU of
-        # the process, runtime threads and edge included; cache flushes,
-        # rebuilds of the indexes' kept shard lists and walks of the
-        # views' kept row sets
+        # the process, runtime threads and edge included, and the same
+        # split by thread group (read here, never on the request path);
+        # cache flushes, rebuilds of the indexes' kept shard lists and
+        # walks of the views' kept row sets
         out["spans"] = tracing.span_stats()
-        out["process"] = {"cpu_seconds": time.process_time()}
+        threads, cpu_seconds = threadcpu.cpu_by_group()
+        if threads is not None:
+            out["threads"] = threads
+        out["process"] = {"cpu_seconds": cpu_seconds}
         out["holder"] = {**self.api.holder.flush_stats(), **shard_list_stats,
                          **row_set_stats}
         if self.api.spmd is not None:
@@ -744,8 +751,6 @@ class PilosaHTTPServer:
         """Dump of the retained span ring when an InMemoryTracer is
         installed (--tracing memory); tells you how to enable it when
         the zero-overhead nop default is active."""
-        from ..utils import tracing
-
         tracer = tracing.get_tracer()
         index_stats = tracing.trace_index().stats()
         if isinstance(tracer, tracing.InMemoryTracer):
@@ -1085,31 +1090,47 @@ class PilosaHTTPServer:
         class Handler(BaseHTTPRequestHandler):
             protocol_version = "HTTP/1.1"
 
+            # the request's edge span (see PilosaHTTPServer._open_edge)
+            # and its route, where parse_request opened one
+            edge = routed = None
+
             def log_message(self, fmt, *args):
                 pass
 
-            # A profiled request also marks its stay at the edge in the
-            # profiler's trace (the profile's root opens only in
-            # api.query): `http.parse` the request line and headers,
-            # `http.request` routing, the call, encoding and the write.
-            # What is left between two requests is the wait for the client.
+            def setup(self):
+                # one thread a connection: named, so /debug/vars
+                # `threads` counts its CPU under `request`
+                threading.current_thread().name = threadcpu.REQUEST_THREAD
+                super().setup()
 
             def parse_request(self):
-                if b"profile=true" in self.raw_requestline:
-                    from ..utils import tracing
+                self.edge = self.routed = None
+                line = self.raw_requestline
+                if b"profile=true" in line or b"/import" in line \
+                        or tracing.recording():
+                    self.edge = server._open_edge(self, line)
+                ok = super().parse_request()
+                if ok:
+                    if self.edge is not None:
+                        tracing.continue_remote(self.edge, self.headers)
+                    elif self.headers.get(tracing.TRACE_HEADER) is not None:
+                        self.edge = server._open_edge(self, None)
+                return ok
 
-                    with tracing.annotate("http.parse"):
-                        return super().parse_request()
-                return super().parse_request()
+            def handle_one_request(self):
+                try:
+                    super().handle_one_request()
+                finally:
+                    edge = self.edge
+                    if edge is not None:
+                        # ends after the reply's last byte is written
+                        # (or where the request failed)
+                        self.edge = None
+                        tracing.end_current("http.parse")
+                        edge.__exit__(None, None, None)
 
             def _dispatch(self):
-                if "profile=true" in self.path:
-                    from ..utils import tracing
-
-                    with tracing.annotate("http.request"):
-                        server.dispatch(self)
-                else:
-                    server.dispatch(self)
+                server.dispatch(self)
 
             do_GET = do_POST = do_DELETE = do_OPTIONS = _dispatch
 
@@ -1219,14 +1240,69 @@ class PilosaHTTPServer:
             return "*"
         return origin if origin in self.allowed_origins else None
 
-    def dispatch(self, handler):
-        from ..utils import tracing
+    def route(self, method, path):
+        """(route, match) of the first route of `method` whose pattern
+        matches `path`; (None, None) where none does."""
+        for route in self.routes:
+            if route.method == method:
+                m = route.regex.match(path)
+                if m is not None:
+                    return route, m
+        return None, None
 
+    def _open_edge(self, handler, line):
+        """The edge span of one request: its root, named `http.<METHOD>
+        <route pattern>` (`http.unmatched` where no route matches), open
+        from the request line to the reply's last byte, with the
+        same-thread children `http.parse` (headers and body read; opened
+        here, ended by dispatch) and `http.reply` (encoding, headers and
+        the write). api.query's profile root opens under it.
+
+        `line` is the raw request line: parse_request calls this before
+        the headers are read for a profiled request (`profile=true`), one
+        on a `traced` route (the imports) and, under a recording tracer,
+        every request, and the sampled CPU weight decides for the whole
+        tree. Else (`line` None) the headers continue a remote trace and
+        the edge opens after them, clocked. Returns None, and opens
+        nothing, for a request on none of those paths."""
+        profiled = True
+        if line is None:
+            method, target = handler.command, handler.path
+        else:
+            method, _, target = line.decode("latin-1").partition(" ")
+            target = target.partition(" ")[0]
+            profiled = b"profile=true" in line
+        path = urlparse(target).path.rstrip("/") or "/"
+        route, m = self.route(method, path)
+        if line is not None and not (profiled or tracing.recording()
+                                     or route is not None and route.traced):
+            return None
+        handler.routed = (path, route, m)
+        name = "http.unmatched" if route is None \
+            else f"http.{route.method} {route.pattern}"
+        if line is None:
+            edge = tracing.span_from_headers(name, handler.headers,
+                                             method=method)
+        else:
+            from ..utils import profile
+
+            edge = tracing.Span(name, tracing.new_trace_id(),
+                                tracing.new_trace_id(), None,
+                                {"method": method},
+                                cpu_weight=profile.cpu_weight())
+        edge.__enter__()
+        tracing.start_span("http.parse").__enter__()
+        return edge
+
+    def dispatch(self, handler):
+        edge = handler.edge
         parsed = urlparse(handler.path)
         path = parsed.path.rstrip("/") or "/"
         query = parse_qs(parsed.query)
         length = int(handler.headers.get("Content-Length", 0))
         body = handler.rfile.read(length) if length else b""
+        if edge is not None:
+            tracing.end_current("http.parse")
 
         cors = self._cors_origin(handler)
         if handler.command == "OPTIONS":
@@ -1251,33 +1327,26 @@ class PilosaHTTPServer:
         status, payload, content_type = 404, {"error": "not found"}, \
             "application/json"
         extra_headers = None  # e.g. Retry-After on a 503
-        matched = None  # Route whose pattern labels this request's metrics
-        trace_id = None  # histogram-exemplar link; the span ends before
-        # the timing below is recorded, so capture its id inside the with
-        for route in self.routes:
-            if route.method != handler.command:
-                continue
-            m = route.regex.match(path)
-            if m is None:
-                continue
-            matched = route
-            if route.args is not None:
-                unknown = set(query) - route.args
-                if unknown:
-                    status, payload = 400, {
-                        "error": "invalid query params: "
-                                 + ", ".join(sorted(unknown))}
-                    break
-            req = Request(m.groupdict(), query, body,
-                          handler.headers.get("Content-Type", ""),
-                          headers=handler.headers)
-            # Continue a cross-node trace from incoming headers (reference:
-            # http/handler.go:321 extractTracing middleware).
-            with tracing.span_from_headers(
-                    f"http.{handler.command} {path}", handler.headers,
-                    method=handler.command) as span:
+        # the edge's trace id links this request's histogram sample to its
+        # trace (a metrics exemplar)
+        trace_id = edge.trace_id if edge is not None else None
+        if handler.routed is not None and handler.routed[0] == path:
+            _, matched, m = handler.routed
+        else:
+            matched, m = self.route(handler.command, path)
+        if matched is not None:
+            unknown = set(query) - matched.args \
+                if matched.args is not None else None
+            if unknown:
+                status, payload = 400, {
+                    "error": "invalid query params: "
+                             + ", ".join(sorted(unknown))}
+            else:
+                req = Request(m.groupdict(), query, body,
+                              handler.headers.get("Content-Type", ""),
+                              headers=handler.headers)
                 try:
-                    result = route.fn(req)
+                    result = matched.fn(req)
                     if isinstance(result, RawResponse):
                         status, payload, content_type = (
                             200, result.body, result.content_type)
@@ -1288,47 +1357,47 @@ class PilosaHTTPServer:
                     extra_headers = e.headers
                 except Exception as e:  # internal error
                     status, payload = 500, {"error": str(e)}
-                if span is not None:
-                    span.set_tag("status", status)
-                    trace_id = span.trace_id
-            break
+        if edge is not None:
+            edge.set_tag("status", status)
 
-        if isinstance(payload, (dict, list)) or payload is None:
-            data = json.dumps(payload).encode()
-        else:
-            data = payload
-        # Per-route/per-status request metrics. Tagged with the matched
-        # route PATTERN, not the raw path — raw paths (index/field names)
-        # are unbounded-cardinality label values. The finally guarantees
-        # error responses — 400s, 404s ("unmatched"), 500s, even a write
-        # that died on a closed socket — are all counted.
-        try:
-            handler.send_response(status)
-            handler.send_header("Content-Type", content_type)
-            handler.send_header("Content-Length", str(len(data)))
-            if extra_headers:
-                for name, value in extra_headers.items():
-                    handler.send_header(name, value)
-            if self.allowed_origins:
-                handler.send_header("Vary", "Origin")
-            if cors:
-                handler.send_header("Access-Control-Allow-Origin", cors)
-            handler.end_headers()
-            handler.wfile.write(data)
-        finally:
-            tags = {"route": matched.pattern if matched else "unmatched",
-                    "method": handler.command, "status": str(status)}
-            self.stats.timing(
-                "http_request_seconds", _time.perf_counter() - t0, tags,
-                trace_id=trace_id)
-            if status >= 400:
-                self.stats.count("http_errors", 1, tags)
-            if status >= 500:
-                from ..utils import flightrec
+        # `http.reply`: encoding, headers, the write, and the request's
+        # metrics. Per-route/per-status request metrics are tagged with the
+        # matched route PATTERN, not the raw path — raw paths (index/field
+        # names) are unbounded-cardinality label values. The finally
+        # guarantees error responses — 400s, 404s ("unmatched"), 500s,
+        # even a write that died on a closed socket — are all counted.
+        with tracing.start_span("http.reply"):
+            if isinstance(payload, (dict, list)) or payload is None:
+                data = json.dumps(payload).encode()
+            else:
+                data = payload
+            try:
+                handler.send_response(status)
+                handler.send_header("Content-Type", content_type)
+                handler.send_header("Content-Length", str(len(data)))
+                if extra_headers:
+                    for name, value in extra_headers.items():
+                        handler.send_header(name, value)
+                if self.allowed_origins:
+                    handler.send_header("Vary", "Origin")
+                if cors:
+                    handler.send_header("Access-Control-Allow-Origin", cors)
+                handler.end_headers()
+                handler.wfile.write(data)
+            finally:
+                tags = {"route": matched.pattern if matched else "unmatched",
+                        "method": handler.command, "status": str(status)}
+                self.stats.timing(
+                    "http_request_seconds", _time.perf_counter() - t0, tags,
+                    trace_id=trace_id)
+                if status >= 400:
+                    self.stats.count("http_errors", 1, tags)
+                if status >= 500:
+                    from ..utils import flightrec
 
-                flightrec.record(
-                    "http.5xx", route=tags["route"],
-                    method=handler.command, status=status)
+                    flightrec.record(
+                        "http.5xx", route=tags["route"],
+                        method=handler.command, status=status)
 
 
 class _SamplingProfiler:

@@ -48,8 +48,9 @@ MAX_TASK_LEAVES = 64
 #: finished profiles retained for GET /debug/queries
 MAX_RECENT = 128
 
-#: one profile in this many has its spans read the thread-CPU clock, and
-#: counts as many times in the per-name CPU sums (tracing.count_span):
+#: one traced request (a profiled query or an import, decided where its
+#: edge span opens) in this many has its spans read the thread-CPU clock,
+#: and counts as many times in the per-name CPU sums (tracing.count_span):
 #: 30 reads a Count at 6-13 µs each cost the chip's host more than the
 #: rest of the profile, and its clock ticks every 10 ms, so only sums over
 #: thousands of queries say anything — which one query in eight gives too
@@ -60,6 +61,13 @@ _active = {}  # trace_id -> QueryProfile (only while the query runs)
 _recent = deque(maxlen=MAX_RECENT)
 _recent_lock = threading.Lock()
 _local = threading.local()
+
+
+def cpu_weight():
+    """The CPU weight of the next sampled root (a query's edge, an
+    import's, or a profile begun with no span live): CPU_SAMPLE one time
+    in CPU_SAMPLE, else 0 (the thread-CPU clock is not read)."""
+    return 0 if next(_turn) % CPU_SAMPLE else CPU_SAMPLE
 
 
 class QueryProfile:
@@ -79,11 +87,19 @@ class QueryProfile:
         self._dropped = 0
         self._tags = {}
         # the query's root span: created unconditionally (even under the
-        # nop tracer) so every start_span below it allocates a real child
-        self.root = tracing.Span(
-            "query", tracing.new_trace_id(), tracing.new_trace_id(),
-            None, {"index": index},
-            cpu_weight=0 if next(_turn) % CPU_SAMPLE else CPU_SAMPLE)
+        # nop tracer) so every start_span below it allocates a real child.
+        # Under a live span (the request's edge, server/http_server.py)
+        # it is that span's same-thread child: its trace, its CPU weight,
+        # and the edge's own time is the edge's less the query's
+        edge = tracing.current_span()
+        if edge is None:
+            self.root = tracing.Span(
+                "query", tracing.new_trace_id(), tracing.new_trace_id(),
+                None, {"index": index}, cpu_weight=cpu_weight())
+        else:
+            self.root = tracing.Span(
+                "query", edge.trace_id, tracing.new_trace_id(),
+                edge.span_id, {"index": index}, parent=edge)
 
     # -- collection (called from arbitrary query threads) --------------------
 

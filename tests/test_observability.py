@@ -598,8 +598,10 @@ def test_one_profile_in_cpu_sample_is_clocked_and_weighed(monkeypatch):
                if s.cpu is None)
     after = tracing.span_stats()["sampled"]
     assert after["count"] - before["count"] == 8
+    # less the clock's own read inside each clocked leaf
+    read = tracing.clock_read_cost()
     assert after["cpu_seconds"] - before["cpu_seconds"] == pytest.approx(
-        4 * sum(s.cpu for s in clocked))
+        4 * sum(s.cpu - read for s in clocked))
 
 
 def test_debug_vars_spans_process_and_holder(tmp_path):
