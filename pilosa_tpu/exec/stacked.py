@@ -650,6 +650,13 @@ class StackedEvaluator:
         # cross products answered: pairwise_dispatches ÷ this is the
         # launches a GroupBy took (1 where its rows fit one launch)
         self.pairwise_calls = 0
+        # Sums answered on the stacked path, and the dispatch-lock holds
+        # they made (each condition leaf's mask, the filter, the sum):
+        # sum_dispatches ÷ sum_calls is the launches a Sum took. A hold
+        # is counted per thread (`_holds`), so a Sum counts its own only.
+        self.sum_calls = 0
+        self.sum_dispatches = 0
+        self._holds = threading.local()
         # Whole-plan fusion observability (GET /debug/fusion): queries
         # whose every top-level Count rode ONE fused device program.
         self.fused_dispatches = 0
@@ -1354,25 +1361,28 @@ class StackedEvaluator:
 
             return jnp.zeros((self._padded_len(tuple(shards)),
                               WORDS_PER_ROW), dtype=jnp.uint32)
-        if plan[0] == "notnull":
-            stack = self.rows_stack(idx, field_name, (BSI_EXISTS_BIT,),
-                                    tuple(shards),
-                                    view_name=field.bsi_view_name())
-            return None if stack is None else stack[0]
-        data = self.bsi_stack(idx, field_name, shards)
-        if data is None:
-            return None
-        planes, sign, exists = data
-        self.dispatches += 1
-        with self._locked_dispatch(
-                "bsi_condition",
-                nbytes_in=(planes.size + sign.size + exists.size) * 4,
-                nbytes_out=sign.size * 4) as ph:
-            out = apply_bsi_condition(plan, planes, sign, exists)
-            ph.mark("dispatch_ack")
-            out = _launch_barrier(out)
-            ph.mark("sync")
-            return out
+        _tracing.end_current("exec.plan")
+        with _tracing.start_span("stack.condition", field=field_name,
+                                 plan=plan[0]):
+            if plan[0] == "notnull":
+                stack = self.rows_stack(idx, field_name, (BSI_EXISTS_BIT,),
+                                        tuple(shards),
+                                        view_name=field.bsi_view_name())
+                return None if stack is None else stack[0]
+            data = self.bsi_stack(idx, field_name, shards)
+            if data is None:
+                return None
+            planes, sign, exists = data
+            self.dispatches += 1
+            with self._locked_dispatch(
+                    "bsi_condition",
+                    nbytes_in=(planes.size + sign.size + exists.size) * 4,
+                    nbytes_out=sign.size * 4) as ph:
+                out = apply_bsi_condition(plan, planes, sign, exists)
+                ph.mark("dispatch_ack")
+                out = _launch_barrier(out)
+                ph.mark("sync")
+                return out
 
     def time_row_stack(self, idx, key, shards):
         """[S, W] union of one row across the quantum-view cover (the
@@ -1464,6 +1474,7 @@ class StackedEvaluator:
             with _tracing.start_span("dispatch.lock_wait", op=kind):
                 self._dispatch_lock.acquire()
             try:
+                self._holds.n = self._held() + 1
                 t1 = time.perf_counter()
                 ph = _PhaseClock(t1, compiling)
                 with _tracing.start_span("stacked.kernel",
@@ -1505,6 +1516,10 @@ class StackedEvaluator:
                 for phase, dt in phases:
                     if phase != "lock_wait":  # already counted above
                         prof.add(f"phase_{phase}_seconds", dt)
+
+    def _held(self):
+        """Dispatch-lock holds this thread has taken."""
+        return getattr(self._holds, "n", 0)
 
     def _note_kernel(self, kind, wall, nbytes_in, nbytes_out):
         """Per-kernel-family attribution (see /debug/kernels)."""
@@ -2237,6 +2252,7 @@ class StackedEvaluator:
         shards = tuple(shards)
         if len(shards) < MIN_SHARDS:
             return None
+        held = self._held()
         covered, filt = self.filter_stack(idx, filter_call, shards)
         if not covered:
             return None
@@ -2263,6 +2279,9 @@ class StackedEvaluator:
         total = 0
         for i in range(planes.shape[0]):
             total += (int(pos[i]) - int(neg[i])) << i
+        with self._lock:
+            self.sum_calls += 1
+            self.sum_dispatches += self._held() - held
         return total, combine_hi_lo(c_hi, c_lo)
 
     def try_minmax(self, idx, field, filter_call, shards, is_max):
@@ -2340,6 +2359,8 @@ class StackedEvaluator:
                 "pairwise_dispatches": self.pairwise_dispatches,
                 "pairwise_syncs": self.pairwise_syncs,
                 "pairwise_calls": self.pairwise_calls,
+                "sum_calls": self.sum_calls,
+                "sum_dispatches": self.sum_dispatches,
                 "group_fetches": self._fetch_commit.batches,
                 "group_fetched_queries": self._fetch_commit.batched,
                 "count_batches": self._count_commit.batches,

@@ -172,9 +172,11 @@ def test_the_cell_reports_what_it_reads(bench):
 @pytest.mark.parametrize("name", LISTED)
 def test_the_cell_is_appended_to(bench, name):
     """An accepted metric the cell reports: the cell appended to its
-    list, nothing else about it changed."""
+    list, nothing else about it changed. Cells added later are appended
+    after it, so the list keeps the order of BENCHMARK.json's cells."""
     entry, = [m for m in bench.listed["per_layer"] if m["name"] == name]
-    assert entry["workloads"][-1] == CELL
+    order = [c["name"] for c in bench.listed["workloads"]]
+    assert entry["workloads"] == sorted(entry["workloads"], key=order.index)
     assert entry["workloads"].count(CELL) == 1
     assert entry["moves"] == "query_qps"
 

@@ -26,7 +26,7 @@ IMPORT_ROUTE = "/index/(?P<index>[^/]+)/field/(?P<field>[^/]+)/import"
 QUERY_EDGE = "http.POST " + QUERY_ROUTE
 IMPORT_EDGE = "http.POST " + IMPORT_ROUTE
 READ_CELLS = ["seg1b-read-c32", "seg1b-read-c1", "seg1b-x4-read-c32",
-              "seg1b-r8-read-c32", "taxi250m-rank-c32"]
+              "seg1b-r8-read-c32", "taxi250m-rank-c32", "ssb40-flight1-c32"]
 RW_CELL = "seg1b-rw-c32"
 
 
